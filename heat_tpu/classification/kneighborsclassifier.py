@@ -7,13 +7,11 @@ vote (reference ``kneighborsclassifier.py:10-136``).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from ..core import types
 from ..core.base import BaseEstimator, ClassificationMixin
 from ..core.dndarray import DNDarray
-from ..spatial.distance import _quadratic_expand
 
 __all__ = ["KNeighborsClassifier"]
 
@@ -48,13 +46,13 @@ class KNeighborsClassifier(BaseEstimator, ClassificationMixin):
             raise RuntimeError("fit needs to be called before predict")
         yt = self.y._logical().ravel()
         nq, nt = x.shape[0], self.x.shape[0]
-        from ..core.kernels import pallas_supported
-        from ..spatial.distance import nearest_neighbors
+        from ..core.kernels import dispatch_mode
+        from ..spatial.distance import _nn_materialized, nearest_neighbors
 
         # the fused kernel's merge is O(k*(k+tile_m)) per tile — past k~64
         # the materializing cdist+top_k path wins, so gate on k as well
         if (
-            pallas_supported()
+            dispatch_mode("topk_distance") != "fallback"
             and nq * nt > 1 << 22
             and x.split in (None, 0)
             and self.n_neighbors <= 64
@@ -68,8 +66,7 @@ class KNeighborsClassifier(BaseEstimator, ClassificationMixin):
             record_dispatch("topk_distance", "fallback")
             Xq = x._logical().astype(jnp.float32)
             Xt = self.x._logical().astype(jnp.float32)
-            d2 = _quadratic_expand(Xq, Xt)  # (nq, nt)
-            _, idx = jax.lax.top_k(-d2, self.n_neighbors)  # (nq, k) nearest
+            _, idx = _nn_materialized(Xq, Xt, self.n_neighbors)  # (nq, k) nearest
         neigh_labels = jnp.take(yt, idx)  # (nq, k)
         votes = jnp.sum(
             one_hot_encoding(neigh_labels.ravel(), self.classes_).reshape(

@@ -25,21 +25,26 @@ from ._kcluster import _BLOCK_PROGRAMS, _KCluster
 __all__ = ["KMeans"]
 
 
-def _assign_choice(x: DNDarray, xa: jnp.ndarray):
+def _assign_choice(x: DNDarray, xa: jnp.ndarray, k: int):
     """(mode, mesh) for the Lloyd assignment at this call boundary.
 
     The fused pallas kernel (``kernels.lloyd``) needs a single-device
     buffer or even split-0 shards (its shard_map derives each shard's
-    validity window from its rank); anything else — feature split, uneven
-    shards — stays on the fused-XLA ``_assign_stats`` path. ``interpret``
-    only ever arrives via ``kernels.forced_mode`` (parity tests)."""
+    validity window from its rank) and a shape inside what it was
+    compiled for (``lloyd.kernel_fits``); anything else — feature split,
+    uneven shards, wide rows, more than a thousand centers — stays on the
+    fused-XLA ``_assign_stats`` path. ``interpret`` only ever arrives via
+    ``kernels.forced_mode`` (parity tests)."""
     from ..core.kernels import dispatch_mode
+    from ..core.kernels.lloyd import kernel_fits
 
     mode = dispatch_mode("lloyd_fused")
     mesh = None
     p = x.comm.size
     if mode in ("pallas", "interpret"):
-        if x.split == 0 and p > 1:
+        if not kernel_fits(xa.shape[1], k):
+            mode = "fallback"
+        elif x.split == 0 and p > 1:
             if xa.shape[0] % p == 0:
                 mesh = x.comm.mesh
             else:
@@ -66,7 +71,7 @@ def _assign_stats(xa: jnp.ndarray, centers: jnp.ndarray, k: int, n_valid):
     d2 = _quadratic_expand(xa, centers)  # (n, k), sharded on n
     labels = jnp.argmin(d2, axis=1).astype(jnp.int32)
     onehot = jax.nn.one_hot(labels, k, dtype=xa.dtype)  # (n, k)
-    valid = jnp.arange(xa.shape[0]) < n_valid
+    valid = jnp.arange(xa.shape[0], dtype=jnp.int32) < n_valid
     onehot = onehot * valid[:, None].astype(xa.dtype)
     # zero the padded rows themselves too: 0-weight x inf-garbage is nan
     xa_safe = jnp.where(valid[:, None], xa, 0.0)
@@ -110,7 +115,7 @@ def _inertia(xa: jnp.ndarray, centers: jnp.ndarray, k: int, n_valid=None) -> jnp
     per_row = jnp.min(d2, axis=1)
     if n_valid is None:
         return jnp.sum(per_row)
-    valid = jnp.arange(xa.shape[0]) < n_valid
+    valid = jnp.arange(xa.shape[0], dtype=jnp.int32) < n_valid
     return jnp.sum(jnp.where(valid, per_row, 0.0))
 
 
@@ -119,8 +124,7 @@ def _lloyd_fit(xa: jnp.ndarray, centers: jnp.ndarray, k: int, max_iter: int, tol
                n_valid=None, mode: str = "fallback", mesh=None):
     """The whole fit as ONE device program: a ``lax.while_loop`` over fused
     Lloyd iterations with the tol check on device. A full fit is a single
-    dispatch — essential when the host drives the TPU over a network
-    (per-step RPC latency would otherwise dominate)."""
+    dispatch: a host-side loop would pay a device→host sync per step."""
 
     def cond(state):
         i, _, _, shift = state
@@ -198,7 +202,7 @@ class KMeans(_KCluster):
     def _supervised_step(self, xa, centers, budget, tol, shift0, x):
         from ..core.kernels import record_dispatch
 
-        mode, mesh = _assign_choice(x, xa)
+        mode, mesh = _assign_choice(x, xa, self.n_clusters)
         record_dispatch("lloyd_fused", mode)
         prog = _lloyd_block_program(self.n_clusters, mode, mesh)
         return prog(xa, centers, budget, tol, jnp.int32(x.gshape[0]), shift0)
@@ -232,7 +236,7 @@ class KMeans(_KCluster):
         tol = -1.0 if self.tol is None else float(self.tol)
         from ..core.kernels import record_dispatch
 
-        mode, mesh = _assign_choice(x, xa)
+        mode, mesh = _assign_choice(x, xa, k)
         record_dispatch("lloyd_fused", mode)  # call boundary: once per fit
         centers, labels, n_iter = _lloyd_fit(
             xa, centers, k, self.max_iter, tol, n, mode=mode, mesh=mesh

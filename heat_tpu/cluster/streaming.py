@@ -134,7 +134,7 @@ class StreamingKMeans(_KCluster):
         from ..core.kernels import record_dispatch
 
         # per-chunk call boundary: pick (and count) the assignment mode
-        self._choice = _assign_choice(chunk, xa)
+        self._choice = _assign_choice(chunk, xa, self.n_clusters)
         record_dispatch("lloyd_fused", self._choice[0])
         return xa, jnp.int32(chunk.gshape[0])
 

@@ -1,5 +1,4 @@
 """Heat-TPU core: array API over JAX/XLA (reference ``heat/core/``)."""
-from . import _jax_compat  # noqa: F401  (installs jax.shard_map on older jax)
 import jax as _jax
 
 # float64/int64 parity with the reference's torch semantics. TPU computes

@@ -33,6 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ._dispatch import fence_cpu_collectives
+
 __all__ = [
     "Communication",
     "MeshCommunication",
@@ -594,7 +596,8 @@ def replicated_frame(
 
 def collective_lockstep(tree):
     """Pin a collective-bearing dispatch to completion under
-    multi-controller execution; a transparent pass-through otherwise.
+    multi-controller execution and on the CPU backend's in-process mesh;
+    a transparent pass-through otherwise.
 
     XLA matches cross-process collectives by launch order per device, but
     two *independent* programs (no data dependency — e.g. the moments and
@@ -603,11 +606,17 @@ def collective_lockstep(tree):
     each process: the rendezvous then deadlocks or silently mixes data
     across programs. Blocking on each such program before launching the
     next independent one restores a total cross-process order. Eager op
-    *chains* don't need this — data dependencies already serialize them —
-    and with one process there is no rendezvous, so this returns
-    immediately and full async dispatch is preserved."""
+    *chains* don't need this — data dependencies already serialize them.
+    One process on an accelerator has no rendezvous to protect, so there
+    this returns immediately and full async dispatch is preserved. One
+    process on a multi-device CPU mesh does: a loop of folds that runs
+    the host 32 programs ahead of the devices wedges the CPU client
+    (:mod:`heat_tpu.core._dispatch` has the mechanism), so each fold is
+    fenced there too."""
     if jax.process_count() > 1:
         jax.block_until_ready(tree)
+    else:
+        fence_cpu_collectives(tree)
     return tree
 
 

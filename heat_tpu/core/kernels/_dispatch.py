@@ -21,6 +21,15 @@ One module-level observer folds ``kernel.dispatch`` events into
 other families pass through untouched. Dispatch is recorded at the
 Python call boundary — once per eager call / fit / chunk — never inside
 traced code, so warm cached programs still count.
+
+x64 and kernel bodies: the library turns ``jax_enable_x64`` on at import,
+so inside a kernel every untyped Python number is 64 bits wide, and
+Mosaic has no 64-bit vectors. A literal ``0`` in a BlockSpec index map
+fails to legalize the map; Python-int ``fori_loop`` bounds make the loop
+index an i64 whose comparison against an int32 iota recurses in the
+lowering; ``jnp.where(mask, 1.0, 0.0)`` builds an f64 vector and aborts
+the compiler. Kernels therefore type them by hand: ``jnp.int32(0)`` in
+index maps, ``jnp.int32`` loop bounds, ``mask.astype(x.dtype)``.
 """
 from __future__ import annotations
 
@@ -30,11 +39,6 @@ from typing import Callable, Dict, Iterator, Optional
 import jax
 
 from .. import _hooks
-
-try:  # pallas TPU backend is optional at import time (CPU test meshes)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
 
 __all__ = [
     "KERNEL_STATS",
@@ -50,7 +54,7 @@ __all__ = [
 
 
 def _default_probe() -> bool:
-    return pltpu is not None and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 # name -> spec dict: {"probe", "fallback", "comparator", "roofline"}
@@ -68,7 +72,7 @@ def register_kernel(
     """Register a fused kernel with the dispatch layer.
 
     ``probe`` answers "can the *compiled* pallas path run right now?"
-    (default: TPU backend with pltpu importable). ``fallback`` names the
+    (default: a TPU backend). ``fallback`` names the
     mode :func:`dispatch_mode` reports when it cannot. ``comparator``
     and ``roofline`` are documentation carried into bench notes and
     docs/PERFORMANCE.md — every kernel lands with a raw-jnp comparator

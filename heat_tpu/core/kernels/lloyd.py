@@ -4,12 +4,14 @@ The XLA Lloyd iteration (``cluster.kmeans._assign_stats``) is two HBM
 passes over the data: the fused distance+argmin pass, then — because the
 argmin→one-hot dependency blocks fusion — a separate ``onehotᵀ @ X``
 update matmul that re-reads X. At k=8 that matmul also drives the MXU at
-8-of-128 output lanes (the BENCH_r05 floor probe's bound). This kernel
-streams X row tiles through VMEM ONCE: distances, argmin, the one-hot
-update matmul, per-cluster counts and the inertia all happen while the
-tile is resident, accumulating (sums, counts, inertia) across the
-sequential TPU grid. Centers are padded to 128 rows so the per-tile
-update matmul runs at full MXU width on operands already in VMEM.
+8-of-128 output lanes. This kernel
+streams X through VMEM ONCE, in column tiles of the transposed buffer
+(rows on the lane axis — the layout the chip keeps a narrow (n, f)
+buffer in, so the transpose costs nothing): distances, argmin, the
+one-hot update matmul, per-cluster counts and the inertia all happen
+while the tile is resident, accumulating (sums, counts, inertia) across
+the sequential TPU grid. Labels leave as a lane-dense row. Centers are
+padded to whole sublane groups (a multiple of 8 rows).
 
 Roofline: one read of the (n, f) buffer + O(n) label writes per Lloyd
 iteration — half the unfused path's traffic. Comparator: the fused-XLA
@@ -20,7 +22,9 @@ Parity: distances use the same quadratic expansion as
 ``spatial.distance._quadratic_expand`` and ties break toward the lower
 index (matching ``jnp.argmin``), so labels are bit-identical; sums and
 inertia accumulate per tile, so centroids match the XLA path to float32
-re-association (~1e-6 relative, the documented tolerance).
+re-association (~1e-6 relative, the documented tolerance). Both hold
+against an oracle at full f32 products, which the kernel asks for; XLA's
+own default for f32 operands on the chip is one bf16 pass.
 """
 from __future__ import annotations
 
@@ -29,17 +33,23 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ._dispatch import register_kernel
-
-try:  # pallas TPU backend is optional at import time (CPU test meshes)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from .moments import MAX_FEATURES, _tile_cols
 
 __all__ = ["lloyd_local", "lloyd_sharded", "LLOYD_KERNEL"]
 
 _INT_MAX = 2**31 - 1
+
+# The (kp, tile) distance and one-hot temporaries scale the tile down with
+# k (see ``lloyd_local``); the resident (kp, f) centers and sums and the
+# (kp, 128) counts do not shrink with it: 3 KiB of VMEM per center with
+# their double buffers, 3 MiB of the default 16 at this bound. Compiled
+# for v5e up to it; more centers are declined at the call boundary
+# (``kernel_fits``) and take the fused-XLA path, whose update matmul has
+# the MXU's lanes full at such k anyway.
+MAX_CLUSTERS = 1024
 
 LLOYD_KERNEL = register_kernel(
     "lloyd_fused",
@@ -49,8 +59,19 @@ LLOYD_KERNEL = register_kernel(
 )
 
 
+def kernel_fits(f: int, k: int) -> bool:
+    """Whether dispatch may give an (n, f) buffer and k centers to the
+    kernel (``moments.MAX_FEATURES`` is the layout bound both share)."""
+    return f <= MAX_FEATURES and k <= MAX_CLUSTERS
+
+
 def _lloyd_kernel(nv_ref, x_ref, c_ref, labels_ref, sums_ref, cnt_ref, in_ref,
                   *, k: int, tile_n: int):
+    """One (f, tile_n) column tile: the rows of the user's buffer sit on
+    the lane axis, so distances are (kp, tile_n) and the labels leave as
+    a lane-dense (1, tile_n) row. ``cnt_ref`` (kp, 128) and ``in_ref``
+    (1, 128) carry the same value in every lane (the wrapper reads lane
+    0): Mosaic stores vectors to VMEM, not scalars."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -59,74 +80,71 @@ def _lloyd_kernel(nv_ref, x_ref, c_ref, labels_ref, sums_ref, cnt_ref, in_ref,
         cnt_ref[:] = jnp.zeros(cnt_ref.shape, cnt_ref.dtype)
         in_ref[:] = jnp.zeros(in_ref.shape, in_ref.dtype)
 
-    x = x_ref[:]
-    c = c_ref[:]
-    xc = jnp.dot(x, c.T, preferred_element_type=jnp.float32)
-    x2 = jnp.sum(x * x, axis=1, keepdims=True)
-    c2 = jnp.sum(c * c, axis=1)[None, :]
-    d2 = jnp.maximum(x2 + c2 - 2.0 * xc, 0.0)
-    col = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
-    d2 = jnp.where(col < k, d2, jnp.inf)  # padded center rows can never win
-    mval = jnp.min(d2, axis=1, keepdims=True)
+    x = x_ref[:]  # (f, tile_n)
+    c = c_ref[:]  # (kp, f)
+    # full f32 products: the chip's default for f32 operands is one bf16
+    # pass, which moves labels at near ties and rounds the summed rows
+    hi = jax.lax.Precision.HIGHEST
+    xc = jnp.dot(c, x, preferred_element_type=jnp.float32, precision=hi)
+    x2 = jnp.sum(x * x, axis=0, keepdims=True)
+    c2 = jnp.sum(c * c, axis=1, keepdims=True)
+    d2 = jnp.maximum(x2 + c2 - 2.0 * xc, 0.0)  # (kp, tile_n)
+    row = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 0)
+    d2 = jnp.where(row < k, d2, jnp.inf)  # padded center rows can never win
+    mval = jnp.min(d2, axis=0, keepdims=True)
     # argmin with ties toward the lower index, matching jnp.argmin
     labels = jnp.min(
-        jnp.where(d2 == mval, col, jnp.int32(_INT_MAX)), axis=1, keepdims=True
+        jnp.where(d2 == mval, row, jnp.int32(_INT_MAX)), axis=0, keepdims=True
     )
     labels_ref[:] = labels
-    row = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0) + i * tile_n
-    valid = row < nv_ref[0, 0]
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, x.shape[1]), 1) + i * tile_n
+    valid = col < nv_ref[0]
     # zero both factors for padded rows: 0-weight x garbage would be nan
-    onehot = jnp.where(valid & (col == labels), 1.0, 0.0).astype(x.dtype)
+    onehot = (valid & (row == labels)).astype(x.dtype)
     xs = jnp.where(valid, x, 0.0)
-    sums_ref[:] += jnp.dot(onehot.T, xs, preferred_element_type=jnp.float32)
-    cnt_ref[:] += jnp.sum(onehot, axis=0, keepdims=True)
-    in_ref[0, 0] += jnp.sum(jnp.where(valid[:, 0], mval[:, 0], 0.0))
+    sums_ref[:] += jax.lax.dot_general(
+        onehot, xs, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=hi,
+    )
+    cnt_ref[:] += jnp.sum(onehot, axis=1, keepdims=True)
+    in_ref[:] += jnp.sum(jnp.where(valid, mval, 0.0), axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tile_n", "interpret"))
 def _lloyd_call(xa, centers, n_valid, k: int, tile_n: int, interpret: bool):
     n, f = xa.shape
-    kp = ((k + 127) // 128) * 128  # full MXU width for the update matmul
-    fp = (-f) % 128
-    xp = jnp.pad(xa, ((0, (-n) % tile_n), (0, fp)))
-    cp = jnp.pad(centers, ((0, kp - k), (0, fp)))
-    grid = (xp.shape[0] // tile_n,)
-    if pltpu is not None and not interpret:
-        vmem = pltpu.VMEM
-    else:  # interpreter path (CPU test meshes) has no TPU memory spaces
-        vmem = pl.ANY
-    # zero index-map components derive from the grid arg (i - i): this
-    # Mosaic build mis-legalizes i64 index-map constants (see topk_distance)
-    amap = lambda i: (i - i, i - i)
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=64 * 1024 * 1024
-        )
+    kp = -(-k // 8) * 8  # whole sublane groups of centers
+    cp = jnp.pad(centers, ((0, kp - k), (0, 0)))
+    # rows on the lane axis: see moments._moments_call for why the
+    # transpose is free on the chip and a (tile, f) row block is not
+    xt = xa.T
+    z = jnp.int32  # typed index-map zeros: see _dispatch's note on x64
+    fixed = lambda i, nv: (z(0), z(0))
     labels, sums, cnt, inertia = pl.pallas_call(
         functools.partial(_lloyd_kernel, k=k, tile_n=tile_n),
-        grid=grid,
-        **kwargs,
-        in_specs=[
-            pl.BlockSpec((1, 1), amap, memory_space=vmem),
-            pl.BlockSpec((tile_n, xp.shape[1]), lambda i: (i, i - i), memory_space=vmem),
-            pl.BlockSpec((kp, xp.shape[1]), amap, memory_space=vmem),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_n, 1), lambda i: (i, i - i), memory_space=vmem),
-            pl.BlockSpec((kp, xp.shape[1]), amap, memory_space=vmem),
-            pl.BlockSpec((1, kp), amap, memory_space=vmem),
-            pl.BlockSpec((1, 1), amap, memory_space=vmem),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(n, tile_n),),
+            in_specs=[
+                pl.BlockSpec((f, tile_n), lambda i, nv: (z(0), i)),
+                pl.BlockSpec((kp, f), fixed),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, tile_n), lambda i, nv: (z(0), i)),
+                pl.BlockSpec((kp, f), fixed),
+                pl.BlockSpec((kp, 128), fixed),
+                pl.BlockSpec((1, 128), fixed),
+            ],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((xp.shape[0], 1), jnp.int32),
-            jax.ShapeDtypeStruct((kp, xp.shape[1]), jnp.float32),
-            jax.ShapeDtypeStruct((1, kp), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
+            jax.ShapeDtypeStruct((kp, f), jnp.float32),
+            jax.ShapeDtypeStruct((kp, 128), jnp.float32),
+            jax.ShapeDtypeStruct((1, 128), jnp.float32),
         ],
         interpret=interpret,
-    )(jnp.asarray(n_valid, jnp.int32).reshape(1, 1), xp, cp)
-    return sums[:k, :f], cnt[0, :k], labels[:n, 0], inertia[0, 0]
+    )(jnp.asarray(n_valid, jnp.int32).reshape(1), xt, cp)
+    return sums[:k], cnt[:k, 0], labels[0], inertia[0, 0]
 
 
 def lloyd_local(
@@ -134,28 +152,29 @@ def lloyd_local(
     centers: jnp.ndarray,
     n_valid=None,
     *,
-    tile_n: int = 512,
-    interpret: bool | None = None,
+    tile_n: int | None = None,
+    interpret: bool = False,
 ):
     """Fused Lloyd assignment statistics of a local (n, f) buffer.
 
     Returns ``(sums, counts, labels, inertia)`` with the exact contract
     of ``cluster.kmeans._assign_stats``: per-cluster sums (k, f), counts
     (k,), per-row labels (n,) int32 and the summed min-distance inertia.
+    ``interpret`` runs the kernel body in the pallas interpreter (parity
+    tests on CPU meshes ask for it by name; nothing selects it silently).
     """
     if xa.ndim != 2 or centers.ndim != 2 or xa.shape[1] != centers.shape[1]:
         raise ValueError(f"bad operand shapes {xa.shape} x {centers.shape}")
-    from ._dispatch import pallas_supported
-
-    if interpret is None:
-        interpret = not pallas_supported(LLOYD_KERNEL)
     xa = xa.astype(jnp.float32)
     centers = centers.astype(jnp.float32)
     if n_valid is None:
         n_valid = xa.shape[0]
-    # keep the tile a multiple of 8: unaligned block shapes break Mosaic
-    tile_n = max(8, min(tile_n, -(-xa.shape[0] // 8) * 8))
-    return _lloyd_call(xa, centers, n_valid, centers.shape[0], tile_n, interpret)
+    # the tallest temporaries are the (f, tile) input and the (kp, tile)
+    # distances: 2^18 / f lanes failed to compile at f = 2 (the tile pads
+    # to 8 sublanes) and at k = 256 ("Ran out of memory in memory space vmem")
+    k = centers.shape[0]
+    tile_n = _tile_cols(xa.shape[0], max(xa.shape[1], k), tile_n)
+    return _lloyd_call(xa, centers, n_valid, k, tile_n, interpret)
 
 
 def lloyd_sharded(
@@ -164,8 +183,8 @@ def lloyd_sharded(
     n_valid,
     mesh,
     *,
-    tile_n: int = 512,
-    interpret: bool | None = None,
+    tile_n: int | None = None,
+    interpret: bool = False,
 ):
     """Fused Lloyd assignment statistics of a split-0 sharded buffer.
 

@@ -8,9 +8,10 @@ never writes the distance matrix: O(n·k) output, one pass over x and y.
 
 Distances are squared euclidean computed with the MXU-friendly quadratic
 expansion ``|x|² + |y|² - 2·x@yᵀ`` (same formula as
-``spatial.distance._quadratic_expand``), so values — and therefore
-neighbor ordering — match the materializing path bit for bit. Ties break
-toward the lower index, matching ``jax.lax.top_k``.
+``spatial.distance._quadratic_expand``), at full f32 products, so values
+— and therefore neighbor ordering — match the materializing path at
+``"highest"`` precision (bit for bit on the CPU). Ties break toward the
+lower index, matching ``jax.lax.top_k``.
 """
 from __future__ import annotations
 
@@ -20,15 +21,11 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ._dispatch import pallas_supported, register_kernel
+from ._dispatch import register_kernel
 
-try:  # pallas TPU backend is optional at import time (CPU test meshes)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-__all__ = ["nearest_neighbors", "pallas_supported", "TOPK_KERNEL"]
+__all__ = ["nearest_neighbors", "TOPK_KERNEL"]
 
 _INT_MAX = 2**31 - 1  # python int: jnp constants would be captured consts in kernels
 
@@ -72,7 +69,11 @@ def _knn_kernel(x_ref, y_ref, d_ref, i_ref, *, k: int, m: int, tile_m: int):
 
     x = x_ref[:]
     y = y_ref[:]
-    xy = jnp.dot(x, y.T, preferred_element_type=jnp.float32)
+    # full f32 products: at the default (one bf16 pass) the cross term's
+    # error exceeds the gaps between neighbours once |x|·|y| is large
+    # against their distance, and the kernel returns the wrong rows
+    xy = jnp.dot(x, y.T, preferred_element_type=jnp.float32,
+                 precision=jax.lax.Precision.HIGHEST)
     x2 = jnp.sum(x * x, axis=1, keepdims=True)
     y2 = jnp.sum(y * y, axis=1)[None, :]
     tile = jnp.maximum(x2 + y2 - 2.0 * xy, 0.0)
@@ -95,33 +96,25 @@ def _knn_local(x, y, k: int, tile_n: int, tile_m: int, interpret: bool):
     xp = jnp.pad(x, ((0, (-n) % tile_n), (0, 0)))
     yp = jnp.pad(y, ((0, (-m) % tile_m), (0, 0)))
     grid = (xp.shape[0] // tile_n, yp.shape[0] // tile_m)
-    if pltpu is not None and not interpret:
-        vmem = pltpu.VMEM
-    else:  # interpreter path (CPU test meshes) has no TPU memory spaces
-        vmem = pl.ANY
-    # index maps derive their zero components from the grid args (j - j)
-    # instead of the literal 0: this Mosaic build mis-legalizes i64 index-map
-    # constants mixed with i32 grid indices ("failed to legalize func.return")
-    xmap = lambda i, j: (i, j - j)
-    ymap = lambda i, j: (j, i - i)
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        # the (tile_n, tile_m) scratch + double-buffered y-tiles exceed the
-        # 16MB default scoped-vmem limit at the fastest tile shapes
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=64 * 1024 * 1024
-        )
+    # typed index-map zeros: see _dispatch's note on x64
+    xmap = lambda i, j: (i, jnp.int32(0))
+    ymap = lambda i, j: (j, jnp.int32(0))
     d, i = pl.pallas_call(
         functools.partial(_knn_kernel, k=k, m=m, tile_m=tile_m),
         grid=grid,
-        **kwargs,
+        # the (tile_n, tile_m) distance strip, the merge's copies of it and
+        # the double-buffered y-tile: inside the compiler's default 16 MiB
+        # of scoped VMEM at (f = 18, k = 5) only. At the default tiles it
+        # refuses f = 128 or k = 64 there ("RESOURCE_EXHAUSTED: Ran out of
+        # memory in memory space vmem"), both inside the classifier's gate
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
         in_specs=[
-            pl.BlockSpec((tile_n, f), xmap, memory_space=vmem),
-            pl.BlockSpec((tile_m, f), ymap, memory_space=vmem),
+            pl.BlockSpec((tile_n, f), xmap),
+            pl.BlockSpec((tile_m, f), ymap),
         ],
         out_specs=[
-            pl.BlockSpec((tile_n, k), xmap, memory_space=vmem),
-            pl.BlockSpec((tile_n, k), xmap, memory_space=vmem),
+            pl.BlockSpec((tile_n, k), xmap),
+            pl.BlockSpec((tile_n, k), xmap),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((xp.shape[0], k), jnp.float32),
@@ -139,7 +132,7 @@ def nearest_neighbors(
     *,
     tile_n: int = 256,
     tile_m: int | None = None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """k nearest reference rows for every query row, without the (n, m)
     distance matrix.
@@ -156,22 +149,25 @@ def nearest_neighbors(
     Returns
     -------
     (d2, idx) : (n, k) squared distances (ascending) and reference indices.
+
+    ``interpret`` runs the kernel body in the pallas interpreter (parity
+    tests on CPU meshes ask for it by name; nothing selects it silently).
     """
     if x.ndim != 2 or y.ndim != 2 or x.shape[1] != y.shape[1]:
         raise ValueError(f"bad operand shapes {x.shape} x {y.shape}")
     m = y.shape[0]
     if not 0 < k <= m:
         raise ValueError(f"k={k} must be in [1, {m}]")
-    if interpret is None:
-        interpret = not pallas_supported(TOPK_KERNEL)
     x = x.astype(jnp.float32)
     y = y.astype(jnp.float32)
     tile_n = min(tile_n, max(8, x.shape[0]))
     if tile_m is None:
-        # wide y-tiles amortize the merge passes (measured 2.5x over the
-        # materializing path at (256, 8192)); cap the (tile_n, tile_m)
-        # scratch at 8MB and the y-tile at 4MB to stay inside VMEM
+        # wide y-tiles amortize the merge passes; cap the (tile_n, tile_m)
+        # scratch at 8MB and the y-tile at 4MB to stay inside VMEM. The
+        # merge is k unrolled passes over the strip, so the program, and
+        # the compiler's time over it, grow with k * tile_m (260 s at
+        # k = 64 on 8192-wide tiles): hold that product where k <= 8 has it
         f = x.shape[1]
-        tile_m = min(8192, (1 << 21) // tile_n, (1 << 20) // max(f, 1))
+        tile_m = min(8192, (1 << 21) // tile_n, (1 << 20) // max(f, 1), (1 << 16) // k)
     tile_m = max(128, min(tile_m, max(128, m)) // 128 * 128)
     return _knn_local(x, y, k, tile_n, tile_m, interpret)

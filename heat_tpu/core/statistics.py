@@ -607,12 +607,16 @@ def _panel_cols_merge(cnt, mean, m2):
 
 def _panel_kernel_stats(x: DNDarray, arr, interpret: bool):
     """Axis-0 and whole-buffer moments via the pallas kernel (one read),
-    or None when the kernel's layout preconditions fail (the caller then
-    uses the XLA panel — never a second read of a memoized buffer)."""
+    or None when the kernel's layout preconditions fail or its rows are
+    wider than ``moments.kernel_fits`` admits (the caller then uses the
+    XLA panel — never a second read of a memoized buffer)."""
     from .kernels import moments_local, moments_sharded
+    from .kernels.moments import kernel_fits
 
     buf = arr if arr.ndim == 2 else arr.reshape(-1, 1)
     p = x.comm.size
+    if not kernel_fits(buf.shape[1]):
+        return None
     if x.split == 0 and p > 1:
         if buf.shape[0] % p:
             return None

@@ -6,9 +6,11 @@ compute path is XLA; this package supplies the *runtime* native layer around
 it — parallel file parsing and background IO prefetch — compiled from
 ``src/*.cpp`` with g++ at first use and bound through :mod:`ctypes`.
 
-Every entry point degrades gracefully: if the toolchain or the build is
-unavailable (``HEAT_TPU_NO_NATIVE=1`` disables it outright), callers fall
-back to their pure-Python paths.
+Every entry point degrades to its pure-Python path when the library is
+unavailable (``HEAT_TPU_NO_NATIVE=1`` disables it outright); a build that
+was attempted and failed says so in a ``RuntimeWarning`` carrying the
+compiler's output. The library is rebuilt when the content of
+``src/*.cpp`` changes (a sha256 kept beside it), never by mtime.
 
 Components
 ----------
@@ -24,9 +26,11 @@ Components
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
+import warnings
 from typing import Optional, Tuple
 
 import numpy as np
@@ -42,9 +46,25 @@ __all__ = [
 
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "src")
 _LIB_PATH = os.path.join(os.path.dirname(__file__), "_heat_native.so")
+_DIGEST_PATH = _LIB_PATH + ".sha256"
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+
+
+_CXX = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread"]
+
+
+def _source_digest(sources) -> str:
+    """sha256 over the compile command and every source's name and
+    bytes: what the binary is a function of. mtimes are not — a copied
+    or freshly checked-out tree scrambles them."""
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    for src in sources:
+        h.update(os.path.basename(src).encode() + b"\0")
+        with open(src, "rb") as fh:
+            h.update(fh.read())
+    return h.hexdigest()
 
 
 def _build() -> bool:
@@ -56,37 +76,44 @@ def _build() -> bool:
     sources = sorted(os.path.join(_SRC_DIR, f) for f in names if f.endswith(".cpp"))
     if not sources:
         return False
-    newest_src = max(os.path.getmtime(s) for s in sources)
-    if os.path.exists(_LIB_PATH) and os.path.getmtime(_LIB_PATH) >= newest_src:
-        return True
-    # compile to a per-process temp name, then atomically rename: a
-    # concurrent process must never dlopen a half-written library
-    tmp = f"{_LIB_PATH}.tmp{os.getpid()}"
-    cmd = [
-        "g++",
-        "-O3",
-        "-std=c++17",
-        "-shared",
-        "-fPIC",
-        "-pthread",
-        *sources,
-        "-o",
-        tmp,
-    ]
+    digest = _source_digest(sources)
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        with open(_DIGEST_PATH) as fh:
+            if fh.read().strip() == digest and os.path.exists(_LIB_PATH):
+                return True
+    except OSError:
+        pass
+    # compile to a per-process temp name, then atomically rename: a
+    # concurrent process must never dlopen a half-written library. The
+    # digest lands after the library, so a crash in between rebuilds.
+    tmp = f"{_LIB_PATH}.tmp{os.getpid()}"
+    try:
+        subprocess.run([*_CXX, *sources, "-o", tmp], check=True, capture_output=True, timeout=120)
         os.replace(tmp, _LIB_PATH)
-    except (OSError, subprocess.SubprocessError):
+        with open(tmp, "w") as fh:
+            fh.write(digest + "\n")
+        os.replace(tmp, _DIGEST_PATH)
+    except (OSError, subprocess.SubprocessError) as e:
         try:
             os.unlink(tmp)
         except OSError:
             pass
+        # callers still degrade to their Python paths, but never in
+        # silence: a run that must not degrade turns this into an error
+        detail = getattr(e, "stderr", b"") or b""
+        warnings.warn(
+            f"heat_tpu.native: building {_LIB_PATH} failed ({e}); "
+            f"{detail.decode(errors='replace')[-2000:]}",
+            RuntimeWarning,
+            stacklevel=3,
+        )
         return False
     return True
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    """Build (if stale) and dlopen the native library; None on any failure."""
+    """Build (if its sources changed) and dlopen the native library;
+    None when it is disabled or unavailable."""
     global _lib, _tried
     if _lib is not None:
         return _lib
@@ -102,12 +129,14 @@ def _load() -> Optional[ctypes.CDLL]:
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
-            return None
-        try:
             _bind_symbols(lib)
-        except AttributeError:
-            # stale prebuilt .so missing current symbols — degrade to Python
+        except (OSError, AttributeError) as e:
+            # a prebuilt library without src/ may be stale or foreign
+            warnings.warn(
+                f"heat_tpu.native: loading {_LIB_PATH} failed ({e})",
+                RuntimeWarning,
+                stacklevel=2,
+            )
             return None
         _lib = lib
         return _lib

@@ -60,7 +60,7 @@ probabilistically (:class:`chaos`) or as an exact scripted
 
 Every guard-layer failure derives from :class:`ResilienceError`
 (:mod:`~heat_tpu.resilience.errors`); see ``docs/RESILIENCE.md`` for the
-failure taxonomy, manifest format, and chaos recipes.
+failure classes, manifest format, and chaos recipes.
 """
 from . import chaos as _chaos_mod  # noqa: F401
 from .chaos import FaultSchedule, Injection, chaos

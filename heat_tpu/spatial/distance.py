@@ -15,6 +15,7 @@ are mailed back. On TPU there are two native schedules:
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Callable, Optional
 
 import jax
@@ -189,6 +190,14 @@ def rbf(
     return _dist(X, Y, lambda a, b: _gaussian_jit(a, b, sigma), use_ring=False)
 
 
+@partial(jax.jit, static_argnums=2)
+def _nn_materialized(x: jnp.ndarray, y: jnp.ndarray, k: int):
+    """The (n, m) distance matrix and ``top_k`` over it: the comparator
+    of the ``topk_distance`` kernel, with its (d2, idx) contract."""
+    neg, idx = jax.lax.top_k(-_quadratic_expand(x, y), k)
+    return -neg, idx.astype(jnp.int32)
+
+
 def nearest_neighbors(x: DNDarray, y: DNDarray, k: int):
     """k nearest rows of ``y`` for every row of ``x`` — without the (n, m)
     distance matrix.
@@ -196,28 +205,33 @@ def nearest_neighbors(x: DNDarray, y: DNDarray, k: int):
     TPU-native extension beyond the reference (whose kNN materializes the
     full ``cdist`` then ``topk``, ``kneighborsclassifier.py:10-136``): a
     fused pallas kernel streams y-tiles through VMEM keeping a per-row
-    running top-k, so the (n, m) intermediate never exists. Supports
+    running top-k, so the (n, m) intermediate never exists (off a TPU
+    backend the materializing comparator answers the same contract).
+    Supports
     ``x.split in (0, None)`` with replicated ``y``; x-shards are processed
     independently per device (``shard_map``), indices are global.
 
     Returns ``(d2, idx)``: (n, k) squared distances (ascending) and row
     indices into ``y``, both with ``x``'s split.
     """
-    from ..core.kernels import nearest_neighbors as _nn_local
-    from ..core.kernels import pallas_supported, record_dispatch
+    from ..core.kernels import dispatch_mode, record_dispatch
+    from ..core.kernels import nearest_neighbors as _nn_kernel
 
     if x.ndim != 2 or y.ndim != 2:
         raise NotImplementedError("nearest_neighbors expects 2-D operands")
-    # this entry always runs the kernel (interpreted off-TPU) — record the
-    # decision at the call boundary, outside any traced code
-    record_dispatch(
-        "topk_distance",
-        "pallas" if pallas_supported("topk_distance") else "interpret",
-    )
-    if y.split is not None:
-        y = y.resplit(None)
     if x.split not in (None, 0):
         raise NotImplementedError("nearest_neighbors: x must be split=0 or replicated")
+    # the decision is made and recorded at the call boundary, outside any
+    # traced code: the compiled kernel on a TPU backend, the materializing
+    # top-k elsewhere; the interpreter only when a test forces it by name
+    mode = dispatch_mode("topk_distance")
+    record_dispatch("topk_distance", mode)
+    if y.split is not None:
+        y = y.resplit(None)
+    if mode == "fallback":
+        _nn_local = _nn_materialized
+    else:
+        _nn_local = partial(_nn_kernel, interpret=(mode != "pallas"))
 
     # the kernel computes in f32 (MXU precision); cast once here.
     # y must be its logical extent: the kernel's indices are global rows

@@ -125,16 +125,19 @@ def _moments_program(mode: str = "xla", mesh=None):
 
 def _moments_choice(chunk: DNDarray, xa) -> tuple:
     """(mode, mesh) for one chunk's moments fold at the call boundary —
-    the same layout gate as the statistics panel: pallas needs a local
-    buffer or even split-0 shards, anything else folds through the
-    one-pass XLA twin."""
+    the same gate as the statistics panel: pallas needs a local buffer or
+    even split-0 shards, of rows no wider than ``moments.kernel_fits``
+    admits; anything else folds through the one-pass XLA twin."""
     from ..core.kernels import dispatch_mode
+    from ..core.kernels.moments import kernel_fits
 
     mode = dispatch_mode("moments_onepass")
     mesh = None
     if mode in ("pallas", "interpret"):
         p = chunk.comm.size
-        if chunk.split == 0 and p > 1:
+        if not kernel_fits(xa.shape[1]):
+            mode = "xla"
+        elif chunk.split == 0 and p > 1:
             if xa.shape[0] % p == 0:
                 mesh = chunk.comm.mesh
             else:

@@ -8,6 +8,7 @@ The true multi-process analogue is ``tools/mpirun.py`` (see
 ``jax.distributed`` groups; it launches each worker with ``XLA_FLAGS``
 pre-set, which the guard below respects.
 """
+import faulthandler
 import hashlib
 import os
 import re
@@ -31,12 +32,40 @@ jax.config.update("jax_platforms", "cpu")
 _WS_SHARED_ROOT = os.environ.get("HEAT_TPU_WS_SHARED_ROOT")
 
 
+# No single test may sit longer than this. A wedged CPU client (see
+# heat_tpu/core/_dispatch.py) blocks the main thread inside the runtime,
+# where no Python-level timeout can reach it: under xdist the worker then
+# sat silent until the whole suite's clock ran out. faulthandler's
+# watchdog thread needs no GIL: it dumps every thread's stack to the real
+# stderr and exits the process, xdist reports the test as crashed, starts
+# a new worker and the run reaches its end. Longer than the 600 s the
+# multi-process tests give their own children; the slowest test takes
+# about a quarter of it under six workers.
+_TEST_BOUND_S = 660
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "multihost: also executed inside the real 2-process jax.distributed "
         "runs (tests/test_multihost.py::test_multi_process_pytest_subset)",
     )
+    # capture is suspended while hooks configure: fd 2 is the real stderr
+    config._heat_tpu_stderr = os.fdopen(os.dup(2), "w")
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    if _WS_SHARED_ROOT:  # tools/mpirun.py workers have the runner's deadlines
+        yield
+        return
+    faulthandler.dump_traceback_later(
+        _TEST_BOUND_S, exit=True, file=item.config._heat_tpu_stderr
+    )
+    try:
+        yield
+    finally:
+        faulthandler.cancel_dump_traceback_later()
 
 
 def pytest_sessionstart(session):

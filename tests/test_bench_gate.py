@@ -150,27 +150,37 @@ def test_band_in_band_best_survives_migration():
     assert "retired_band_outliers" not in rec
 
 
-def test_real_history_gram_outlier_retires_on_migration():
-    """The shipped BENCH_HISTORY's gram record carries a top-of-band best
-    (32173.5 against a ~26 TFLOP/s trailing clean median) that made every
-    healthy in-band run read as ~0.81x vs_best. The r8 protocol bump
-    re-runs ``_migrate_history``, whose r7 band clamp must retire exactly
-    that best — this pins the fix to the REAL on-disk record, not a
-    synthetic one."""
-    import copy
-    import json
-    import os
+# A history shaped like the record the r7 band clamp was written against:
+# written at protocol api-r5, a top-of-band ``best`` (32173.5) over a
+# ~26k trailing clean median, and two values already retired above the
+# physical cap.
+_GRAM_HISTORY = {
+    "_protocol": "api-r5",
+    "kernel_matmul_gram_gflops": {
+        "best": 32173.5,
+        "best_median": 32173.5,
+        "clean": [22529.96, 25688.16, 24596.07, 25427.6, 27672.7, 31051.54,
+                  32173.5, 31121.89, 26087.26],
+        "runs": [17630.62, 10529.08, 26400.65, 22529.96, 25688.16, 24596.07,
+                 25427.6, 27672.7, 31051.54, 32173.5, 31121.89, 26087.26],
+        "retired_artifacts": [46286.73, 50457.26],
+        "pending_violations": [],
+    },
+}
 
-    path = os.path.join(os.path.dirname(os.path.abspath(bench.__file__)),
-                        "BENCH_HISTORY.json")
-    if not os.path.exists(path):
-        pytest.skip("no BENCH_HISTORY.json in this checkout")
-    with open(path) as fh:
-        hist = json.load(fh)
+
+def test_history_gram_outlier_retires_on_migration():
+    """A gram record carrying a top-of-band best (32173.5 against a
+    ~26 TFLOP/s trailing clean median) made every healthy in-band run
+    read as ~0.81x vs_best. The r8 protocol bump re-runs
+    ``_migrate_history``, whose r7 band clamp must retire exactly that
+    best — pinned on a whole api-r5 history, not a single synthetic
+    record."""
+    import copy
+
+    hist = copy.deepcopy(_GRAM_HISTORY)
     key = "kernel_matmul_gram_gflops"
-    rec = hist.get(key)
-    if not isinstance(rec, dict) or not (rec.get("clean") or rec.get("runs")):
-        pytest.skip("history has no gram record yet")
+    rec = hist[key]
     limit = bench._band_limit(rec, bench.OVERLAP_BAND[key])
     migrated = bench._migrate_history(copy.deepcopy(hist))[key]
     # whatever the starting state, the migrated bar sits inside the band

@@ -1,0 +1,211 @@
+"""The chip's compiler, asked without the chip.
+
+Interpret mode discharges a pallas kernel to plain jax on the CPU, so it
+passes what Mosaic refuses: a scalar store to VMEM, a 64-bit index-map
+literal, a slice off the (8, 128) tiling, a copy the layout forces. The
+TPU compiler is installed here and compiles for a chip that is described
+and not attached, so these tests lower the four registered kernels — at
+``bench.py``'s shapes and at ``chip_smoke.py``'s — for device 0 of a
+``v5e:2x2`` topology, and the two ``shard_map`` wrappers over a mesh of
+its four devices. Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture of this file,
+which skips if it cannot be; nothing here touches ``topologies`` while a
+module is imported (xdist workers all import every test file, and only
+one process may load the TPU's library). Compiles run in the test's own
+process with the persistent compilation cache off around them: an entry
+written for a described device cannot be read back without the chip.
+"""
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+import bench
+import chip_smoke
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps the library from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    from jax.sharding import Mesh
+
+    from heat_tpu.core.communication import SPLIT_AXIS
+
+    return Mesh(np.array(topo.devices), (SPLIT_AXIS,))
+
+
+def _spec(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_kernel(lowered, max_temp_bytes: int):
+    """Compile; the Mosaic kernel must be in the program, and XLA must not
+    have had to copy the operand into another layout around it."""
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= max_temp_bytes, f"{temp} bytes of temporaries around the kernel"
+    return compiled
+
+
+# Each kernel is lowered through its own entry point, so the tiles under
+# test are the ones dispatch gets. Beside the bench and smoke shapes: the
+# corners of what dispatch admits (``kernel_fits``, the classifier's
+# n_neighbors <= 64), where a tile sized by the feature count alone ran
+# out of VMEM.
+KMEANS_SHAPES = {
+    "bench": (bench.N, bench.F, bench.K),
+    "smoke": chip_smoke.SIZES["kmeans"][:3],
+    "two_features": (1 << 20, 2, 3),  # the tile pads to 8 sublanes
+    "many_centers": (1 << 20, 32, 256),
+    "widest_most_centers": (1 << 20, 64, 1024),
+    "narrow_most_centers": (1 << 20, 8, 1024),
+}
+MOMENTS_SHAPES = {
+    "bench": (bench.MOM_N, bench.MOM_F),
+    "smoke": chip_smoke.SIZES["moments"],
+    "one_column": (1 << 22, 1),  # what a 1-D array is given as
+    "widest": (1 << 22, 64),
+}
+_NQ, _NT, _F, _K = chip_smoke.SIZES["knn"]
+KNN_SHAPES = {
+    "smoke": (_NQ, _NT, _F, _K),  # bench-protocol sizes too: SUSY's 18 features, k = 5
+    "most_neighbors": (_NQ, _NT, _F, 64),
+    "wide": (_NQ, _NT, 128, _K),
+    "wide_most_neighbors": (_NQ, _NT, 128, 64),
+}
+
+
+def test_shapes_are_what_dispatch_admits():
+    from heat_tpu.core.kernels import lloyd, moments
+
+    assert _F == bench.CDIST_F
+    assert all(lloyd.kernel_fits(f, k) for _, f, k in KMEANS_SHAPES.values())
+    assert all(moments.kernel_fits(f) for _, f in MOMENTS_SHAPES.values())
+    # one past each bound is declined
+    assert not lloyd.kernel_fits(lloyd.MAX_FEATURES + 1, 8)
+    assert not lloyd.kernel_fits(32, lloyd.MAX_CLUSTERS + 1)
+    assert not moments.kernel_fits(moments.MAX_FEATURES + 1)
+
+
+@pytest.mark.parametrize("which", sorted(KMEANS_SHAPES))
+def test_lloyd_fused_compiles(one_chip, which):
+    import jax
+    import jax.numpy as jnp
+
+    from heat_tpu.core.kernels import lloyd_local
+
+    n, f, k = KMEANS_SHAPES[which]
+    lowered = jax.jit(lloyd_local).lower(
+        _spec((n, f), jnp.float32, one_chip), _spec((k, f), jnp.float32, one_chip),
+        _spec((), jnp.int32, one_chip),
+    )
+    # the (n, f) operand is consumed in place: no padded or transposed copy
+    _compiled_kernel(lowered, max_temp_bytes=1 << 20)
+
+
+@pytest.mark.parametrize("which", sorted(MOMENTS_SHAPES))
+def test_moments_onepass_compiles(one_chip, which):
+    import jax
+    import jax.numpy as jnp
+
+    from heat_tpu.core.kernels import moments_local
+
+    n, f = MOMENTS_SHAPES[which]
+    lowered = jax.jit(moments_local).lower(
+        _spec((n, f), jnp.float32, one_chip), _spec((), jnp.int32, one_chip)
+    )
+    _compiled_kernel(lowered, max_temp_bytes=1 << 20)
+
+
+@pytest.mark.parametrize("n", [chip_smoke.SIZES["chol"], 1000, 100])
+def test_chol_panel_fused_compiles(one_chip, n):
+    """The public path's shapes: 128-wide panels once the matrix has more
+    than one, a single whole-matrix panel below that; n = 1000 pads."""
+    import jax
+    import jax.numpy as jnp
+
+    from heat_tpu.core.kernels import panel_update
+
+    assert chip_smoke.SIZES["chol"] == panel_update.MAX_FUSED_N
+    lowered = jax.jit(panel_update.cholesky_blocked).lower(_spec((n, n), jnp.float32, one_chip))
+    _compiled_kernel(lowered, max_temp_bytes=16 << 20)
+
+
+@pytest.mark.parametrize("which", sorted(KNN_SHAPES))
+def test_topk_distance_compiles(one_chip, which):
+    import jax
+    import jax.numpy as jnp
+
+    from heat_tpu.core.kernels import nearest_neighbors
+
+    nq, nt, f, k = KNN_SHAPES[which]
+    lowered = jax.jit(lambda x, y: nearest_neighbors(x, y, k)).lower(
+        _spec((nq, f), jnp.float32, one_chip), _spec((nt, f), jnp.float32, one_chip)
+    )
+    # row-tiled still: XLA may re-lay both (n, f) operands out to 128 lanes
+    _compiled_kernel(lowered, max_temp_bytes=(nq + nt) * 128 * 4 * 2)
+
+
+def test_lloyd_sharded_compiles_over_four_chips(four_chips):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from heat_tpu.core.communication import SPLIT_AXIS
+    from heat_tpu.core.kernels import lloyd_sharded
+
+    n, f, k = KMEANS_SHAPES["smoke"]
+    rows, rep = NamedSharding(four_chips, P(SPLIT_AXIS, None)), NamedSharding(four_chips, P())
+    lowered = jax.jit(lambda x, c, nv: lloyd_sharded(x, c, nv, four_chips)).lower(
+        _spec((n, f), jnp.float32, rows), _spec((k, f), jnp.float32, rep), _spec((), jnp.int32, rep)
+    )
+    compiled = _compiled_kernel(lowered, max_temp_bytes=1 << 20)
+    assert "all-reduce" in compiled.as_text()  # the psum of sums, counts, inertia
+    # each chip holds its quarter of the rows, not a replica
+    assert compiled.memory_analysis().argument_size_in_bytes < n * f * 4 // 4 + (1 << 20)
+
+
+def test_moments_sharded_compiles_over_four_chips(four_chips):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from heat_tpu.core.communication import SPLIT_AXIS
+    from heat_tpu.core.kernels import moments_sharded
+
+    n, f = MOMENTS_SHAPES["smoke"]
+    rows, rep = NamedSharding(four_chips, P(SPLIT_AXIS, None)), NamedSharding(four_chips, P())
+    lowered = jax.jit(lambda x, nv: moments_sharded(x, nv, four_chips)).lower(
+        _spec((n, f), jnp.float32, rows), _spec((), jnp.int32, rep)
+    )
+    compiled = _compiled_kernel(lowered, max_temp_bytes=1 << 20)
+    assert "all-reduce" in compiled.as_text()  # the Chan combine's psums
+    assert compiled.memory_analysis().argument_size_in_bytes < n * f * 4 // 4 + (1 << 20)

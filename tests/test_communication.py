@@ -343,6 +343,39 @@ class TestSubMeshComms(TestCase):
         self.assertIs(ht.get_comm(), before)
 
 
+class TestLockstepFence(TestCase):
+    def test_cpu_mesh_dispatch_is_pinned_to_completion(self):
+        """On the in-process multi-device CPU mesh a loop of collective
+        programs must not run the host ahead of the devices: past 32
+        programs in flight the CPU client wedges (tests/test_sketch.py's
+        warm streaming loop aborted its process that way). So
+        ``collective_lockstep`` returns only what has finished there."""
+        import jax
+
+        from heat_tpu.core.communication import collective_lockstep
+
+        if self.comm.size < 2:
+            pytest.skip("needs a multi-device mesh")
+        x = ht.random.randn(1024, 1024, split=0).larray
+        prog = jax.jit(lambda a: ((a @ a.T) @ (a @ a.T)).sum(axis=0))
+        jax.block_until_ready(prog(x))  # compiled: the next call only dispatches
+        out = collective_lockstep((prog(x), prog(x + 1.0)))
+        self.assertTrue(all(o.is_ready() for o in out))
+
+    def test_single_device_results_pass_through(self):
+        """One device has no rendezvous to protect: nothing to wait for,
+        and whatever is not an array passes through untouched."""
+        import jax.numpy as jnp
+
+        from heat_tpu.core._dispatch import fence_cpu_collectives
+        from heat_tpu.core.communication import collective_lockstep
+
+        tree = {"a": jnp.arange(4.0), "n": 3, "none": None}
+        self.assertIs(collective_lockstep(tree), tree)
+        fence_cpu_collectives(None)
+        fence_cpu_collectives([])
+
+
 class TestCommunicatorPlumbing(TestCase):
     def test_sanitize_defaults_and_rejects(self):
         self.assertIs(sanitize_comm(None), ht.get_comm())

@@ -77,7 +77,7 @@ class TestTopkDistanceKernel(TestCase):
         for (n, m, f, k) in [(64, 200, 8, 5), (130, 512, 32, 1), (37, 999, 16, 7)]:
             x = rng.normal(size=(n, f)).astype(np.float32)
             y = rng.normal(size=(m, f)).astype(np.float32)
-            d, i = nearest_neighbors(jnp.asarray(x), jnp.asarray(y), k)
+            d, i = nearest_neighbors(jnp.asarray(x), jnp.asarray(y), k, interpret=True)
             ref_d, ref_i = _reference_knn(x, y, k)
             np.testing.assert_array_equal(np.asarray(i), ref_i)
             np.testing.assert_allclose(np.asarray(d), ref_d, rtol=1e-4, atol=1e-5)
@@ -90,7 +90,7 @@ class TestTopkDistanceKernel(TestCase):
         rng = np.random.default_rng(5)
         x = rng.normal(size=(16, 4)).astype(np.float32)
         y = rng.normal(size=(20, 4)).astype(np.float32)
-        d, i = nearest_neighbors(jnp.asarray(x), jnp.asarray(y), 20)
+        d, i = nearest_neighbors(jnp.asarray(x), jnp.asarray(y), 20, interpret=True)
         ref_d, ref_i = _reference_knn(x, y, 20)
         np.testing.assert_array_equal(np.asarray(i), ref_i)
 
@@ -102,45 +102,54 @@ class TestTopkDistanceKernel(TestCase):
         x = jnp.zeros((4, 3))
         y = jnp.zeros((5, 3))
         with self.assertRaises(ValueError):
-            nearest_neighbors(x, y, 0)
+            nearest_neighbors(x, y, 0, interpret=True)
         with self.assertRaises(ValueError):
-            nearest_neighbors(x, y, 6)
+            nearest_neighbors(x, y, 6, interpret=True)
 
     def test_dndarray_api_split_sweep(self):
+        """Both routes of the public entry — the kernel body (interpret,
+        forced by name) and the materializing comparator a CPU backend
+        dispatches by itself — against the same oracle."""
+        from heat_tpu.core.kernels import forced_mode
+
         rng = np.random.default_rng(23)
         x = rng.normal(size=(64, 8)).astype(np.float32)
         y = rng.normal(size=(96, 8)).astype(np.float32)
         ref_d, ref_i = _reference_knn(x, y, 3)
-        for sx in (None, 0):
-            for sy in (None, 0):
-                d, i = ht.spatial.nearest_neighbors(
-                    ht.array(x, split=sx), ht.array(y, split=sy), 3
-                )
-                self.assertEqual(d.split, sx)
-                self.assertEqual(i.split, sx)
-                np.testing.assert_array_equal(i.numpy(), ref_i)
-                np.testing.assert_allclose(d.numpy(), ref_d, rtol=1e-4, atol=1e-5)
+        for mode in ("interpret", "fallback"):
+            for sx in (None, 0):
+                for sy in (None, 0):
+                    with forced_mode("topk_distance", mode):
+                        d, i = ht.spatial.nearest_neighbors(
+                            ht.array(x, split=sx), ht.array(y, split=sy), 3
+                        )
+                    self.assertEqual(d.split, sx)
+                    self.assertEqual(i.split, sx)
+                    np.testing.assert_array_equal(i.numpy(), ref_i, mode)
+                    np.testing.assert_allclose(
+                        d.numpy(), ref_d, rtol=1e-4, atol=1e-5, err_msg=mode
+                    )
 
     def test_knn_classifier_fused_path_matches(self):
-        """Force the fused path and compare labels against the
-        materializing predict."""
+        """The fused route ``predict`` takes on a TPU backend — past the
+        nq*nt gate, kernel forced to interpret by name — against the
+        materializing predict a CPU backend dispatches by itself."""
         from heat_tpu.classification.kneighborsclassifier import KNeighborsClassifier
+        from heat_tpu.core.kernels import forced_mode, reset_kernel_stats
 
         rng = np.random.default_rng(31)
-        xt = rng.normal(size=(160, 6)).astype(np.float32)
-        yt = (rng.integers(0, 3, size=(160,))).astype(np.int32)
-        xq = rng.normal(size=(48, 6)).astype(np.float32)
+        nq, nt = 1024, 4100  # nq * nt just over the classifier's 2^22 gate
+        xt = rng.normal(size=(nt, 6)).astype(np.float32)
+        yt = (rng.integers(0, 3, size=(nt,))).astype(np.int32)
+        xq = rng.normal(size=(nq, 6)).astype(np.float32)
 
         clf = KNeighborsClassifier(n_neighbors=5).fit(ht.array(xt), ht.array(yt))
+        reset_kernel_stats()
         base = clf.predict(ht.array(xq)).numpy()
-
-        # the fused route the classifier takes on TPU, driven directly
-        # (interpret kernel on the CPU mesh), then the same one-hot vote
-        _, idx = ht.spatial.nearest_neighbors(ht.array(xq), ht.array(xt), 5)
-        votes = yt[idx.numpy()]
-        fused = np.array(
-            [np.bincount(row, minlength=3).argmax() for row in votes]
-        )
+        self.assertEqual(ht.KERNEL_STATS.get("topk_distance.fallback"), 1)
+        with forced_mode("topk_distance", "interpret"):
+            fused = clf.predict(ht.array(xq)).numpy()
+        self.assertEqual(ht.KERNEL_STATS.get("topk_distance.interpret"), 1)
         np.testing.assert_array_equal(base, fused)
 
 
@@ -195,18 +204,21 @@ class TestDispatchRegistry(TestCase):
         """The public nearest_neighbors entry reports its kernel-vs-
         fallback decision once per call (satellite: counter-assert the
         flash-kNN dispatch)."""
-        from heat_tpu.core.kernels import reset_kernel_stats
+        from heat_tpu.core.kernels import forced_mode, reset_kernel_stats
 
         rng = np.random.default_rng(3)
         x = ht.array(rng.normal(size=(32, 4)).astype(np.float32))
         y = ht.array(rng.normal(size=(48, 4)).astype(np.float32))
         reset_kernel_stats()
         ht.spatial.nearest_neighbors(x, y, 3)
-        # CPU mesh: compiled pallas unavailable -> the interpret route
-        self.assertEqual(ht.KERNEL_STATS["topk_distance.interpret"], 1)
+        # CPU mesh: no compiled pallas -> the registered fallback; the
+        # interpreter is never picked silently
+        self.assertEqual(ht.KERNEL_STATS["topk_distance.fallback"], 1)
         self.assertEqual(ht.KERNEL_STATS["dispatches"], 1)
-        ht.spatial.nearest_neighbors(x, y, 3)
-        self.assertEqual(ht.KERNEL_STATS["topk_distance.interpret"], 2)
+        with forced_mode("topk_distance", "interpret"):
+            ht.spatial.nearest_neighbors(x, y, 3)
+        self.assertEqual(ht.KERNEL_STATS["topk_distance.interpret"], 1)
+        self.assertNotIn("topk_distance.pallas", ht.KERNEL_STATS)
 
 
 class TestMomentsKernel(TestCase):
@@ -622,3 +634,61 @@ class TestCholKernel(TestCase):
 
 if __name__ == "__main__":
     unittest.main()
+
+
+def _fit_kmeans(f: int, k: int):
+    x = np.random.default_rng(5).normal(size=(k + 40, f)).astype(np.float32)
+    ht.cluster.KMeans(n_clusters=k, init=ht.array(x[:k].copy()), max_iter=2, tol=None).fit(ht.array(x, split=0))
+
+
+def _fit_streaming_kmeans(f: int, k: int):
+    x = np.random.default_rng(6).normal(size=(48, f)).astype(np.float32)
+    ht.cluster.StreamingKMeans(n_clusters=k, init=ht.array(x[:k].copy()), max_iter=1, tol=None).fit(
+        [ht.array(x[:24]), ht.array(x[24:])]
+    )
+
+
+def _mean_std(f: int, _k: int):
+    x = np.random.default_rng(7).normal(size=(40, f)).astype(np.float32)
+    xd = ht.array(x, split=0)
+    np.testing.assert_allclose(ht.mean(xd, axis=0).numpy(), x.mean(0), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(ht.std(xd, axis=0).numpy(), x.std(0), rtol=1e-4, atol=1e-5)
+
+
+def _streaming_moments(f: int, _k: int):
+    from heat_tpu.stream import StreamingMoments
+
+    x = np.random.default_rng(8).normal(size=(48, f)).astype(np.float32)
+    est = StreamingMoments()
+    for i in (0, 24):
+        est.update(ht.array(x[i:i + 24]))
+    np.testing.assert_allclose(est.mean.numpy(), x.mean(0), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "kernel, declined_as, run, f, k",
+    [
+        ("lloyd_fused", "fallback", _fit_kmeans, 65, 3),
+        ("lloyd_fused", "fallback", _fit_kmeans, 2, 1025),
+        ("lloyd_fused", "fallback", _fit_streaming_kmeans, 65, 3),
+        ("moments_onepass", "xla", _mean_std, 65, 0),
+        ("moments_onepass", "xla", _streaming_moments, 65, 0),
+    ],
+    ids=["kmeans-wide", "kmeans-many-centers", "streaming-kmeans-wide", "mean-std-wide", "streaming-moments-wide"],
+)
+def test_shapes_past_kernel_fits_are_declined_and_counted(kernel, declined_as, run, f, k):
+    """A shape the kernel was not compiled for (rows wider than the
+    chip keeps column-major, more centers than VMEM holds resident) never
+    reaches it: dispatch records the declared twin instead, and one
+    inside the bound still takes the kernel."""
+    from heat_tpu.core.kernels import forced_mode, reset_kernel_stats
+
+    with forced_mode(kernel, "interpret"):
+        reset_kernel_stats()
+        run(f, k)
+        stats = dict(ht.KERNEL_STATS)
+        assert stats.get(f"{kernel}.{declined_as}", 0) >= 1, stats
+        assert f"{kernel}.interpret" not in stats, stats
+        reset_kernel_stats()
+        run(min(f, 64), min(k, 3))
+        assert ht.KERNEL_STATS.get(f"{kernel}.interpret", 0) >= 1, dict(ht.KERNEL_STATS)

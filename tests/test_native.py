@@ -22,6 +22,37 @@ pytestmark = pytest.mark.skipif(
 )
 
 
+def test_rebuild_follows_source_content_not_mtime(tmp_path, monkeypatch):
+    """A copied checkout scrambles mtimes: the library is rebuilt when the
+    bytes of ``src/*.cpp`` change and only then, and a build that fails
+    says so instead of degrading in silence."""
+    import ctypes
+
+    src = tmp_path / "src"
+    src.mkdir()
+    cpp = src / "probe.cpp"
+    cpp.write_text('extern "C" int ht_probe() { return 1; }\n')
+    lib = tmp_path / "_probe.so"
+    monkeypatch.setattr(native, "_SRC_DIR", str(src))
+    monkeypatch.setattr(native, "_LIB_PATH", str(lib))
+    monkeypatch.setattr(native, "_DIGEST_PATH", str(lib) + ".sha256")
+    assert native._build()
+    # sources "newer" than the library, same bytes: the library stays
+    os.utime(lib, ns=(1, 1))
+    far = 4_000_000_000 * 10**9
+    os.utime(cpp, ns=(far, far))
+    assert native._build()
+    assert lib.stat().st_mtime_ns == 1
+    # sources "older" than the library, other bytes: rebuilt
+    cpp.write_text('extern "C" int ht_probe() { return 2; }\n')
+    os.utime(cpp, ns=(0, 0))
+    assert native._build()
+    assert ctypes.CDLL(str(lib)).ht_probe() == 2
+    cpp.write_text("this is not C++\n")
+    with pytest.warns(RuntimeWarning, match="building .* failed"):
+        assert not native._build()
+
+
 def _write_csv(path, arr, sep=",", header_lines=0, crlf=False, trailing_newline=True):
     eol = "\r\n" if crlf else "\n"
     with open(path, "w", newline="") as f:

@@ -291,6 +291,50 @@ class TestProfiling(TestCase):
         with ht.utils.profiling.annotate("region"):
             pass
 
+    def test_timer_stop_fences_what_it_is_given(self):
+        """``stop(x)`` is ``jax.block_until_ready`` on x's buffers —
+        DNDarrays and pytrees of them unwrapped — with no host fetch."""
+        from heat_tpu.analysis.sanitizer import sanitizer
+
+        x = ht.random.randn(256, 256, split=0)
+        t = ht.utils.profiling.Timer().start()
+        y = ht.matmul(x, x.T)
+        with sanitizer("timer fence") as region:
+            t.stop(y, {"also": [y.larray]})
+        self.assertTrue(y.larray.is_ready())
+        self.assertEqual(region.host_syncs, 0)
+        ht.utils.profiling.force_sync()  # nothing given: nothing to do
+
+    def test_compile_cache_dir_env_wins_else_fixed_path(self):
+        """JAX_COMPILATION_CACHE_DIR set: no directory is set in code.
+        Unset: <checkout>/.jax_cache, the same path on every call."""
+        import os
+        from unittest import mock
+
+        import jax
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from heat_tpu.utils.profiling import configure_compile_cache
+
+        names = ("jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes")
+        before = {n: getattr(jax.config, n) for n in names}
+        try:
+            with mock.patch.dict(os.environ, {"JAX_COMPILATION_CACHE_DIR": "/somewhere/else"}):
+                self.assertEqual(configure_compile_cache(), "/somewhere/else")
+                self.assertEqual(jax.config.jax_compilation_cache_dir, before[names[0]])
+            env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+            with mock.patch.dict(os.environ, env, clear=True):
+                path = configure_compile_cache()
+                self.assertEqual(path, configure_compile_cache())
+            root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            self.assertEqual(path, os.path.join(root, ".jax_cache"))
+            self.assertEqual(jax.config.jax_compilation_cache_dir, path)
+        finally:
+            for n, v in before.items():
+                jax.config.update(n, v)
+            compilation_cache.reset_cache()
+
 
 class TestLongContextGradients(TestCase):
     """Long-context training is first-class: both sequence-parallel

@@ -149,6 +149,38 @@ class TestDeviceSurface(TestCase):
         with pytest.raises(ValueError):
             ht.sanitize_device("quantum")
 
+    def test_failed_backend_probe_is_an_error_not_cpu(self):
+        """A backend probe that raises (JAX fails at start-up when the
+        platform it was told to use is missing) propagates — it is never
+        read as "no accelerator" — and the next lookup probes again."""
+        from unittest import mock
+
+        import jax
+
+        from heat_tpu.core import devices
+
+        with mock.patch.object(devices, "_accel_probed", False), \
+                mock.patch.object(devices, "_accel", None):
+            with mock.patch.object(jax, "default_backend", side_effect=RuntimeError("no TPU")):
+                with pytest.raises(RuntimeError, match="no TPU"):
+                    ht.sanitize_device("tpu")
+                assert not devices._accel_probed
+            with pytest.raises(ValueError):  # probed for real: a CPU backend has no 'tpu'
+                ht.sanitize_device("tpu")
+            assert devices._accel_probed
+
+    def test_entry_dry_run_refuses_more_devices_than_exist(self):
+        """Asking for more devices than are visible is an error: the entry
+        point never re-initialises JAX on another platform to find them."""
+        import jax
+
+        import __graft_entry__ as entry
+
+        before = jax.default_backend(), len(jax.devices())
+        with pytest.raises(RuntimeError, match="device"):
+            entry.dryrun_multichip(len(jax.devices()) + 1)
+        assert (jax.default_backend(), len(jax.devices())) == before
+
     def test_device_repr_fields(self):
         d = ht.sanitize_device("cpu")
         assert "cpu" in repr(d)

@@ -1,38 +1,39 @@
-"""Real-hardware smoke tests — run only when the default backend is TPU.
+"""Real-hardware smoke tests — skipped unless the default backend is a TPU.
 
 The CPU-mesh suite (conftest forces ``jax_platforms=cpu``) can never
-exercise the actual accelerator; VERDICT round 1 flagged that nothing
-but the benchmark touches real hardware. This file is the opt-in
-counterpart: run it WITHOUT the conftest override::
+exercise the actual accelerator. The proof that the main path runs on
+the chip is ``chip_smoke.py`` at the repo root (one process, real sizes,
+every kernel dispatched as ``.pallas``); this file is the small pytest
+counterpart for the numerics that differ on TPU silicon: bf16 MXU matmul
+error bounds, the f32 'highest' escape hatch, kmeans fit correctness,
+sort/percentile, and IO round-trip on device. Run it WITHOUT the conftest
+override::
 
     python -m pytest tests/test_tpu_smoke.py -q -p no:cacheprovider \
         --override-ini= -c /dev/null
 
-or simply ``python tests/test_tpu_smoke.py`` which self-hosts. It
-validates the numerics that differ on TPU silicon: bf16 MXU matmul
-error bounds, f32 'highest' precision escape hatch, kmeans fit
-correctness, sort/percentile, and IO round-trip on device.
+or simply ``python tests/test_tpu_smoke.py`` which self-hosts.
+
+The backend is probed inside a fixture, never while this module is
+imported: every xdist worker imports every test file, and a module that
+decides at import whether its tests exist can hand the workers different
+collections.
 """
-import os
 import sys
 
 import numpy as np
 import pytest
 
 
-def _on_tpu() -> bool:
+@pytest.fixture(scope="module")
+def tpu_backend():
     import jax
 
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs a real TPU backend")
 
 
-pytestmark = pytest.mark.skipif(not _on_tpu(), reason="needs a real TPU backend")
-
-
-def test_mxu_matmul_precision_bounds():
+def test_mxu_matmul_precision_bounds(tpu_backend):
     import jax
     import jax.numpy as jnp
 
@@ -56,7 +57,7 @@ def test_mxu_matmul_precision_bounds():
     np.testing.assert_allclose(got_hi, want, rtol=2e-5, atol=2e-5)
 
 
-def test_kmeans_fit_on_device():
+def test_kmeans_fit_on_device(tpu_backend):
     import heat_tpu as ht
 
     rng = np.random.default_rng(1)
@@ -70,7 +71,7 @@ def test_kmeans_fit_on_device():
         assert np.linalg.norm(found - c, axis=1).min() < 0.2
 
 
-def test_sort_and_percentile_on_device():
+def test_sort_and_percentile_on_device(tpu_backend):
     import heat_tpu as ht
 
     x = np.random.default_rng(2).normal(size=10_001).astype(np.float32)
@@ -83,7 +84,7 @@ def test_sort_and_percentile_on_device():
     )
 
 
-def test_io_roundtrip_on_device(tmp_path):
+def test_io_roundtrip_on_device(tpu_backend, tmp_path):
     import heat_tpu as ht
 
     x = ht.random.randn(1000, 8, split=0)
@@ -93,7 +94,7 @@ def test_io_roundtrip_on_device(tmp_path):
     np.testing.assert_allclose(back.numpy(), x.numpy(), rtol=1e-6)
 
 
-def test_reductions_match_host():
+def test_reductions_match_host(tpu_backend):
     import heat_tpu as ht
 
     x = np.random.default_rng(3).normal(size=(513, 9)).astype(np.float32)
