@@ -15,8 +15,11 @@ hang, a silent recompile storm, or a host-transfer stall at scale:
   class ``resilience/guard`` detects at runtime — this rule catches it
   at review time);
 - an implicit host sync (``np.asarray`` on a device value, ``.item()``,
-  ``jax.device_get``) in a hot path serializes the dispatch pipeline on
-  a device round-trip;
+  ``jax.device_get``, ``float()``/``int()`` of a jitted function's
+  result) in a hot path serializes the dispatch pipeline on a device
+  round-trip — and, made anywhere but ``core._hooks.fetch``, is a fetch
+  that ``COMPILE_STATS["host_syncs"]`` and the trace's ``ht.fetch:*``
+  spans never hear of;
 - iterating a ``set`` to build collective schedules or cache keys gives
   each host its own ordering (hash randomization) — ranks dispatch
   different programs;
@@ -97,10 +100,14 @@ RULES: Dict[str, Rule] = {
 
 TAG_TO_ID = {r.tag: r.id for r in RULES.values()}
 
-# G004 hot-path set: every parallel/ module plus the core modules on the
-# per-op dispatch path.  Cold modules (io, printing, manipulations' host
-# merges) do explicit, documented host work and are exempt; a new module
-# opts in with a file-level ``# graftlint: hot-path`` pragma.
+# G004 hot-path set: every parallel/ module, the analytics packages whose
+# public calls the chip benchmark times (their device reads go through
+# ``core._hooks.fetch``, which counts them and names them in a trace), plus
+# the core modules on the per-op dispatch path.  Cold modules (io, printing,
+# manipulations' host merges) do explicit, documented host work and are
+# exempt; a new module opts in with a file-level ``# graftlint: hot-path``
+# pragma.
+HOT_PACKAGES = ("parallel", "cluster", "spatial", "frame", "regression")
 HOT_CORE_MODULES = {
     "_operations.py", "_movement.py", "_dispatch.py", "arithmetics.py",
     "statistics.py", "relational.py", "logical.py", "rounding.py",
@@ -182,7 +189,7 @@ def _is_hot(path: str, pragmas: Set[str]) -> bool:
     if "hot-path" in pragmas:
         return True
     p = "/" + path.replace(os.sep, "/").lstrip("/")
-    if "/heat_tpu/parallel/" in p:
+    if any(f"/heat_tpu/{pkg}/" in p for pkg in HOT_PACKAGES):
         return True
     if "/heat_tpu/core/" in p and os.path.basename(p) in HOT_CORE_MODULES:
         return True
@@ -212,6 +219,26 @@ def _call_name(func: ast.expr) -> Optional[str]:
 
 def _is_jit(func: ast.expr) -> bool:
     return _call_name(func) == "jit"
+
+
+def _is_jit_decorator(dec: ast.expr) -> bool:
+    """``@jax.jit`` / ``@jit`` / ``@partial(jax.jit, ...)``."""
+    if isinstance(dec, ast.Call):
+        if _call_name(dec.func) == "partial" and dec.args:
+            return _is_jit(dec.args[0])
+        return _is_jit(dec.func)
+    return _is_jit(dec)
+
+
+def _numpy_rooted(node: ast.expr) -> bool:
+    """``np.inf``, ``np.iinfo(dt).max``: computed by numpy, so on the host."""
+    while isinstance(node, (ast.Attribute, ast.Call, ast.UnaryOp)):
+        node = (
+            node.func if isinstance(node, ast.Call)
+            else node.operand if isinstance(node, ast.UnaryOp)
+            else node.value
+        )
+    return isinstance(node, ast.Name) and node.id in ("np", "numpy")
 
 
 def _is_literal(node: ast.expr) -> bool:
@@ -265,6 +292,10 @@ class _Checker(ast.NodeVisitor):
         self._handled_jit_ids: Set[int] = set()
         self._seen: Set[Tuple[str, int, int]] = set()
         self._parents: Dict[int, ast.AST] = {}
+        # G004: the file's jitted functions, and per open function the
+        # names bound to what one of them returned
+        self._jitted: Set[str] = set()
+        self._jit_results: List[Set[str]] = []
 
     # -- plumbing -------------------------------------------------------------
     def check(self, tree: ast.Module) -> List[Finding]:
@@ -282,6 +313,10 @@ class _Checker(ast.NodeVisitor):
                         and isinstance(item.optional_vars, ast.Name)
                     ):
                         self._atomic_names.add(item.optional_vars.id)
+            if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)) and any(
+                _is_jit_decorator(d) for d in node.decorator_list
+            ):
+                self._jitted.add(node.name)
         self._check_module_caches(tree)
         self.visit(tree)
         return self.findings
@@ -318,7 +353,9 @@ class _Checker(ast.NodeVisitor):
         self._local_defs.append(local_defs)
         self._cache_decorated.append(cache_dec)
         self._local_sets.append(set())
+        self._jit_results.append(self._bound_to_jit_results(node))
         self.generic_visit(node)
+        self._jit_results.pop()
         self._func_stack.pop()
         self._local_defs.pop()
         self._cache_decorated.pop()
@@ -500,11 +537,37 @@ class _Checker(ast.NodeVisitor):
         self.generic_visit(node)
 
     # -- G004: implicit host syncs in hot paths -------------------------------
+    def _is_jitted_call(self, node: ast.expr) -> bool:
+        return isinstance(node, ast.Call) and _call_name(node.func) in self._jitted
+
+    def _bound_to_jit_results(self, fn) -> Set[str]:
+        """Names ``fn`` assigns from a call of one of the file's jitted
+        functions (``a, b = _fit(...)``): device values, whatever they hold."""
+        names: Set[str] = set()
+        for n in ast.walk(fn):
+            if isinstance(n, ast.Assign) and self._is_jitted_call(n.value):
+                for t in n.targets:
+                    elts = t.elts if isinstance(t, (ast.Tuple, ast.List)) else [t]
+                    names.update(e.id for e in elts if isinstance(e, ast.Name))
+        return names
+
     def _check_sync_call(self, node: ast.Call) -> None:
         if not self.hot:
             return
         f = node.func
         what = None
+        if (
+            isinstance(f, ast.Name)
+            and f.id in ("float", "int", "bool", "complex")
+            and len(node.args) == 1
+        ):
+            arg = node.args[0]
+            if self._is_jitted_call(arg) or (
+                isinstance(arg, ast.Name)
+                and self._jit_results
+                and arg.id in self._jit_results[-1]
+            ):
+                what = f"{f.id}() of a jitted function's result"
         if isinstance(f, ast.Attribute):
             if f.attr == "item" and not node.args:
                 what = ".item()"
@@ -518,6 +581,7 @@ class _Checker(ast.NodeVisitor):
                 and f.value.id in ("np", "numpy")
                 and node.args
                 and not _is_literal(node.args[0])
+                and not _numpy_rooted(node.args[0])
             ):
                 what = f"np.{f.attr} on a computed value"
         elif isinstance(f, ast.Name) and f.id == "device_get":
@@ -526,7 +590,8 @@ class _Checker(ast.NodeVisitor):
             self._emit(
                 "G004", node,
                 f"{what} in a hot path blocks dispatch on a device->host round "
-                "trip; keep the value on device, or waive an intentional sync "
+                "trip; keep the value on device, read it with core._hooks.fetch "
+                "(counted, and named in a trace), or waive an intentional sync "
                 "with '# graftlint: host-sync'",
             )
 

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import random as ht_random
-from ..core import types
+from ..core import _hooks, types
 from ..core._cache import ExecutableCache
 from ..core.base import BaseEstimator, ClusteringMixin
 from ..core.dndarray import DNDarray
@@ -231,12 +231,12 @@ class _KCluster(BaseEstimator, ClusteringMixin):
                 xd,
             )
             # the one host round-trip per chunk: the convergence decision
-            shift_val = float(jax.device_get(shift))
+            shift_val = float(_hooks.fetch(shift, "kcluster.shift"))
             new = dict(st)
             new["centers"] = DNDarray(c, split=None, device=xd.device, comm=xd.comm)
             new["labels"] = _wrap_labels(labels, xd)
             new["shift"] = shift_val
-            new["n_iter"] = st["n_iter"] + int(jax.device_get(iters))
+            new["n_iter"] = st["n_iter"] + int(_hooks.fetch(iters, "kcluster.iters"))
             return new, shift_val <= tol or new["n_iter"] >= max_iter
 
         result = supervisor.run(step_fn, state, data=(x,), label=label)

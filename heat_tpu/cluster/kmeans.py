@@ -17,7 +17,7 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 
-from ..core import types
+from ..core import _hooks, types
 from ..core.dndarray import DNDarray
 from ..spatial.distance import _quadratic_expand
 from ._kcluster import _BLOCK_PROGRAMS, _KCluster
@@ -210,11 +210,13 @@ class KMeans(_KCluster):
     def _finalize_supervised(self, result) -> None:
         x = result.data[0]  # on the final (possibly shrunken) mesh
         xa = self._prep_fit(x)
-        self._inertia = float(
+        self._inertia = float(_hooks.fetch(
             _inertia(xa, self._cluster_centers.larray.astype(xa.dtype),
-                     self.n_clusters, x.gshape[0])
-        )
+                     self.n_clusters, x.gshape[0]),
+            "kmeans.inertia",
+        ))
 
+    @_hooks.public_call("KMeans.fit")
     def fit(self, x: DNDarray, supervisor=None, block_iters: int = 16) -> "KMeans":
         """Lloyd iterations until the centroid shift drops below tol
         (reference ``kmeans.py:102-135``). With ``supervisor`` the fit
@@ -252,6 +254,6 @@ class KMeans(_KCluster):
             self._labels = DNDarray(
                 labels[:n], dtype=types.int64, split=x.split, device=x.device, comm=x.comm
             )
-        self._inertia = float(_inertia(xa, centers, k, n))
-        self._n_iter = int(n_iter)
+        self._inertia = float(_hooks.fetch(_inertia(xa, centers, k, n), "kmeans.inertia"))
+        self._n_iter = int(_hooks.fetch(n_iter, "kmeans.n_iter"))
         return self

@@ -12,7 +12,7 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 
-from ..core import types
+from ..core import _hooks, types
 from ..core.dndarray import DNDarray
 from ..spatial.distance import _manhattan as _l1_distance
 from ._kcluster import _BLOCK_PROGRAMS, _KCluster, _block_fit
@@ -104,7 +104,7 @@ class KMedoids(_KCluster):
         centers = self._initialize_cluster_centers(x).astype(xa.dtype)
 
         centers, labels, n_iter = _medoid_fit(xa, centers, k, jnp.int32(self.max_iter))
-        n_iter = int(n_iter)
+        n_iter = int(_hooks.fetch(n_iter, "kmedoids.n_iter"))
 
         self._cluster_centers = DNDarray(centers, split=None, device=x.device, comm=x.comm)
         self._labels = DNDarray(

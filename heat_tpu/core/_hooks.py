@@ -9,14 +9,25 @@ injector for the duration of a ``with`` block, which lets every recovery
 path (retry, atomic rename, checksum verification) be exercised
 deterministically on CPU.
 
-This module is dependency-free on purpose: ``core`` must not import
-``resilience`` at module scope (resilience sits above core), so the
+This module imports nothing of ``heat_tpu`` on purpose: ``core`` must not
+import ``resilience`` at module scope (resilience sits above core), so the
 registry lives down here and chaos reaches down to install itself.
+
+The same hook carries the program's spans (:func:`span`,
+:func:`public_call`, :func:`fetch`): a span is a
+``jax.profiler.TraceAnnotation``, which the profiler writes on the host
+plane on the clock of the device's events while a trace is being taken
+(``ht.utils.profiling.trace``) and which is one object and one flag test
+otherwise. Nothing else stores, exports or switches them.
 """
 from __future__ import annotations
 
+import functools as _functools
+import itertools as _itertools
 import threading as _threading
 from typing import Callable, Dict, Optional
+
+import jax as _jax
 
 # the active injector: fn(name, ctx) -> None, may raise to simulate a
 # fault and may mutate ``ctx`` values in place (e.g. corrupt a byte
@@ -189,3 +200,60 @@ def observe(event: str, **ctx) -> None:
     if _OBSERVERS:
         for fn in tuple(_OBSERVERS):
             fn(event, ctx)
+
+
+# spans: the three families a trace of the program shows, named at the
+# layer boundaries (docs/PERFORMANCE.md, "Reading a trace of your own
+# program"): ``ht.call:<public call>`` around an entry point,
+# ``ht.fetch:<site>`` around a device -> host read, ``ht.exchange:<kind>``
+# around the host's part of a data movement. ``_CALL`` is the open public
+# call of this thread (depth, and the number every span of one request
+# carries); like ``_TRACE_SAFE`` it is per thread, so a serving thread's
+# calls do not renumber a client's.
+_CALL = _threading.local()
+_CALL_NUMBERS = _itertools.count(1)
+
+
+def span(name: str, **attrs):
+    """A named region of the profiler's host plane: a context manager
+    that records ``name`` (and ``attrs``, plus ``call=<n>`` inside a
+    :func:`public_call`) while a trace is being taken and nothing
+    otherwise. Public as ``ht.utils.profiling.annotate``."""
+    if getattr(_CALL, "depth", 0):
+        attrs.setdefault("call", _CALL.n)
+    return _jax.profiler.TraceAnnotation(name, **attrs)
+
+
+def public_call(name: str):
+    """Decorator of a public entry point: its body runs inside the span
+    ``ht.call:<name>`` with ``call=<n>``. Only the outermost public call
+    of a thread draws a new ``n`` from the process-wide counter; one made
+    inside it opens a child span with the same ``n``."""
+    label = "ht.call:" + name
+
+    def decorate(fn):
+        @_functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            depth = getattr(_CALL, "depth", 0)
+            if not depth:
+                _CALL.n = next(_CALL_NUMBERS)
+            _CALL.depth = depth + 1
+            try:
+                with span(label):
+                    return fn(*args, **kwargs)
+            finally:
+                _CALL.depth = depth
+
+        return wrapper
+
+    return decorate
+
+
+def fetch(x, site: str):
+    """Read a device value (an array or a pytree of them) to the host:
+    the one way the library does it on a call path. Raises the
+    ``host.fetch`` event, which ``COMPILE_STATS["host_syncs"]`` counts,
+    and blocks inside the span ``ht.fetch:<site>``."""
+    observe("host.fetch", site=site)
+    with span("ht.fetch:" + site):
+        return _jax.device_get(x)

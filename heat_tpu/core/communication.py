@@ -708,7 +708,8 @@ def tree_merge(state, combine, *, label: str = "collective.tree_merge", active: 
         MOVE_STATS["tree_merge_rounds"] += tree_merge_rounds(nproc)
         return out
 
-    merged = _hooks.guarded_call(label, impl)
+    with _hooks.span("ht.exchange:tree_merge", p=nproc):
+        merged = _hooks.guarded_call(label, impl)
     return collective_lockstep(merged)
 
 

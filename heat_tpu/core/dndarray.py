@@ -810,6 +810,10 @@ class DNDarray:
         allgather of the valid local blocks (every process must call —
         collective, like the reference's ``resplit(None)`` gather)."""
         _hooks.observe("host.gather", shape=self.__gshape)
+        with _hooks.span("ht.fetch:dndarray.gather"):
+            return self._gather_to_host()
+
+    def _gather_to_host(self) -> np.ndarray:
         buf = self.larray
         if getattr(buf, "is_fully_addressable", True):
             host = np.asarray(jax.device_get(buf))
@@ -892,9 +896,10 @@ class DNDarray:
     def item(self):
         """Scalar extraction (reference ``dndarray.py:955``)."""
         _hooks.observe("host.item")
-        if self.padded:
-            return self._logical().item()
-        return self.__array.item()
+        with _hooks.span("ht.fetch:dndarray.item"):
+            if self.padded:
+                return self._logical().item()
+            return self.__array.item()
 
     def __bool__(self) -> bool:
         return bool(self.__cast(bool))
@@ -911,7 +916,8 @@ class DNDarray:
     def __cast(self, cast_function):
         if np.prod(self.shape) == 1:
             _hooks.observe("host.scalar")
-            return cast_function(self._logical().reshape(()).item())
+            with _hooks.span("ht.fetch:dndarray.scalar"):
+                return cast_function(self._logical().reshape(()).item())
         raise TypeError("only size-1 arrays can be converted to Python scalars")
 
     def __len__(self) -> int:

@@ -55,6 +55,7 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
+from ..core import _hooks
 from ..core._cache import ExecutableCache
 from ..core.communication import SPLIT_AXIS, MeshCommunication, collective_lockstep
 from ..core.dndarray import DNDarray
@@ -232,7 +233,7 @@ def _plan_executable(
         return fn
     b = pshape[0] // p
 
-    def kernel(kb, counts, *vals):
+    def frame_plan(kb, counts, *vals):
         r = lax.axis_index(SPLIT_AXIS)
         n = counts[r]
         pad = lax.iota(jnp.int32, b) >= n
@@ -267,7 +268,7 @@ def _plan_executable(
     spec = P(SPLIT_AXIS)
     in_specs = (spec, P(), *([spec] * len(val_dtypes)))
     out_specs = (spec, *([spec] * len(stats)), P(), P())
-    prog = shard_map(kernel, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    prog = shard_map(frame_plan, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     fn = _PROGRAMS[key] = jax.jit(prog)
     return fn
 
@@ -289,7 +290,7 @@ def _merge_executable(
         return fn
     b = pshape[0] // p
 
-    def kernel(kb, counts, *parts):
+    def frame_merge(kb, counts, *parts):
         r = lax.axis_index(SPLIT_AXIS)
         n = counts[r]
         pad = lax.iota(jnp.int32, b) >= n
@@ -307,7 +308,7 @@ def _merge_executable(
     spec = P(SPLIT_AXIS)
     in_specs = (spec, P(), *([spec] * len(stats)))
     out_specs = (spec, *([spec] * len(stats)), P())
-    prog = shard_map(kernel, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    prog = shard_map(frame_merge, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     fn = _PROGRAMS[key] = jax.jit(prog)
     return fn
 
@@ -327,7 +328,7 @@ def _elect_executable(
         return fn
     nbufs = len(pshapes)
 
-    def kernel(*args):
+    def frame_elect(*args):
         blocks, counts = args[:nbufs], args[nbufs:]
         r = lax.axis_index(SPLIT_AXIS)
         mk = jnp.asarray(_max_key(blocks[0].dtype))
@@ -350,7 +351,7 @@ def _elect_executable(
 
     spec = P(SPLIT_AXIS)
     in_specs = tuple([spec] * nbufs + [P()] * nbufs)
-    prog = shard_map(kernel, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False)
+    prog = shard_map(frame_elect, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False)
     fn = _PROGRAMS[key] = jax.jit(prog)
     return fn
 
@@ -373,7 +374,7 @@ def _partition_executable(
         return fn
     b = pshape[0] // p
 
-    def kernel(kb, counts, splitters, *vals):
+    def frame_partition(kb, counts, splitters, *vals):
         r = lax.axis_index(SPLIT_AXIS)
         n = counts[r]
         pad = lax.iota(jnp.int32, b) >= n
@@ -391,7 +392,7 @@ def _partition_executable(
     spec = P(SPLIT_AXIS)
     in_specs = (spec, P(), P(), *([spec] * len(payload_dtypes)))
     out_specs = (spec, *([spec] * len(payload_dtypes)), P())
-    prog = shard_map(kernel, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    prog = shard_map(frame_partition, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     fn = _PROGRAMS[key] = jax.jit(prog)
     return fn
 
@@ -417,7 +418,7 @@ def _join_executable(
     bl = l_pshape[0] // p
     br = r_pshape[0] // p
 
-    def kernel(lk, lcnt, *rest):
+    def frame_join(lk, lcnt, *rest):
         rk, rcnt = rest[len(l_dtypes)], rest[len(l_dtypes) + 1]
         lvals = list(rest[: len(l_dtypes)])
         rvals = list(rest[len(l_dtypes) + 2 :])
@@ -464,7 +465,7 @@ def _join_executable(
     out_specs = (
         spec, *([spec] * (len(l_dtypes) + len(r_dtypes))), P(), P(),
     )
-    prog = shard_map(kernel, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    prog = shard_map(frame_join, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     fn = _PROGRAMS[key] = jax.jit(prog)
     return fn
 
@@ -484,7 +485,7 @@ def _compact_executable(
         return fn
     b = pshape[0] // p
 
-    def kernel(mask, counts, *cols):
+    def frame_compact(mask, counts, *cols):
         r = lax.axis_index(SPLIT_AXIS)
         n = counts[r]
         valid = lax.iota(jnp.int32, b) < n
@@ -498,7 +499,7 @@ def _compact_executable(
     spec = P(SPLIT_AXIS)
     in_specs = (spec, P(), *([spec] * len(dtypes)))
     out_specs = (*([spec] * len(dtypes)), P())
-    prog = shard_map(kernel, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    prog = shard_map(frame_compact, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     fn = _PROGRAMS[key] = jax.jit(prog)
     return fn
 
@@ -549,7 +550,7 @@ def groupby_reduce(
     pk, parts, mat = out[0], list(out[1 : 1 + len(stats)]), out[-2]
     # the replicated bucket matrix comes to host to build the static
     # exchange schedule — same bounded sync as redistribute_'s target map
-    mat_np = np.asarray(mat)
+    mat_np = _hooks.fetch(mat, "groupby.bucket_matrix")
     moved, out_counts, b_out = _exchange_operands([pk, *parts], mat_np, comm)
     merge = _merge_executable(
         (p * b_out,),
@@ -559,7 +560,7 @@ def groupby_reduce(
         comm,
     )
     mout = collective_lockstep(merge(moved[0], _counts_vec(out_counts), *moved[1:]))
-    gvec = np.asarray(mout[-1])
+    gvec = _hooks.fetch(mout[-1], "groupby.group_counts")
     n_groups = int(gvec.sum())
     mkeys = DNDarray._from_ragged(
         mout[0], (n_groups,), mout[0].dtype, 0, tuple(int(c) for c in gvec),
@@ -600,7 +601,7 @@ def shuffle_rows(
         tuple(str(b.dtype) for b in payload_bufs), p, mode, comm,
     )
     out = collective_lockstep(part(kb, counts, splitters, *payload_bufs))
-    mat_np = np.asarray(out[-1])
+    mat_np = _hooks.fetch(out[-1], "shuffle.bucket_matrix")
     moved, out_counts, b_out = _exchange_operands(list(out[:-1]), mat_np, comm)
     return moved, out_counts, b_out
 
@@ -649,8 +650,8 @@ def hash_join(
             r_moved[0], _counts_vec(r_counts), *r_moved[1:],
         )
     )
-    dup = int(np.asarray(out[-1]))
-    gvec = np.asarray(out[-2])
+    dup = int(_hooks.fetch(out[-1], "shuffle.join_dup"))
+    gvec = _hooks.fetch(out[-2], "shuffle.join_counts")
     SHUFFLE_STATS["joins"] += 1
     return list(out[:-2]), gvec, dup
 
@@ -667,6 +668,6 @@ def compact_rows(
         tuple(mask_buf.shape), tuple(str(b.dtype) for b in col_bufs), comm.size, comm
     )
     out = collective_lockstep(fn(mask_buf, _counts_vec(counts), *col_bufs))
-    gvec = np.asarray(out[-1])
+    gvec = _hooks.fetch(out[-1], "shuffle.compact_counts")
     SHUFFLE_STATS["compactions"] += 1
     return list(out[:-1]), gvec

@@ -21,7 +21,7 @@ from typing import Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
 
-from ..core import factories, types
+from ..core import _hooks, factories, types
 from ..core.dndarray import DNDarray
 from ._shuffle import SHUFFLE_STATS, compact_rows, hash_join, shard_counts
 
@@ -101,7 +101,7 @@ class Frame:
     def to_dict(self) -> Dict[str, np.ndarray]:
         """Materialize every column as a host numpy array (logical rows,
         ragged padding trimmed). Test/debug convenience — syncs."""
-        return {name: np.asarray(c._logical()) for name, c in self._cols.items()}
+        return _hooks.fetch({name: c._logical() for name, c in self._cols.items()}, "frame.to_dict")
 
     # ----------------------------------------------------------------- verbs
     def groupby(self, key: str, mode: str = "range"):

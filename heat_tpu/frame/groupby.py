@@ -26,6 +26,7 @@ from typing import Dict, List, Mapping, Sequence, Tuple, Union
 
 import numpy as np
 
+from ..core import _hooks
 from ..core.dndarray import DNDarray
 from ._shuffle import groupby_reduce
 
@@ -63,6 +64,7 @@ class FrameGroupBy:
         self._mode = mode
 
     # ------------------------------------------------------------- plan+run
+    @_hooks.public_call("groupby.agg")
     def agg(self, spec: AggSpec, ddof: int = 1):
         """Aggregate value columns per distinct key.
 
@@ -238,7 +240,7 @@ class FrameGroupBy:
         for c in value_cols:
             _, _, vals, wts = merged[c]
             res = kll._grouped_quantile(vals, wts, qs)[:, 0]
-            out[c] = np.asarray(res)  # graftlint: host-sync - O(G) finalize
+            out[c] = _hooks.fetch(res, "groupby.finalize")  # O(G) finalize
         from .frame import Frame
 
         return Frame(

@@ -180,8 +180,13 @@ def _fetch_found(data: jax.Array, counts: jax.Array, comm: MeshCommunication):
     global, so per-rank counts are read from its addressable shards, not
     a device_get of the whole vector). The cross-process candidate merge
     happens in the callers' existing allgather step."""
-    per_rank = {}
     _hooks.observe("host.fetch_found")
+    with _hooks.span("ht.fetch:dscan.found"):
+        return _found_parts(data, counts, comm)
+
+
+def _found_parts(data: jax.Array, counts: jax.Array, comm: MeshCommunication):
+    per_rank = {}
     for s in counts.addressable_shards:
         start = s.index[0].start or 0
         # graftlint: host-sync - O(world) count vector, fetched once per scan

@@ -411,11 +411,12 @@ def ragged_move(
     partitions (see :func:`ragged_move_executable`). Watchdog-bounded
     (label ``flatmove.ragged``) when ``resilience.deadlines`` is active."""
     _hooks.trace_barrier("ragged_move")
-    fn = ragged_move_executable(
-        tuple(buf.shape), buf.dtype, split, in_counts, out_counts, b_out, comm
-    )
-    MOVE_STATS["ragged_moves"] += 1
-    return _bounded_exchange("ragged", fn, buf)
+    with _hooks.span("ht.exchange:ragged_move", p=comm.size):
+        fn = ragged_move_executable(
+            tuple(buf.shape), buf.dtype, split, in_counts, out_counts, b_out, comm
+        )
+        MOVE_STATS["ragged_moves"] += 1
+        return _bounded_exchange("ragged", fn, buf)
 
 
 def bucket_move_executable(
@@ -490,12 +491,13 @@ def bucket_move(
     bounded (label ``flatmove.bucket``) when ``resilience.deadlines`` is
     active."""
     _hooks.trace_barrier("bucket_move")
-    fn = bucket_move_executable(
-        tuple(buf.shape), buf.dtype, split, matrix, b_out, comm
-    )
-    MOVE_STATS["ragged_moves"] += 1
-    MOVE_STATS["bucket_moves"] += 1
-    return _bounded_exchange("bucket", fn, buf)
+    with _hooks.span("ht.exchange:bucket_move", p=comm.size):
+        fn = bucket_move_executable(
+            tuple(buf.shape), buf.dtype, split, matrix, b_out, comm
+        )
+        MOVE_STATS["ragged_moves"] += 1
+        MOVE_STATS["bucket_moves"] += 1
+        return _bounded_exchange("bucket", fn, buf)
 
 
 def _t_interval(lo: int, hi: int, start: int, step: int, m: int) -> Tuple[int, int]:

@@ -14,7 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..core import types
+from ..core import _hooks, types
 from ..core._cache import ExecutableCache
 from ..core.base import BaseEstimator, RegressionMixin
 from ..core.communication import collective_lockstep
@@ -227,12 +227,12 @@ class Lasso(BaseEstimator, RegressionMixin):
                 jnp.int32(budget),
                 jnp.asarray(st["diff"], X.dtype),
             )
-            diff_val = float(jax.device_get(diff))
+            diff_val = float(_hooks.fetch(diff, "lasso.diff"))
             new = dict(st)
             new["theta"] = DNDarray(th.reshape(-1, 1), split=None,
                                     device=xd.device, comm=xd.comm)
             new["diff"] = diff_val
-            new["n_iter"] = st["n_iter"] + int(jax.device_get(sweeps))
+            new["n_iter"] = st["n_iter"] + int(_hooks.fetch(sweeps, "lasso.sweeps"))
             return new, diff_val < tol or new["n_iter"] >= max_iter
 
         result = supervisor.run(step_fn, state, data=(x, y), label="lasso.fit")
@@ -265,7 +265,7 @@ class Lasso(BaseEstimator, RegressionMixin):
             jnp.asarray(self.tol, X.dtype),
             jnp.int32(self.max_iter),
         )
-        self.n_iter = int(n_iter)
+        self.n_iter = int(_hooks.fetch(n_iter, "lasso.n_iter"))
         self.__theta = DNDarray(theta.reshape(-1, 1), split=None, device=x.device, comm=x.comm)
         return self
 

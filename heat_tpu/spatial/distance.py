@@ -21,7 +21,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from ..core import sanitation, types
+from ..core import _hooks, sanitation, types
 from ..core.dndarray import DNDarray
 from ..core.linalg.basics import _wrap_result
 
@@ -142,6 +142,7 @@ def _dist(x: DNDarray, y: Optional[DNDarray], metric: Callable, use_ring: bool =
     return _wrap_result(result, out_gshape, out_split, promoted, x.device, x.comm)
 
 
+@_hooks.public_call("cdist")
 def cdist(
     X: DNDarray,
     Y: Optional[DNDarray] = None,

@@ -15,6 +15,8 @@ from typing import Optional
 
 import jax
 
+from ..core import _hooks
+
 __all__ = ["trace", "annotate", "force_sync", "Timer", "configure_compile_cache"]
 
 
@@ -63,9 +65,10 @@ def trace(log_dir: str, create_perfetto_link: bool = False):
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named region that shows up inside a :func:`trace` capture."""
-    return jax.profiler.TraceAnnotation(name)
+# a named region inside a :func:`trace` capture: ``with annotate("load"):``.
+# The library's own spans (``ht.call:*``, ``ht.fetch:*``, ``ht.exchange:*``)
+# are made by the same function.
+annotate = _hooks.span
 
 
 class Timer:

@@ -1,4 +1,4 @@
-# graftlint-fixture: G004=4
+# graftlint-fixture: G004=6
 # graftflow-fixture: F001=0
 # graftlint: hot-path
 """True positives for G004: implicit host syncs on a hot path.
@@ -6,6 +6,8 @@
 The pragma above opts this file into the hot-path set (in the real tree
 that set is parallel/** plus the core dispatch modules).
 """
+from functools import partial
+
 import jax
 import numpy as np
 
@@ -25,3 +27,17 @@ def device_get_sync(x):
 def block_sync(x):
     x.block_until_ready()
     return x
+
+
+@partial(jax.jit, static_argnames=("k",))
+def _fit(x, k):
+    return x * k, x.sum()
+
+
+def scalar_of_a_jit_call(x):
+    return float(_fit(x, 2)[1].sum()), float(_fit(x, 2))  # the second is the fetch nobody counts
+
+
+def scalar_of_a_name_bound_to_a_jit_result(x):
+    centers, n_iter = _fit(x, 2)
+    return centers, int(n_iter)
