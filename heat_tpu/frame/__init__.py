@@ -3,7 +3,7 @@
 A :class:`Frame` is a thin dict of named, equal-length, co-sharded
 split-0 DNDarray columns. Its verbs — ``groupby(key).agg(...)``,
 ``value_counts``, ``join``, ``filter`` — all follow one shape: *local
-segment-reduce per shard → ONE bounded bucketed exchange per operand →
+sort and scan of equal keys per shard → ONE bounded bucketed exchange per operand →
 local merge*, built on the sample-sort splitter election and the
 ``bucket_move`` collective (see :mod:`heat_tpu.frame._shuffle` for the
 engine and :mod:`heat_tpu.parallel.flatmove` for the exchange). There is

@@ -7,25 +7,38 @@ becomes device-local afterwards. MPI frameworks express this as one
 shapes, so the TPU-native formulation splits the same work into three
 cached jitted programs plus ONE bounded bucketed exchange per operand:
 
-1. **plan** (one program): locally sort rows by key (pads last), fold
-   duplicate keys with a segment-reduce into per-shard *partials* (at
-   most one row per distinct local key — the combiner that makes low
-   cardinality cheap), elect range splitters from per-shard key samples
-   via one ``all_gather`` (replicated by construction — every device
-   computes identical splitters, the sample-sort election), tag each
-   partial with its destination partition, sort by destination, and
-   ``all_gather`` the per-destination counts into the replicated P×P
-   bucket matrix.
+1. **plan** (one program): locally sort rows by key (pads last) with
+   the value columns carried as operands of the sort, fold each run of
+   equal keys into its last row with a segmented scan — per-shard
+   *partials*, at most one row per distinct local key, the combiner that
+   makes low cardinality cheap — elect range splitters from per-shard
+   key samples via one ``all_gather`` (replicated by construction —
+   every device computes identical splitters, the sample-sort election),
+   tag each run's last row with its destination partition and every
+   other row with the one past the last, sort once more by (destination,
+   key) carrying the totals, and ``all_gather`` the per-destination
+   counts into the replicated P×P bucket matrix.
 2. **exchange**: the host materializes the (tiny) bucket matrix — the
    same bounded host sync ``redistribute_`` performs for its target
    map — and dispatches :func:`heat_tpu.parallel.flatmove.bucket_move`
    once per operand column: colored ``ppermute`` matchings, counted in
    ``MOVE_STATS``, watchdog-bounded. No per-key traffic, ever.
-3. **merge** (one program): locally sort the received partials by key
-   and segment-reduce again with each statistic's combiner (sums add,
-   counts add, mins min, maxs max) — legal because every statistic
-   carried here is associative and commutative, the same contract as
+3. **merge** (one program): the same three steps on the received
+   partials — sort by key carrying them, scan with each statistic's
+   combiner (sums add, counts add, mins min, maxs max), run ends to the
+   front — legal because every statistic carried here is associative
+   and commutative, the same contract as
    :class:`heat_tpu.stream.StreamingMoments.merge`.
+
+No program moves a block-long column through an index vector: on a v5e a
+gather or scatter of a 1e8-row column ran at 0.21 GB/s, bound by how the
+chip executes indexed access and not by its memory, and the twelve of
+them in the plan were 87 % of a 17.9 s groupby (PERF.md §6, PR 25). A
+column moves as an operand of a sort the program runs anyway
+(:func:`_carry_sort`), equal keys fold by comparing neighbours
+(:func:`_fold_runs`), and a compaction is a sort on a small leading key.
+The indexed reads left are the election's 32 samples and the join's
+lookup of each left row's match, which is the join.
 
 Partition decisions are REPLICATED at every step: splitters come out of
 an ``all_gather`` inside the program, bucket matrices are identical on
@@ -105,29 +118,54 @@ def _max_key(dtype) -> np.ndarray:
     return np.asarray(np.iinfo(dt).max, dt)
 
 
-def _neutral(kind: str, dtype) -> np.ndarray:
+def _last_key(dtype) -> np.ndarray:
+    """A key no valid key sorts after: NaN for floats (``lax.sort`` puts
+    every NaN, as one class, behind +inf), the maximum otherwise."""
     dt = np.dtype(dtype)
-    if kind in ("sum", "sumsq", "count"):
-        return np.asarray(0, dt)
-    if kind == "min":
-        return _max_key(dt)
-    if dt.kind == "f":
-        return np.asarray(-np.inf, dt)
-    if dt.kind == "b":
-        return np.asarray(False)
-    return np.asarray(np.iinfo(dt).min, dt)
+    return np.asarray(np.nan, dt) if dt.kind == "f" else _max_key(dt)
 
 
-def _sort_by_key(keys, pad, payloads):
-    """Stable local sort: pads last, then ascending key (lax.sort's total
-    order — NaN last), ties by position. Returns (sorted_keys,
-    sorted_pad, sorted_payloads)."""
-    b = keys.shape[0]
-    iota = lax.iota(jnp.int32, b)
-    k = keys.astype(jnp.int8) if keys.dtype == jnp.bool_ else keys
-    ops = lax.sort((pad.astype(jnp.int32), k, iota), num_keys=3, is_stable=True)
-    perm = ops[2]
-    return keys[perm], ops[0].astype(jnp.bool_), [v[perm] for v in payloads]
+def _carry_sort(leads, cols, stable: bool):
+    """``lax.sort`` by the ``leads`` alone, with ``cols`` riding as the
+    sort's own operands: no permutation comes out and no column goes
+    through one (an indexed read of a 1e8-row column ran at 0.21 GB/s on
+    a v5e, PERF.md §6 PR 25). Returns the sorted leads and cols, in that
+    order."""
+    ops = [*leads, *cols]
+    out = lax.sort(
+        [o.astype(jnp.int8) if o.dtype == jnp.bool_ else o for o in ops],
+        num_keys=len(leads), is_stable=stable,
+    )
+    return [r.astype(o.dtype) for r, o in zip(out, ops)]
+
+
+def _sort_by_key(keys, n, payloads):
+    """Stable local sort of a block's first ``n`` rows by key (lax.sort's
+    total order: NaN last), the payload columns carried. The pads sit
+    behind the valid rows already, so giving them the last key keeps them
+    there, behind a valid row with that very key too: the rows valid
+    after the sort are again the first ``n``. Returns (sorted_keys,
+    sorted_payloads); the pads' keys come back as the last key."""
+    valid = lax.iota(jnp.int32, keys.shape[0]) < n
+    k = jnp.where(valid, keys, jnp.asarray(_last_key(keys.dtype)))
+    sk, *spay = _carry_sort([k], payloads, stable=True)
+    return sk, spay
+
+
+def _partition_front(dest, cols):
+    """Stable partition: rows in ascending ``dest`` (a small integer a
+    row), their order kept within each value. Returns the moved cols."""
+    return _carry_sort([dest], cols, stable=True)[1:]
+
+
+def _ends_first(pid, p: int, sk, totals):
+    """The compaction: each destination's group totals contiguous and in
+    key order, ahead of every row that is no group's (``pid == p``).
+    Among the run ends of one destination the keys differ, so (pid, key)
+    orders them fully and the sort needs no stability, which spares the
+    index operand a stable one carries. Returns (keys, *totals)."""
+    pid = pid.astype(jnp.int8 if p <= jnp.iinfo(jnp.int8).max else jnp.int32)
+    return _carry_sort([pid, sk], totals, stable=False)[1:]
 
 
 def _hash_pid(keys, p: int):
@@ -153,55 +191,85 @@ def _range_pid(keys, splitters):
     partitions cover contiguous key ranges in rank order."""
     k = keys.astype(jnp.int8) if keys.dtype == jnp.bool_ else keys
     s = splitters.astype(k.dtype) if splitters.dtype != k.dtype else splitters
-    return jnp.searchsorted(s, k, side="right").astype(jnp.int32)
+    # one comparison a splitter: the binary search would read the P-1
+    # splitters through a block-long index vector
+    return jnp.searchsorted(s, k, side="right", method="compare_all").astype(jnp.int32)
 
 
-def _elect(sorted_keys, sorted_pad, n, p: int):
-    """Range splitters from one locally sorted key block: s evenly spaced
-    samples per shard (pads replaced by the max key so empty shards do
-    not skew downward), one all_gather, sort, take the P-1 quantiles.
+def _sample_ranks(n, b: int):
+    """The ranks at which a shard's ``n`` sorted keys are sampled for the
+    election, clipped into its block of ``b`` rows."""
+    return jnp.clip((lax.iota(jnp.int32, _OVERSAMPLE) * n) // jnp.maximum(n, 1), 0, b - 1)
+
+
+def _splitters(samples, p: int):
+    """Range splitters from every shard's key samples (a shard short of
+    keys fills up with the max key, so an empty shard does not skew the
+    splitters downward): one all_gather, sort, take the P-1 quantiles.
     Replicated by construction — every device computes the same values."""
-    b = sorted_keys.shape[0]
-    mk = jnp.asarray(_max_key(sorted_keys.dtype))
-    sk = jnp.where(sorted_pad, mk, sorted_keys)
-    idx = jnp.clip((lax.iota(jnp.int32, _OVERSAMPLE) * n) // jnp.maximum(n, 1), 0, b - 1)
-    smp = jnp.where(n > 0, sk[idx], jnp.full((_OVERSAMPLE,), mk))
-    g = lax.all_gather(smp, SPLIT_AXIS, tiled=True)
-    gs = jnp.sort(g)
-    m = gs.shape[0]
-    pos = (jnp.arange(1, p) * m) // p
-    return gs[pos]
+    gs = jnp.sort(lax.all_gather(samples, SPLIT_AXIS, tiled=True))
+    return gs[(jnp.arange(1, p) * gs.shape[0]) // p]
 
 
-def _segments(sorted_keys, valid):
-    """(is_start, segment_ids, n_segments) of equal-key runs in a sorted
-    block; invalid rows get the out-of-range segment (dropped by the
-    segment reducers)."""
-    b = sorted_keys.shape[0]
-    prev = jnp.concatenate([sorted_keys[:1], sorted_keys[:-1]])
-    first = lax.iota(jnp.int32, b) == 0
-    is_start = valid & (first | (sorted_keys != prev))
-    seg = jnp.cumsum(is_start.astype(jnp.int32)) - 1
-    segv = jnp.where(valid, seg, b)
-    return is_start, segv, jnp.sum(is_start.astype(jnp.int32))
+_COMBINE = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
 
 
-def _segment_reduce(kind: str, data, valid, segv, b: int):
-    neutral = jnp.asarray(_neutral(kind, data.dtype))
-    masked = jnp.where(valid, data, neutral)
-    if STAT_COMBINE[kind] == "sum":
-        return jax.ops.segment_sum(masked, segv, num_segments=b)
-    if STAT_COMBINE[kind] == "min":
-        return jax.ops.segment_min(masked, segv, num_segments=b)
-    return jax.ops.segment_max(masked, segv, num_segments=b)
+def _fold_runs(sk, n, cols, kinds):
+    """Inclusive scan of each column within the runs of equal keys among a
+    sorted block's first ``n`` rows, ``kinds[j]``'s combiner on
+    ``cols[j]``: afterwards the last row of a run holds the group's total.
+    Returns (keys, totals, is_end), ``is_end`` marking those last rows.
+    Rows i and i-d belong to one group exactly when their keys are equal
+    (the block is sorted), so doubling d folds a run of length L in
+    ceil(log2 L) elementwise passes, and once no pair at distance d is
+    equal none is at 2d: the loop reads its length from the keys, on the
+    device. Each step's shift is static (a pad, fused into the pass). The
+    scan looks backwards only: what the pads behind the valid rows hold
+    never reaches a valid row. A NaN equals nothing, itself included, and
+    stays its own group."""
+    b = sk.shape[0]
+    ops = [_COMBINE[STAT_COMBINE[kind]] for kind in kinds]
+
+    def step(d: int):
+        def back(x):  # row i reads row i - d
+            return lax.pad(x, jnp.zeros((), x.dtype), [(d, -d, 0)])
+
+        def fold(cs):
+            i = lax.iota(jnp.int32, b)
+            same = (sk == back(sk)) & (i >= d) & (i < n)
+            out = tuple(jnp.where(same, op(c, back(c)), c) for op, c in zip(ops, cs))
+            return jnp.any(same), out
+
+        return fold
+
+    steps = [step(1 << s) for s in range((b - 1).bit_length())]
+
+    def body(carry):
+        s, _, cs = carry
+        go, cs = lax.switch(s, steps, cs)
+        return s + 1, go, cs
+
+    totals = tuple(cols)
+    if steps and totals:
+        totals = lax.while_loop(
+            lambda c: c[1] & (c[0] < len(steps)), body, (jnp.int32(0), jnp.bool_(True), totals)
+        )[2]
+    # what the caller derives from the sorted keys next (run ends, destinations)
+    # waits behind this barrier for the scan, and so is not held alive across it
+    sk, totals = lax.optimization_barrier((sk, totals))
+    i = lax.iota(jnp.int32, b)
+    is_end = (i < n) & ((i == n - 1) | (sk != jnp.concatenate([sk[1:], sk[-1:]])))
+    return sk, totals, is_end
 
 
-def _scatter_starts(values, segv, fill, b: int):
-    """Per-segment representative (all rows of a segment carry the same
-    key, so duplicate scatter writes agree)."""
-    return jnp.full((b,), jnp.asarray(fill), values.dtype).at[segv].set(
-        values, mode="drop"
-    )
+def _unique_samples(sk, is_end, u):
+    """The election's samples among a sorted block's ``u`` distinct keys:
+    the key at each sampled rank is the one at the run end whose running
+    count of ends first reaches rank + 1."""
+    b = sk.shape[0]
+    idx = _sample_ranks(u, b)
+    pos = jnp.searchsorted(jnp.cumsum(is_end.astype(jnp.int32)), idx + 1, side="left")
+    return jnp.where(idx < u, sk[jnp.clip(pos, 0, b - 1)], jnp.asarray(_max_key(sk.dtype)))
 
 
 def _dest_matrix(pid, p: int):
@@ -223,9 +291,10 @@ def _plan_executable(
     mode: str,
     comm: MeshCommunication,
 ):
-    """The groupby plan program: local sort → segment-reduce partials →
-    splitter election → destination tagging → destination-major sort →
-    replicated bucket matrix. One dispatch, data-independent cache key."""
+    """The groupby plan program: local sort carrying the values →
+    segmented scan into partials → splitter election → destination
+    tagging → compaction by (destination, key) → replicated bucket
+    matrix. One dispatch, data-independent cache key."""
     mesh = comm.mesh
     key = ("plan", pshape, str(key_dtype), val_dtypes, stats, p, mode, mesh)
     fn = _PROGRAMS.get(key)
@@ -234,36 +303,29 @@ def _plan_executable(
     b = pshape[0] // p
 
     def frame_plan(kb, counts, *vals):
-        r = lax.axis_index(SPLIT_AXIS)
-        n = counts[r]
-        pad = lax.iota(jnp.int32, b) >= n
-        sk, sp, svals = _sort_by_key(kb, pad, list(vals))
-        valid = ~sp
-        _, segv, u = _segments(sk, valid)
-        ukeys = _scatter_starts(sk, segv, _max_key(sk.dtype), b)
-        parts = []
+        n = counts[lax.axis_index(SPLIT_AXIS)]
+        sk, svals = _sort_by_key(kb, n, list(vals))
+        data = []
         for kind, ci, odt in stats:
             dt = jnp.dtype(odt)
-            data = (
-                valid.astype(dt)
+            data.append(
+                jnp.ones((b,), dt)
                 if kind == "count"
                 else svals[ci].astype(dt) ** 2
                 if kind == "sumsq"
                 else svals[ci].astype(dt)
             )
-            parts.append(_segment_reduce(kind, data, valid, segv, b))
-        upad = lax.iota(jnp.int32, b) >= u
+        sk, totals, is_end = _fold_runs(sk, n, data, [kind for kind, _, _ in stats])
+        u = jnp.sum(is_end.astype(jnp.int32))
         if mode == "range":
-            splitters = _elect(ukeys, upad, u, p)
-            pid = _range_pid(ukeys, splitters)
+            pid = _range_pid(sk, _splitters(_unique_samples(sk, is_end, u), p))
         else:
-            pid = _hash_pid(ukeys, p)
-        pid = jnp.where(upad, p, pid)
-        iota = lax.iota(jnp.int32, b)
-        perm = lax.sort((pid, iota), num_keys=2, is_stable=True)[1]
+            pid = _hash_pid(sk, p)
+        # everything that is no group's last row goes behind the last destination
+        pid = jnp.where(is_end, pid, p)
         mat = _dest_matrix(pid, p)
         uvec = lax.all_gather(u, SPLIT_AXIS)
-        return (ukeys[perm], *[s[perm] for s in parts], mat, uvec)
+        return (*_ends_first(pid, p, sk, totals), mat, uvec)
 
     spec = P(SPLIT_AXIS)
     in_specs = (spec, P(), *([spec] * len(val_dtypes)))
@@ -281,29 +343,20 @@ def _merge_executable(
     comm: MeshCommunication,
 ):
     """The post-exchange merge program: sort received partials by key,
-    segment-reduce with each statistic's associative combiner, report
-    per-shard group counts (replicated)."""
+    scan with each statistic's associative combiner, compact the group
+    totals to the front, report per-shard group counts (replicated)."""
     mesh = comm.mesh
     key = ("gmerge", pshape, str(key_dtype), stats, p, mesh)
     fn = _PROGRAMS.get(key)
     if fn is not None:
         return fn
-    b = pshape[0] // p
 
     def frame_merge(kb, counts, *parts):
-        r = lax.axis_index(SPLIT_AXIS)
-        n = counts[r]
-        pad = lax.iota(jnp.int32, b) >= n
-        sk, sp, sparts = _sort_by_key(kb, pad, list(parts))
-        valid = ~sp
-        _, segv, g = _segments(sk, valid)
-        ukeys = _scatter_starts(sk, segv, _max_key(sk.dtype), b)
-        outs = [
-            _segment_reduce(kind, s, valid, segv, b)
-            for (kind, _), s in zip(stats, sparts)
-        ]
-        gvec = lax.all_gather(g, SPLIT_AXIS)
-        return (ukeys, *outs, gvec)
+        n = counts[lax.axis_index(SPLIT_AXIS)]
+        sk, sparts = _sort_by_key(kb, n, list(parts))
+        sk, totals, is_end = _fold_runs(sk, n, sparts, [kind for kind, _ in stats])
+        gvec = lax.all_gather(jnp.sum(is_end.astype(jnp.int32)), SPLIT_AXIS)
+        return (*_ends_first(~is_end, 1, sk, totals), gvec)
 
     spec = P(SPLIT_AXIS)
     in_specs = (spec, P(), *([spec] * len(stats)))
@@ -334,20 +387,11 @@ def _elect_executable(
         mk = jnp.asarray(_max_key(blocks[0].dtype))
         samples = []
         for blk, cnt in zip(blocks, counts):
-            b = blk.shape[0]
             n = cnt[r]
-            pad = lax.iota(jnp.int32, b) >= n
-            sk, sp, _ = _sort_by_key(blk, pad, [])
-            sk = jnp.where(sp, mk, sk)
-            idx = jnp.clip(
-                (lax.iota(jnp.int32, _OVERSAMPLE) * n) // jnp.maximum(n, 1), 0, b - 1
-            )
-            samples.append(jnp.where(n > 0, sk[idx], jnp.full((_OVERSAMPLE,), mk)))
-        local = jnp.concatenate(samples)
-        g = lax.all_gather(local, SPLIT_AXIS, tiled=True)
-        gs = jnp.sort(g)
-        pos = (jnp.arange(1, p) * gs.shape[0]) // p
-        return gs[pos]
+            sk, _ = _sort_by_key(blk, n, [])
+            idx = _sample_ranks(n, blk.shape[0])
+            samples.append(jnp.where(idx < n, sk[idx], mk))
+        return _splitters(jnp.concatenate(samples), p)
 
     spec = P(SPLIT_AXIS)
     in_specs = tuple([spec] * nbufs + [P()] * nbufs)
@@ -375,19 +419,12 @@ def _partition_executable(
     b = pshape[0] // p
 
     def frame_partition(kb, counts, splitters, *vals):
-        r = lax.axis_index(SPLIT_AXIS)
-        n = counts[r]
-        pad = lax.iota(jnp.int32, b) >= n
-        sk, sp, svals = _sort_by_key(kb, pad, list(vals))
-        if mode == "range":
-            pid = _range_pid(sk, splitters)
-        else:
-            pid = _hash_pid(sk, p)
-        pid = jnp.where(sp, p, pid)
-        iota = lax.iota(jnp.int32, b)
-        perm = lax.sort((pid, iota), num_keys=2, is_stable=True)[1]
+        n = counts[lax.axis_index(SPLIT_AXIS)]
+        sk, svals = _sort_by_key(kb, n, list(vals))
+        pid = _range_pid(sk, splitters) if mode == "range" else _hash_pid(sk, p)
+        pid = jnp.where(lax.iota(jnp.int32, b) < n, pid, p)
         mat = _dest_matrix(pid, p)
-        return (sk[perm], *[v[perm] for v in svals], mat)
+        return (*_partition_front(pid, [sk, *svals]), mat)
 
     spec = P(SPLIT_AXIS)
     in_specs = (spec, P(), P(), *([spec] * len(payload_dtypes)))
@@ -424,10 +461,10 @@ def _join_executable(
         rvals = list(rest[len(l_dtypes) + 2 :])
         r = lax.axis_index(SPLIT_AXIS)
         nl, nr = lcnt[r], rcnt[r]
-        lpad = lax.iota(jnp.int32, bl) >= nl
-        rpad = lax.iota(jnp.int32, br) >= nr
-        slk, slp, slv = _sort_by_key(lk, lpad, lvals)
-        srk, srp, srv = _sort_by_key(rk, rpad, rvals)
+        slp = lax.iota(jnp.int32, bl) >= nl
+        srp = lax.iota(jnp.int32, br) >= nr
+        slk, slv = _sort_by_key(lk, nl, lvals)
+        srk, srv = _sort_by_key(rk, nr, rvals)
         mk = jnp.asarray(_max_key(srk.dtype))
         srk2 = jnp.where(srp, mk, srk)
         # duplicate right keys would silently multiply rows in a merge
@@ -439,15 +476,8 @@ def _join_executable(
         hit = (idx < nr) & (srk2[idxc] == slk) & ~slp
         gathered = [v[idxc] for v in srv]
         if how == "inner":
-            keep = hit
-            iota = lax.iota(jnp.int32, bl)
-            perm = lax.sort(((~keep).astype(jnp.int32), iota), num_keys=2, is_stable=True)[1]
-            g = jnp.sum(keep.astype(jnp.int32))
-            outs = (
-                slk[perm],
-                *[v[perm] for v in slv],
-                *[jnp.where(keep, v, jnp.zeros_like(v))[perm] for v in gathered],
-            )
+            g = jnp.sum(hit.astype(jnp.int32))
+            outs = _partition_front((~hit).astype(jnp.int8), [slk, *slv, *gathered])
         else:  # left: all valid left rows, unmatched right values -> NaN
             g = nl
             filled = []
@@ -486,15 +516,10 @@ def _compact_executable(
     b = pshape[0] // p
 
     def frame_compact(mask, counts, *cols):
-        r = lax.axis_index(SPLIT_AXIS)
-        n = counts[r]
-        valid = lax.iota(jnp.int32, b) < n
-        keep = mask & valid
-        iota = lax.iota(jnp.int32, b)
-        perm = lax.sort(((~keep).astype(jnp.int32), iota), num_keys=2, is_stable=True)[1]
-        g = jnp.sum(keep.astype(jnp.int32))
-        gvec = lax.all_gather(g, SPLIT_AXIS)
-        return (*[c[perm] for c in cols], gvec)
+        n = counts[lax.axis_index(SPLIT_AXIS)]
+        keep = mask & (lax.iota(jnp.int32, b) < n)
+        gvec = lax.all_gather(jnp.sum(keep.astype(jnp.int32)), SPLIT_AXIS)
+        return (*_partition_front((~keep).astype(jnp.int8), list(cols)), gvec)
 
     spec = P(SPLIT_AXIS)
     in_specs = (spec, P(), *([spec] * len(dtypes)))

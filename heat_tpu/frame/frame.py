@@ -3,7 +3,7 @@
 Not a dataframe library: a Frame is a dict of equal-length, co-sharded
 1-D columns plus the relational verbs the shuffle engine makes cheap —
 ``groupby(...).agg(...)``, ``value_counts``, hash/range ``join``, and
-``filter``. Every verb is *local segment-reduce per shard → one bounded
+``filter``. Every verb is *local sort and scan of equal keys per shard → one bounded
 exchange per operand → local merge* (or zero exchanges for ``filter``),
 dispatched through cached jitted programs: warm repeats are 0-trace /
 0-compile, and partition decisions are replicated so every verb is

@@ -209,3 +209,79 @@ def test_moments_sharded_compiles_over_four_chips(four_chips):
     compiled = _compiled_kernel(lowered, max_temp_bytes=1 << 20)
     assert "all-reduce" in compiled.as_text()  # the Chan combine's psums
     assert compiled.memory_analysis().argument_size_in_bytes < n * f * 4 // 4 + (1 << 20)
+
+
+# The groupby's two programs at h2o question 5's widths: int32 key, two
+# int32 sums and one f32 sum. An indexed read or write of a block-long
+# column ran at 0.21 GB/s on the chip (PERF.md §6, PR 25), so none may come
+# back: every column moves as an operand of a sort that the program runs
+# anyway, and the rewrite may not hold more of them alive than the
+# gathering program did (2.34 columns of temporaries at this size).
+_Q5_ROWS = 1 << 20
+_Q5_STATS = (("sum", 0, "int32"), ("sum", 1, "int32"), ("sum", 2, "float32"))
+
+
+def _lower_frame_program(which: str, mesh, p: int):
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from heat_tpu.core.communication import SPLIT_AXIS, MeshCommunication
+    from heat_tpu.frame import _shuffle
+
+    comm = MeshCommunication(devices=list(mesh.devices.flat))
+    rows, rep = NamedSharding(comm.mesh, P(SPLIT_AXIS)), NamedSharding(comm.mesh, P())
+    shape = (_Q5_ROWS * p,)
+    if which == "plan":
+        fn = _shuffle._plan_executable(
+            shape, jnp.dtype("int32"), ("int32", "int32", "float32"), _Q5_STATS, p, "range", comm
+        )
+    else:
+        fn = _shuffle._merge_executable(
+            shape, jnp.dtype("int32"), tuple((kind, odt) for kind, _, odt in _Q5_STATS), p, comm
+        )
+    return fn.lower(
+        _spec(shape, jnp.int32, rows), _spec((p,), jnp.int32, rep),
+        *[_spec(shape, jnp.dtype(odt), rows) for _, _, odt in _Q5_STATS],
+    )
+
+
+def _indexed_ops(text: str, b: int):
+    """The gather and scatter instructions of a compiled program whose result
+    has ``b`` elements: a gather through a block-long index vector, a scatter
+    into a block-long column."""
+    import re
+
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= (\S+) (gather|scatter)\((.*)", line)
+        if m and re.search(rf"\[(\d+,)*{b}(,\d+)*\]", m.group(1) + m.group(3)):
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("which", ["plan", "merge"])
+def test_groupby_program_moves_no_column_through_an_index(topo, which):
+    from jax.sharding import Mesh
+
+    from heat_tpu.core.communication import SPLIT_AXIS
+
+    mesh = Mesh(np.array(topo.devices[:1]), (SPLIT_AXIS,))
+    compiled = _lower_frame_program(which, mesh, 1).compile()
+    text, column = compiled.as_text(), 4 * _Q5_ROWS
+    assert _indexed_ops(text, _Q5_ROWS) == []
+    assert " sort(" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 2 * column, mem.temp_size_in_bytes / column
+    assert mem.output_size_in_bytes <= 4.1 * column, mem.output_size_in_bytes / column
+
+
+def test_groupby_plan_compiles_over_four_chips(four_chips):
+    compiled = _lower_frame_program("plan", four_chips, 4).compile()
+    text = compiled.as_text()
+    # the election's samples, the bucket matrix, the group counts: all_gathers of a
+    # few words, which this compiler turns into all-reduces
+    assert "all-gather" in text or "all-reduce" in text
+    # the election reads 32 samples through an index and nothing longer
+    assert _indexed_ops(text, _Q5_ROWS) == []
+    # each chip sorts its quarter of the rows, not a replica
+    assert compiled.memory_analysis().argument_size_in_bytes < 4 * 4 * _Q5_ROWS + (1 << 20)

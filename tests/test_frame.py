@@ -459,3 +459,215 @@ class TestStreamingGroupBy:
         other = StreamingGroupBy(aggs=("sum",), capacity=16)
         with pytest.raises(ValueError, match="merge"):
             sg.merge(other)
+
+
+# ------------------------------------------------------------------ PR 25
+# The plan and merge programs move every column as an operand of a sort and
+# fold equal keys with a segmented scan; these hold them to NumPy over the
+# layouts that bend the scan: pads holding plausible garbage, an empty shard,
+# one run as long as the block, no run longer than one row, and a valid key
+# equal to the key the pads are given.
+_BLOCK = 24  # rows a shard, so every layout of a mesh shares its programs
+_LAYOUTS = ["ragged", "one-group", "all-distinct", "max-key"]
+_STATS = (
+    ("sum", 0, "int32"), ("sumsq", 1, "float32"), ("count", 0, "int32"),
+    ("min", 1, "float32"), ("max", 0, "int32"), ("sum", 1, "float32"),
+)
+
+
+def _mesh_comm(which: str):
+    if which == "mesh":
+        return ht.get_comm()
+    import jax
+
+    if jax.process_count() > 1:
+        pytest.skip("a one-device mesh leaves the other processes without a shard")
+    return ht.MeshCommunication(devices=mh.submesh(1))
+
+
+def _layout_keys(layout: str, kind: str, n: int, rng) -> np.ndarray:
+    """``n`` keys of one layout, as float64 codes the key kind then casts."""
+    if kind == "bool":
+        if layout == "one-group":
+            return np.ones(n, np.bool_)
+        return rng.integers(0, 2, size=n).astype(np.bool_)
+    if layout == "one-group":
+        codes = np.full(n, 3.0)
+    elif layout == "all-distinct":
+        codes = rng.permutation(n).astype(np.float64) - n // 2
+    else:
+        codes = rng.integers(-6, 7, size=n).astype(np.float64)
+    if kind == "int32":
+        keys = codes.astype(np.int32)
+        if layout == "max-key":
+            keys[rng.random(n) < 0.3] = np.iinfo(np.int32).max
+        return keys
+    keys = (codes / 2).astype(np.float32)
+    if layout in ("ragged", "max-key"):
+        special = np.array([np.nan, np.inf, -np.inf, -0.0, 0.0], np.float32)
+        hit = rng.random(n) < 0.4
+        keys[hit] = special[rng.integers(0, special.size, size=int(hit.sum()))]
+    return keys
+
+
+def _numpy_groups(keys, x, y):
+    """Groups in lax.sort's order (NaN last, each NaN alone; -0.0 with 0.0) and
+    the six statistics of ``_STATS``; float sums in float64."""
+    order = np.argsort(keys, kind="stable")
+    ks, xs, ys = keys[order], x[order].astype(np.int64), y[order].astype(np.float64)
+    new = np.ones(ks.size, np.bool_)
+    new[1:] = ~(ks[1:] == ks[:-1])
+    starts = np.flatnonzero(new)
+    count = np.diff(np.append(starts, ks.size))
+    return ks[starts], count, [
+        np.add.reduceat(xs, starts), np.add.reduceat(ys * ys, starts), count,
+        np.minimum.reduceat(ys, starts), np.maximum.reduceat(xs, starts),
+        np.add.reduceat(ys, starts),
+    ]
+
+
+class TestGroupbyReduceLayouts:
+    @pytest.mark.parametrize("mesh", ["one-device", "mesh"])
+    @pytest.mark.parametrize("mode", ["range", "hash"])
+    @pytest.mark.parametrize("layout", _LAYOUTS)
+    @pytest.mark.parametrize("kind", ["int32", "float32", "bool"])
+    def test_matches_numpy(self, kind, layout, mode, mesh):
+        from heat_tpu.frame._shuffle import groupby_reduce, shard_counts
+
+        comm = _mesh_comm(mesh)
+        p = comm.size
+        rng = np.random.default_rng([25, p, _LAYOUTS.index(layout)])
+        n = p * _BLOCK
+        keys = _layout_keys(layout, kind, n, rng)
+        x = rng.integers(-4, 9, size=n).astype(np.int32)
+        y = rng.uniform(0.5, 100.0, size=n).astype(np.float32)
+        # the rows a filter rejects stay behind the kept ones as the pads' content:
+        # keys of real groups, values that would show in any total they reached
+        keep = rng.random(n) < 0.8
+        if layout != "all-distinct":
+            keep[:: max(p, 2)] = False
+        if p > 1:
+            keep[_BLOCK : 2 * _BLOCK] = False  # shard 1 keeps nothing
+        x, y = np.where(keep, x, 10_000).astype(np.int32), np.where(keep, y, 1e6).astype(np.float32)
+        full = Frame({c: ht.array(a, split=0, comm=comm) for c, a in (("k", keys), ("x", x), ("y", y))})
+        kept = full.filter(ht.array(keep, split=0, comm=comm))
+        assert shard_counts(kept["k"]) == tuple(int(keep[r * _BLOCK : (r + 1) * _BLOCK].sum()) for r in range(p))
+
+        mkeys, reduced, n_groups = groupby_reduce(
+            kept["k"], [kept["x"]._raw, kept["y"]._raw], ("int32", "float32"), _STATS, mode=mode
+        )
+        want_keys, count, want = _numpy_groups(keys[keep], x[keep], y[keep])
+        got_keys, got = mkeys.numpy(), [r.numpy() for r in reduced]
+        assert n_groups == want_keys.size == got_keys.size
+        if mode == "hash":  # co-located, not ordered: order the groups as the reference does
+            order = np.argsort(got_keys, kind="stable")
+            got_keys, got = got_keys[order], [g[order] for g in got]
+        np.testing.assert_array_equal(got_keys, want_keys)  # exact and in order; NaN last
+        nan = np.isnan(want_keys) if kind == "float32" else np.zeros(want_keys.size, np.bool_)
+        if nan.any():  # every NaN is a group of its own; which came first is not defined
+            order = np.lexsort((got[5][nan], got[0][nan]))
+            worder = np.lexsort((want[5][nan], want[0][nan]))
+            got = [np.concatenate([g[~nan], g[nan][order]]) for g in got]
+            want = [np.concatenate([w[~nan], w[nan][worder]]) for w in want]
+        for i in (0, 2, 4):  # integer statistics: exact
+            np.testing.assert_array_equal(got[i], want[i], err_msg=_STATS[i][0])
+        np.testing.assert_array_equal(got[3], want[3].astype(np.float32), err_msg="min")
+        # an f32 sum of c positive terms in any order is within c·2^-24 of the exact one,
+        # relatively; twice that for the reference's own rounding (the benchmark's bound)
+        rtol = 2.0 * count.max() * 2.0**-24
+        for i in (1, 5):
+            np.testing.assert_allclose(got[i], want[i], rtol=rtol, atol=0, err_msg=_STATS[i][0])
+
+    @pytest.mark.parametrize("mode", ["range", "hash"])
+    @pytest.mark.parametrize("kind", ["int32", "float32"])
+    def test_mean_std_on_a_ragged_frame(self, kind, mode):
+        comm = ht.get_comm()
+        n = comm.size * _BLOCK
+        rng = np.random.default_rng(26)
+        keys = _layout_keys("plain", kind, n, rng)
+        y = rng.uniform(0.5, 100.0, size=n).astype(np.float32)
+        keep = rng.random(n) < 0.7
+        full = Frame({"k": ht.array(keys, split=0), "y": ht.array(np.where(keep, y, 1e6).astype(np.float32), split=0)})
+        got = _sorted_dict(full.filter(ht.array(keep, split=0)).groupby("k", mode=mode).agg({"y": ["mean", "std"]}), "k")
+        uk, want_mean = _oracle(keys[keep], y[keep], "mean")
+        _, want_std = _oracle(keys[keep], y[keep], "std")
+        np.testing.assert_array_equal(got["k"], uk)
+        np.testing.assert_allclose(got["y_mean"], want_mean, rtol=1e-5)
+        np.testing.assert_allclose(got["y_std"], want_std, rtol=2e-3, atol=1e-3, equal_nan=True)
+
+
+def _numpy_election(blocks, mk, p):
+    """The election as the parent commit ran it, in NumPy: each shard's sorted
+    keys sampled at ``_sample_ranks``, short shards filled with the max key,
+    the P-1 quantiles of all samples."""
+    from heat_tpu.frame._shuffle import _OVERSAMPLE
+
+    samples = []
+    for blk, size in blocks:
+        n = blk.size
+        idx = np.clip((np.arange(_OVERSAMPLE) * n) // max(n, 1), 0, size - 1)
+        samples.append(np.where(idx < n, blk[np.minimum(idx, max(n - 1, 0))] if n else mk, mk))
+    gs = np.sort(np.concatenate(samples))
+    return gs[(np.arange(1, p) * gs.size) // p]
+
+
+class TestRangeElection:
+    """The election must not move with the rewrite: the plan samples among a
+    shard's distinct keys, ``shuffle_rows``' election among its rows."""
+
+    ROWS = 2000
+
+    def _data(self):
+        import jax
+
+        if jax.process_count() > 1:
+            pytest.skip("reads the programs' raw outputs, which one process does not hold whole")
+        rng = np.random.default_rng(25)
+        keys = rng.integers(-500, 500, size=self.ROWS).astype(np.int32)
+        vals = rng.integers(0, 9, size=self.ROWS).astype(np.int32)
+        return keys, vals
+
+    def test_plan_buckets_match_the_parents_election(self):
+        from heat_tpu.frame import _shuffle
+
+        keys, vals = self._data()
+        k, v = ht.array(keys, split=0), ht.array(vals, split=0)
+        comm, p = k.comm, k.comm.size
+        counts = _shuffle.shard_counts(k)
+        plan = _shuffle._plan_executable(
+            tuple(k._raw.shape), k._raw.dtype, ("int32",), (("sum", 0, "int32"),), p, "range", comm
+        )
+        out = plan(k._raw, _shuffle._counts_vec(counts), v._raw)
+        mat, uvec = np.asarray(out[-2]), np.asarray(out[-1])
+        b, offs = k._raw.shape[0] // p, np.cumsum((0, *counts))
+        uniq = [np.unique(keys[offs[r] : offs[r + 1]]) for r in range(p)]
+        splitters = _numpy_election([(u, b) for u in uniq], np.iinfo(np.int32).max, p)
+        np.testing.assert_array_equal(uvec, [u.size for u in uniq])
+        want = np.stack([np.bincount(np.searchsorted(splitters, u, side="right"), minlength=p) for u in uniq])
+        np.testing.assert_array_equal(mat, want)
+        if p == 8:  # what the parent commit (b98c5b1) returned for these rows
+            np.testing.assert_array_equal(uvec, [223, 224, 217, 215, 219, 230, 224, 218])
+            np.testing.assert_array_equal(mat[0], [6, 9, 1, 4, 5, 3, 3, 192])
+            np.testing.assert_array_equal(mat[:, -1], [192, 193, 186, 185, 193, 199, 202, 197])
+        # the partials leave destination-major, in key order, each key's total exact
+        pk, ps = np.asarray(out[0]).reshape(p, b), np.asarray(out[1]).reshape(p, b)
+        for r in range(p):
+            got_k, got_s = pk[r, : uniq[r].size], ps[r, : uniq[r].size]
+            np.testing.assert_array_equal(got_k, uniq[r])  # range destinations ascend with the key
+            shard_k, shard_v = keys[offs[r] : offs[r + 1]], vals[offs[r] : offs[r + 1]]
+            np.testing.assert_array_equal(got_s, [shard_v[shard_k == u].sum() for u in uniq[r]])
+
+    def test_row_election_matches_the_parents(self):
+        from heat_tpu.frame import _shuffle
+
+        keys, _ = self._data()
+        k = ht.array(keys, split=0)
+        comm, p = k.comm, k.comm.size
+        counts = _shuffle.shard_counts(k)
+        elect = _shuffle._elect_executable((tuple(k._raw.shape),), k._raw.dtype, p, comm)
+        got = np.asarray(elect(k._raw, _shuffle._counts_vec(counts)))
+        b, offs = k._raw.shape[0] // p, np.cumsum((0, *counts))
+        rows = [(np.sort(keys[offs[r] : offs[r + 1]]), b) for r in range(p)]
+        np.testing.assert_array_equal(got, _numpy_election(rows, np.iinfo(np.int32).max, p))
+        if p == 8:  # what the parent commit (b98c5b1) returned for these rows
+            np.testing.assert_array_equal(got, [-485, -471, -455, -437, -420, -402, -385])
