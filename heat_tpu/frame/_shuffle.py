@@ -37,8 +37,9 @@ them in the plan were 87 % of a 17.9 s groupby (PERF.md §6, PR 25). A
 column moves as an operand of a sort the program runs anyway
 (:func:`_carry_sort`), equal keys fold by comparing neighbours
 (:func:`_fold_runs`), and a compaction is a sort on a small leading key.
-The indexed reads left are the election's 32 samples and the join's
-lookup of each left row's match, which is the join.
+The indexed accesses left are the election's 32 samples and, in the
+join, the two rank scatters of the search for each left row's match and
+the read of each right column through the positions it finds.
 
 Partition decisions are REPLICATED at every step: splitters come out of
 an ``all_gather`` inside the program, bucket matrices are identical on
@@ -471,7 +472,10 @@ def _join_executable(
         # join — detect and report (replicated via max over shards)
         dup_local = jnp.any((srk2[1:] == srk2[:-1]) & ~srp[1:] & ~srp[:-1])
         dup = lax.pmax(dup_local.astype(jnp.int32), SPLIT_AXIS)
-        idx = jnp.searchsorted(srk2, jnp.where(slp, mk, slk), side="left")
+        # method="sort": two short sorts and two scatters. The default binary search reads
+        # the right keys through a block-long index once a step, 17 steps for 1e5 keys: alone
+        # on a v5e, 1e8 left rows, 12.85 s against 2.38 s (compare_all 22.80 s; PERF.md §6, PR 27)
+        idx = jnp.searchsorted(srk2, jnp.where(slp, mk, slk), side="left", method="sort")
         idxc = jnp.clip(idx, 0, br - 1)
         hit = (idx < nr) & (srk2[idxc] == slk) & ~slp
         gathered = [v[idxc] for v in srv]

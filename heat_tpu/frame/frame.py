@@ -153,6 +153,7 @@ class Frame:
             }
         )
 
+    @_hooks.public_call("Frame.join")
     def join(
         self,
         other: "Frame",
@@ -162,11 +163,21 @@ class Frame:
         mode: str = "range",
     ) -> "Frame":
         """Join on a shared key column; right keys must be unique (the
-        m:1 contract — duplicates raise). Both sides are co-partitioned
-        by ONE shared splitter election, each side pays one bounded
-        exchange per operand, then a device-local merge join matches
-        rows. ``how="left"`` NaN-fills unmatched right values (right
-        columns promote to float)."""
+        m:1 contract — duplicates raise ``ValueError``). Both sides are
+        co-partitioned by ONE shared splitter election, each side pays
+        one bounded exchange per operand, then a device-local merge join
+        matches rows. ``how="left"`` NaN-fills unmatched right values
+        (right columns promote to float: float32 unless they are wider).
+
+        Columns of the result: the key, this frame's others in its
+        order, ``other``'s others in its order (``rsuffix`` appended to
+        a name this frame has too). Rows: on each shard in ascending
+        key, this frame's own order within a key; with ``mode="range"``
+        the shards hold ascending key ranges in rank order, so the whole
+        result is in that order, row for row what
+        :func:`heat_tpu.frame.reference.join_m1` returns. ``"hash"`` only
+        co-locates equal keys: each shard is ordered, the shards are
+        not. Neither input is changed or consumed."""
         if on not in self._cols or on not in other._cols:
             raise KeyError(f"join key {on!r} must exist in both frames")
         lk, rk = self._cols[on], other._cols[on]

@@ -671,3 +671,89 @@ class TestRangeElection:
         np.testing.assert_array_equal(got, _numpy_election(rows, np.iinfo(np.int32).max, p))
         if p == 8:  # what the parent commit (b98c5b1) returned for these rows
             np.testing.assert_array_equal(got, [-485, -471, -455, -437, -420, -402, -385])
+
+
+# --- Frame.join against the plain reference (heat_tpu/frame/reference.py), at the shapes of
+# h2o.ai db-benchmark's join question 2 ("medium inner on int"), cut to 2e4 rows: x with seven
+# columns, ``medium`` (rows/1000 rows, its id2 unique) with five, three names in both; a key
+# column's values come from a shuffled pool of 1.1 n, the first 0.9 n on both sides, the next
+# 0.1 n in x only, the last 0.1 n in ``medium`` only, so about nine rows in ten of x match.
+_J_ROWS, _J_MEDIUM = 20_000, 20
+
+
+def _h2o_join_tables(rng):
+    def pool(n):
+        values = (rng.permutation(n + n // 10) + 1).astype(np.int32)
+        return values[:n], np.concatenate([values[: n - n // 10], values[n:]])
+
+    x1, m1 = pool(10)
+    x2, m2 = pool(_J_MEDIUM)
+    x = {"id1": rng.choice(x1, _J_ROWS), "id2": rng.choice(x2, _J_ROWS),
+         "id3": rng.integers(1, _J_ROWS + 1, _J_ROWS).astype(np.int32)}
+    x.update(id4=x["id1"].copy(), id5=x["id2"].copy(), id6=x["id3"].copy(),
+             v1=rng.uniform(0, 100, _J_ROWS).astype(np.float32))
+    medium = {"id1": rng.choice(m1, _J_MEDIUM), "id2": rng.permutation(m2)}
+    medium.update(id4=medium["id1"].copy(), id5=medium["id2"].copy(),
+                  v2=rng.uniform(0, 100, _J_MEDIUM).astype(np.float32))
+    return x, medium
+
+
+class TestJoinAgainstReference:
+    @pytest.mark.parametrize("mode", ["range", "hash"])
+    @pytest.mark.parametrize("devices", [1, 4, 8])
+    @pytest.mark.parametrize("how", ["inner", "left"])
+    def test_every_column_equals_the_references(self, how, devices, mode):
+        import jax
+
+        from heat_tpu.frame.reference import join_m1
+        from heat_tpu.frame._shuffle import shard_counts
+
+        if devices > len(jax.devices()) or (jax.process_count() > 1 and devices % jax.process_count()):
+            pytest.skip(f"no mesh of {devices} devices here")
+        comm = ht.MeshCommunication(devices=mh.submesh(devices))
+        x, medium = _h2o_join_tables(np.random.default_rng([27, devices]))
+        # x as a filter leaves it: ragged, and on a mesh its second shard empty (the rows
+        # it drops stay behind the kept ones as the pads' content, keys that would match)
+        keep = np.random.default_rng(devices).random(_J_ROWS) < 0.9
+        block = -(-_J_ROWS // devices)
+        if devices > 1:
+            keep[block : 2 * block] = False
+        full = Frame({c: ht.array(a, split=0, comm=comm) for c, a in x.items()})
+        left = full.filter(ht.array(keep, split=0, comm=comm))
+        assert shard_counts(left["id2"]) == tuple(int(keep[r * block : (r + 1) * block].sum()) for r in range(devices))
+        right = Frame({c: ht.array(a, split=0, comm=comm) for c, a in medium.items()})
+        xk = {c: a[keep] for c, a in x.items()}
+        matched = np.isin(xk["id2"], medium["id2"])
+        assert 0.85 < matched.mean() < 0.95 and not np.isin(medium["id2"], xk["id2"]).all()
+
+        want = join_m1(xk, medium, on="id2", how=how)
+        out = left.join(right, on="id2", how=how, mode=mode)
+        got = out.to_dict()
+        assert out.columns == tuple(want) == ("id2", "id1", "id3", "id4", "id5", "id6", "v1", "id1_r", "id4_r", "id5_r", "v2")
+        if mode == "hash":
+            # equal keys share a shard and each shard is in the promised order, the shards are
+            # not: one stable sort by key of the shards laid end to end is the promised order
+            order = np.argsort(got["id2"], kind="stable")
+            got = {c: a[order] for c, a in got.items()}
+        for name in want:  # range: row for row as it stands, over the whole mesh
+            assert got[name].dtype == want[name].dtype, name
+            np.testing.assert_array_equal(got[name], want[name], err_msg=name)  # NaN equals NaN here
+
+    def test_a_second_call_compiles_nothing_and_leaves_both_frames_as_they_were(self):
+        from heat_tpu.frame.reference import join_m1
+
+        x, medium = _h2o_join_tables(np.random.default_rng(28))
+        left, right = Frame(x), Frame(medium)
+        first = left.join(right, on="id2").to_dict()  # cold
+        with sanitizer("warm frame join") as region:
+            again = left.join(right, on="id2")
+        assert region.compiles == 0, region.stats()
+        assert region.traces == 0, region.stats()
+        want = join_m1(x, medium, on="id2")
+        for name, col in again.to_dict().items():
+            np.testing.assert_array_equal(col, first[name], err_msg=name)
+            np.testing.assert_array_equal(col, want[name], err_msg=name)
+        # what a call may never do, with or without donated buffers: touch a user's column
+        for frame, table in ((left, x), (right, medium)):
+            for name, col in frame.to_dict().items():
+                np.testing.assert_array_equal(col, table[name], err_msg=name)
