@@ -37,9 +37,10 @@ them in the plan were 87 % of a 17.9 s groupby (PERF.md §6, PR 25). A
 column moves as an operand of a sort the program runs anyway
 (:func:`_carry_sort`), equal keys fold by comparing neighbours
 (:func:`_fold_runs`), and a compaction is a sort on a small leading key.
-The indexed accesses left are the election's 32 samples and, in the
-join, the two rank scatters of the search for each left row's match and
-the read of each right column through the positions it finds.
+A join matches the same way: both sides sorted together, the right row
+first in each run of equal keys, its values carried along the run
+(:func:`_scan_runs`). The one indexed access left is the election's 32
+samples.
 
 Partition decisions are REPLICATED at every step: splitters come out of
 an ``all_gather`` inside the program, bucket matrices are identical on
@@ -212,14 +213,19 @@ def _splitters(samples, p: int):
     return gs[(jnp.arange(1, p) * gs.shape[0]) // p]
 
 
-_COMBINE = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+# how a row takes in the row ``d`` before it in its run: ``op(own, before)``
+_COMBINE = {
+    "sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum,
+    "first": lambda own, before: before,
+}
 
 
-def _fold_runs(sk, n, cols, kinds):
+def _scan_runs(sk, n, cols, combiners):
     """Inclusive scan of each column within the runs of equal keys among a
-    sorted block's first ``n`` rows, ``kinds[j]``'s combiner on
-    ``cols[j]``: afterwards the last row of a run holds the group's total.
-    Returns (keys, totals, is_end), ``is_end`` marking those last rows.
+    sorted block's first ``n`` rows, ``_COMBINE[combiners[j]]`` on
+    ``cols[j]``: afterwards the last row of a run holds the run's total
+    (``"first"``: every row of a run holds what its first row held).
+    Returns (keys, scanned columns).
     Rows i and i-d belong to one group exactly when their keys are equal
     (the block is sorted), so doubling d folds a run of length L in
     ceil(log2 L) elementwise passes, and once no pair at distance d is
@@ -229,7 +235,7 @@ def _fold_runs(sk, n, cols, kinds):
     never reaches a valid row. A NaN equals nothing, itself included, and
     stays its own group."""
     b = sk.shape[0]
-    ops = [_COMBINE[STAT_COMBINE[kind]] for kind in kinds]
+    ops = [_COMBINE[c] for c in combiners]
 
     def step(d: int):
         def back(x):  # row i reads row i - d
@@ -257,8 +263,16 @@ def _fold_runs(sk, n, cols, kinds):
         )[2]
     # what the caller derives from the sorted keys next (run ends, destinations)
     # waits behind this barrier for the scan, and so is not held alive across it
-    sk, totals = lax.optimization_barrier((sk, totals))
-    i = lax.iota(jnp.int32, b)
+    return lax.optimization_barrier((sk, totals))
+
+
+def _fold_runs(sk, n, cols, kinds):
+    """Each run of equal keys among a sorted block's first ``n`` rows folded
+    into its last row, ``kinds[j]``'s combiner on ``cols[j]``
+    (:func:`_scan_runs`). Returns (keys, totals, is_end), ``is_end``
+    marking those last rows."""
+    sk, totals = _scan_runs(sk, n, cols, [STAT_COMBINE[kind] for kind in kinds])
+    i = lax.iota(jnp.int32, sk.shape[0])
     is_end = (i < n) & ((i == n - 1) | (sk != jnp.concatenate([sk[1:], sk[-1:]])))
     return sk, totals, is_end
 
@@ -445,9 +459,18 @@ def _join_executable(
     p: int,
     comm: MeshCommunication,
 ):
-    """Device-local merge join of two co-partitioned, exchanged sides:
-    sort both by key, match left rows into the (unique-keyed) right side
-    with one searchsorted, compact (inner) or null-fill (left)."""
+    """Device-local merge join of two co-partitioned, exchanged sides,
+    neither of them in any order. One stable sort by key of the right
+    block with the left block behind it, each side's columns carried
+    (zeros in the other side's rows): within a run of equal keys the
+    right row, which stood earlier, comes first, then the left rows in
+    their own order. :func:`_scan_runs` carries that first row's values,
+    and whether it was a right row at all (``hit``), along the run; a
+    compaction brings the left rows to keep to the front: the matched
+    (inner), or all of them, what found no match NaN-filled (left). No
+    search, no index, no lookup. The result's block is as long as both
+    blocks together: cut back to the left block's length, every column
+    of it would be one more copy."""
     mesh = comm.mesh
     key = ("join", l_pshape, r_pshape, str(key_dtype), l_dtypes, r_dtypes, how, p, mesh)
     fn = _PROGRAMS.get(key)
@@ -455,6 +478,7 @@ def _join_executable(
         return fn
     bl = l_pshape[0] // p
     br = r_pshape[0] // p
+    pad, right, left = (jnp.int8(t) for t in range(3))
 
     def frame_join(lk, lcnt, *rest):
         rk, rcnt = rest[len(l_dtypes)], rest[len(l_dtypes) + 1]
@@ -462,33 +486,34 @@ def _join_executable(
         rvals = list(rest[len(l_dtypes) + 2 :])
         r = lax.axis_index(SPLIT_AXIS)
         nl, nr = lcnt[r], rcnt[r]
-        slp = lax.iota(jnp.int32, bl) >= nl
-        srp = lax.iota(jnp.int32, br) >= nr
-        slk, slv = _sort_by_key(lk, nl, lvals)
-        srk, srv = _sort_by_key(rk, nr, rvals)
-        mk = jnp.asarray(_max_key(srk.dtype))
-        srk2 = jnp.where(srp, mk, srk)
+        i = lax.iota(jnp.int32, br + bl)
+        side = jnp.where(i < nr, right, jnp.where((i >= br) & (i < br + nl), left, pad))
+        # a pad gets the key nothing sorts after, as in _sort_by_key; it may end up inside
+        # the run of a valid row with that very key, where its side tells it apart
+        k = jnp.where(side == pad, jnp.asarray(_last_key(lk.dtype)), jnp.concatenate([rk, lk]))
+        lcols = [jnp.concatenate([jnp.zeros((br,), v.dtype), v]) for v in lvals]
+        rcols = [jnp.concatenate([v, jnp.zeros((bl,), v.dtype)]) for v in rvals]
+        # the side is a payload, not a second key: one comparison a row
+        sk, side, *cols = _carry_sort([k], [side, *lcols, *rcols], stable=True)
+        slv, srv = cols[: len(lvals)], cols[len(lvals) :]
+        is_right = side == right
         # duplicate right keys would silently multiply rows in a merge
         # join — detect and report (replicated via max over shards)
-        dup_local = jnp.any((srk2[1:] == srk2[:-1]) & ~srp[1:] & ~srp[:-1])
+        dup_local = jnp.any(is_right[1:] & is_right[:-1] & (sk[1:] == sk[:-1]))
         dup = lax.pmax(dup_local.astype(jnp.int32), SPLIT_AXIS)
-        # method="sort": two short sorts and two scatters. The default binary search reads
-        # the right keys through a block-long index once a step, 17 steps for 1e5 keys: alone
-        # on a v5e, 1e8 left rows, 12.85 s against 2.38 s (compare_all 22.80 s; PERF.md §6, PR 27)
-        idx = jnp.searchsorted(srk2, jnp.where(slp, mk, slk), side="left", method="sort")
-        idxc = jnp.clip(idx, 0, br - 1)
-        hit = (idx < nr) & (srk2[idxc] == slk) & ~slp
-        gathered = [v[idxc] for v in srv]
+        sk, (hit, *srv) = _scan_runs(sk, br + bl, [is_right, *srv], ["first"] * (1 + len(srv)))
         if how == "inner":
-            g = jnp.sum(hit.astype(jnp.int32))
-            outs = _partition_front((~hit).astype(jnp.int8), [slk, *slv, *gathered])
+            keep, null = (side == left) & hit, 0
+            g = jnp.sum(keep.astype(jnp.int32))
         else:  # left: all valid left rows, unmatched right values -> NaN
+            keep, null = side == left, jnp.nan
             g = nl
-            filled = []
-            for v in gathered:
-                fv = v.astype(jnp.promote_types(v.dtype, jnp.float32))
-                filled.append(jnp.where(hit, fv, jnp.full_like(fv, jnp.nan)))
-            outs = (slk, *slv, *filled)
+            srv = [v.astype(jnp.promote_types(v.dtype, jnp.float32)) for v in srv]
+        # An inner join drops the rows this fills, but the pass pays for itself in memory: its
+        # results are new buffers, so the scan's columns need not outlive the scan, and at 1e8
+        # rows the program holds 4 columns (1.6 GB) less (sandbox compile, PR 28; PERF.md §5)
+        srv = [jnp.where(hit, v, jnp.asarray(null, v.dtype)) for v in srv]
+        outs = _partition_front((~keep).astype(jnp.int8), [sk, *slv, *srv])
         gvec = lax.all_gather(g, SPLIT_AXIS)
         return (*outs, gvec, dup)
 

@@ -166,7 +166,9 @@ class Frame:
         m:1 contract — duplicates raise ``ValueError``). Both sides are
         co-partitioned by ONE shared splitter election, each side pays
         one bounded exchange per operand, then a device-local merge join
-        matches rows. ``how="left"`` NaN-fills unmatched right values
+        matches rows: one stable sort of both sides together, each right
+        row's values carried along the left rows with its key. ``how="left"``
+        NaN-fills unmatched right values
         (right columns promote to float: float32 unless they are wider).
 
         Columns of the result: the key, this frame's others in its

@@ -266,20 +266,44 @@ def _indexed_ops(text: str, b: int):
     return found
 
 
-@pytest.mark.parametrize("which", ["plan", "merge"])
-def test_groupby_program_moves_no_column_through_an_index(topo, which):
+def _one_chip(topo):
     from jax.sharding import Mesh
 
     from heat_tpu.core.communication import SPLIT_AXIS
 
-    mesh = Mesh(np.array(topo.devices[:1]), (SPLIT_AXIS,))
-    compiled = _lower_frame_program(which, mesh, 1).compile()
+    return Mesh(np.array(topo.devices[:1]), (SPLIT_AXIS,))
+
+
+_ONE_CHIP_GROUPBY = {}  # which -> the compiled program: two tests read each, one compile
+
+
+def _one_chip_groupby(topo, which: str):
+    if which not in _ONE_CHIP_GROUPBY:
+        _ONE_CHIP_GROUPBY[which] = _lower_frame_program(which, _one_chip(topo), 1).compile()
+    return _ONE_CHIP_GROUPBY[which]
+
+
+@pytest.mark.parametrize("which", ["plan", "merge"])
+def test_groupby_program_moves_no_column_through_an_index(topo, which):
+    compiled = _one_chip_groupby(topo, which)
     text, column = compiled.as_text(), 4 * _Q5_ROWS
     assert _indexed_ops(text, _Q5_ROWS) == []
     assert " sort(" in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes <= 2 * column, mem.temp_size_in_bytes / column
     assert mem.output_size_in_bytes <= 4.1 * column, mem.output_size_in_bytes / column
+
+
+@pytest.mark.parametrize("which", ["plan", "merge"])
+def test_groupby_program_keeps_its_instruction_mix(topo, which):
+    """The join carries a right row along its run with the groupby's own scan (``_scan_runs``) and a
+    combiner of its own, "first", beside sum, min and max. That is an entry more, not another loop:
+    the groupby's programs hold what they held before the join used the scan (PR 25), the sort by key
+    and the compaction's sort, and the scan's one loop over one switch."""
+    text = _one_chip_groupby(topo, which).as_text()
+    assert text.count(" sort(") == 2
+    assert text.count(" while(") == 1
+    assert text.count(" conditional(") == 1
 
 
 def test_groupby_plan_compiles_over_four_chips(four_chips):
@@ -296,35 +320,62 @@ def test_groupby_plan_compiles_over_four_chips(four_chips):
 
 # --- the join's match program at the widths of h2o.ai db-benchmark's join question 2 (PERF.md §4,
 # `join-q2-medium-inner`): int32 key, five int32 and one f32 payload on the left, three int32 and
-# one f32 on the right, a thousandth of the rows. One compile over the four described chips, not
-# four: a sort's compile time follows its operand count, not its rows, and this program's two
-# wide sorts (8 and 13 operands) make it the slowest compile of the file. No assertion on indexed
-# ops yet: the search's two rank scatters and the five lookups of each left row's match are
-# still there (PERF.md §7); what is held is that each chip takes its quarter and that the
-# temporaries stay under four columns (2.83 over the four at this size: sandbox compile, PR 27).
+# one f32 on the right, a thousandth of the rows. It sorts the right block with the left block
+# behind it (13 operands: key, side, 6 + 4 payloads, the index stability costs), carries each
+# run's first row forward and compacts with a second sort of 13 operands: no search, no lookup,
+# so no gather and no scatter over any of the three block lengths involved. A sort's compile time
+# follows its operand count, not its rows: these two compiles are the slowest of the file, one over
+# the four described chips and one over one chip, the cell's layout. The result's block is as long
+# as both sides' blocks together: the concatenation is written into the result's buffers, and half
+# of the first sort's columns leave them for temporaries until the second sort brings them back
+# (sandbox compile, PR 28: temporaries 3.85 columns over the four chips and 3.84 over one at this
+# size, where the compiler keeps some columns in another memory space; 7.27 at 1e8 rows on one
+# chip, PERF.md §5).
 _Q2_ROWS = 1 << 20
+_Q2_RIGHT_ROWS = _Q2_ROWS // 1024
 _Q2_LEFT = ("int32",) * 5 + ("float32",)
 _Q2_RIGHT = ("int32",) * 3 + ("float32",)
 
 
-def test_join_program_compiles_over_four_chips_at_question_2s_widths(four_chips):
+def _compiled_join(mesh, p: int):
     import jax.numpy as jnp
 
     from heat_tpu.frame import _shuffle
 
-    p = 4
-    comm, rows, rep = _frame_mesh(four_chips)
-    left, right = (p * _Q2_ROWS,), (p * (_Q2_ROWS // 1024),)
+    comm, rows, rep = _frame_mesh(mesh)
+    left, right = (p * _Q2_ROWS,), (p * _Q2_RIGHT_ROWS,)
     fn = _shuffle._join_executable(left, right, jnp.dtype("int32"), _Q2_LEFT, _Q2_RIGHT, "inner", p, comm)
-    compiled = fn.lower(
+    return fn.lower(
         _spec(left, jnp.int32, rows), _spec((p,), jnp.int32, rep), *[_spec(left, jnp.dtype(d), rows) for d in _Q2_LEFT],
         _spec(right, jnp.int32, rows), _spec((p,), jnp.int32, rep), *[_spec(right, jnp.dtype(d), rows) for d in _Q2_RIGHT],
     ).compile()
+
+
+def _join_matches_without_an_index(text: str):
+    for block in (_Q2_ROWS + _Q2_RIGHT_ROWS, _Q2_ROWS, _Q2_RIGHT_ROWS):
+        assert _indexed_ops(text, block) == [], block
+    assert text.count(" sort(") == 2  # both sides together by key; the compaction
+
+
+def test_join_program_compiles_over_four_chips_at_question_2s_widths(four_chips):
+    compiled = _compiled_join(four_chips, 4)
     text, column = compiled.as_text(), 4 * _Q2_ROWS
-    assert " sort(" in text
+    _join_matches_without_an_index(text)
     assert "all-gather" in text or "all-reduce" in text  # the row counts and the duplicate flag, a few words
     mem = compiled.memory_analysis()
     # a chip's arguments are its quarter: 7 left columns, 5 right ones a thousandth as long
     assert mem.argument_size_in_bytes < 7.01 * column + (1 << 20), mem.argument_size_in_bytes / column
-    assert mem.output_size_in_bytes < 11.01 * column + (1 << 20), mem.output_size_in_bytes / column
-    assert mem.temp_size_in_bytes < 4 * column, mem.temp_size_in_bytes / column
+    # eleven columns of both blocks' rows
+    assert mem.output_size_in_bytes < 11 * (1 + 1 / 1024) * column + (1 << 20), mem.output_size_in_bytes / column
+    assert mem.temp_size_in_bytes < 4.5 * column, mem.temp_size_in_bytes / column
+
+
+def test_join_program_fits_one_chip_at_question_2s_widths(topo):
+    """The cell's layout. Everything the program holds at once, in columns of the left table: 7 + 11
+    + the temporaries (21.86 at this size: sandbox compile, PR 28); at 1e8 rows a column is 0.4 GB and
+    the two tables stand beside the program."""
+    compiled = _compiled_join(_one_chip(topo), 1)
+    _join_matches_without_an_index(compiled.as_text())
+    mem, column = compiled.memory_analysis(), 4 * _Q2_ROWS
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+    assert held < 22.5 * column, held / column
