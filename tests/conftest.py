@@ -39,8 +39,13 @@ _WS_SHARED_ROOT = os.environ.get("HEAT_TPU_WS_SHARED_ROOT")
 # watchdog thread needs no GIL: it dumps every thread's stack to the real
 # stderr and exits the process, xdist reports the test as crashed, starts
 # a new worker and the run reaches its end. Longer than the 600 s the
-# multi-process tests give their own children; the slowest test takes
-# about a quarter of it under six workers.
+# multi-process tests give their own children. Under the driver's six
+# workers (PR 29, four runs) the slowest test is test_fuzz_battery.py's
+# one sweep, 242-256 s: 37-39 % of the bound, not the third that was
+# the aim. The slowest chip compile takes 104-122 s (the join's program at
+# one payload a side; at question 2's widths, 352-373 s alone and 463 s
+# beside a full run, it is marked slow). docs/TESTING.md has the rule for
+# a new slow test.
 _TEST_BOUND_S = 660
 
 
@@ -66,44 +71,6 @@ def pytest_runtest_protocol(item, nextitem):
         yield
     finally:
         faulthandler.cancel_dump_traceback_later()
-
-
-def pytest_sessionstart(session):
-    session.config._heat_tpu_t0 = __import__("time").perf_counter()
-
-
-def pytest_sessionfinish(session, exitstatus):
-    """Record suite wall clock into SUITE_SECONDS.json at the repo root so
-    ``bench.py`` can report ``suite_seconds`` alongside the perf metrics.
-    Only the full-suite single-process invocation writes (selected-test
-    runs and tools/mpirun.py pool workers would otherwise clobber the
-    number with noise); ``ws_runs`` records written by tools/mpirun.py
-    are preserved, not overwritten."""
-    import json
-    import time
-
-    t0 = getattr(session.config, "_heat_tpu_t0", None)
-    if t0 is None or session.testscollected < 50 or _WS_SHARED_ROOT:
-        return
-    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                        "SUITE_SECONDS.json")
-    try:
-        try:
-            with open(path, "r") as fh:
-                record = json.load(fh)
-        except (OSError, ValueError):
-            record = {}
-        record.update(
-            {
-                "suite_seconds": round(time.perf_counter() - t0, 1),
-                "tests_collected": session.testscollected,
-                "exit_status": int(exitstatus),
-            }
-        )
-        with open(path, "w") as fh:
-            json.dump(record, fh)
-    except OSError:
-        pass
 
 
 def _rendezvous_dir(root: str, nodeid: str):

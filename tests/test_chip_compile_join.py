@@ -1,0 +1,99 @@
+"""The chip's compiler, asked without the chip: the join's match program.
+
+``frame_join`` compiled for one described v5e chip, the cell's layout, and
+over the four of a ``v5e:2x2``. ``tests/_chip_helpers.py`` says what a
+compile here shows and what it does not.
+"""
+from __future__ import annotations
+
+import pytest
+
+from ._chip_helpers import _frame_mesh, _indexed_ops, _one_chip, _spec, four_chips, topo  # noqa: F401 - fixtures
+
+# --- the join's match program (``_shuffle._join_executable``) on an int32 key, the right table a
+# thousandth of the left one's rows as in h2o.ai db-benchmark's join question 2 (PERF.md §4,
+# `join-q2-medium-inner`). It sorts the right block with the left block behind it (key, side, every
+# payload of both sides, the index stability costs), carries each run's first row forward and
+# compacts with a second sort of as many operands: no search, no lookup, so no gather and no scatter
+# over any of the three block lengths involved. The result's block is as long as both sides' blocks
+# together: the concatenation is written into the result's buffers, and half of the first sort's
+# columns leave them for temporaries until the second sort brings them back.
+#
+# A sort's compile time follows its operand count, not its rows, and what these tests ask does not
+# follow it: the structure (two sorts, nothing indexed, the collectives, a chip's arguments being
+# its share) is the same with one payload a side as with six and four, and the memory bounds are
+# linear in the payloads and stated in columns of the left table. So tier-1 compiles
+# ``one_payload`` (one int32 payload left, one f32 right: sorts of 5 operands, the groupby's price)
+# for both layouts, and a program that began to hold a second copy of its columns would show there
+# as it would at question 2's widths. ``question_2`` (five int32 and one f32 payload left, three
+# int32 and one f32 right: sorts of 13 operands) is the cell's own program a hundredth as long, on
+# one chip only and marked ``slow``: 352-373 s alone on the sandbox and 463 s beside a full run
+# (PR 29), 53-70 % of the bound conftest gives a test. Whoever changes ``_join_executable``,
+# ``_carry_sort``, ``_scan_runs`` or ``_partition_front`` runs it:
+#     pytest -m slow tests/test_chip_compile_join.py
+# (sandbox compiles at question 2's widths, PR 28: temporaries 3.85 columns over the four chips
+# and 3.84 over one at this size, where the compiler keeps some columns in another memory space,
+# everything held 21.86; 7.27 columns of temporaries at 1e8 rows on one chip, PERF.md §5). Over
+# four chips those widths come back with a four-chip join cell (ROADMAP.md, Queue 2).
+_ROWS = 1 << 20
+_RIGHT_ROWS = _ROWS // 1024
+_COLUMN = 4 * _ROWS  # bytes: every column here is 32 bits wide
+
+# widths -> (left payloads, right payloads, most temporaries over four chips, most held on one chip),
+# the last two in columns, each pinned over the sandbox's compile with the margin question 2's bound
+# has over its own: one payload 2.75 and 8.13 (2 + 3 + 3.12 of temporaries; PR 29), question 2
+# 3.85 and 21.86 (PR 28)
+_WIDTHS = {
+    "one_payload": (("int32",), ("float32",), 3.2, 8.4),
+    "question_2": (("int32",) * 5 + ("float32",), ("int32",) * 3 + ("float32",), 4.5, 22.5),
+}
+
+
+def _compiled_join(mesh, p: int, left, right):
+    import jax.numpy as jnp
+
+    from heat_tpu.frame import _shuffle
+
+    comm, rows, rep = _frame_mesh(mesh)
+    lshape, rshape = (p * _ROWS,), (p * _RIGHT_ROWS,)
+    fn = _shuffle._join_executable(lshape, rshape, jnp.dtype("int32"), left, right, "inner", p, comm)
+    return fn.lower(
+        _spec(lshape, jnp.int32, rows), _spec((p,), jnp.int32, rep), *[_spec(lshape, jnp.dtype(d), rows) for d in left],
+        _spec(rshape, jnp.int32, rows), _spec((p,), jnp.int32, rep), *[_spec(rshape, jnp.dtype(d), rows) for d in right],
+    ).compile()
+
+
+def _join_matches_without_an_index(text: str):
+    for block in (_ROWS + _RIGHT_ROWS, _ROWS, _RIGHT_ROWS):
+        assert _indexed_ops(text, block) == [], block
+    assert text.count(" sort(") == 2  # both sides together by key; the compaction
+
+
+@pytest.mark.parametrize("widths", ["one_payload"])
+def test_join_program_compiles_over_four_chips(four_chips, widths):
+    left, right, most_temp, _ = _WIDTHS[widths]
+    compiled = _compiled_join(four_chips, 4, left, right)
+    text = compiled.as_text()
+    _join_matches_without_an_index(text)
+    assert "all-gather" in text or "all-reduce" in text  # the row counts and the duplicate flag, a few words
+    mem = compiled.memory_analysis()
+    # a chip's arguments are its quarter: the left table's columns, the right one's a thousandth as long
+    arguments = (1 + len(left)) + (1 + len(right)) / 1024
+    assert mem.argument_size_in_bytes < arguments * _COLUMN + (1 << 20), mem.argument_size_in_bytes / _COLUMN
+    # the key and every payload, of both blocks' rows
+    outputs = (1 + len(left) + len(right)) * (1 + 1 / 1024)
+    assert mem.output_size_in_bytes < outputs * _COLUMN + (1 << 20), mem.output_size_in_bytes / _COLUMN
+    assert mem.temp_size_in_bytes < most_temp * _COLUMN, mem.temp_size_in_bytes / _COLUMN
+
+
+@pytest.mark.parametrize("widths", ["one_payload", pytest.param("question_2", marks=pytest.mark.slow)])
+def test_join_program_fits_one_chip(topo, widths):
+    """The cell's layout. Everything the program holds at once, in columns of the left table: its
+    arguments, its outputs and its temporaries (7 + 11 + 3.84 at question 2's widths; at 1e8 rows a
+    column is 0.4 GB and the two tables stand beside the program)."""
+    left, right, _, most_held = _WIDTHS[widths]
+    compiled = _compiled_join(_one_chip(topo), 1, left, right)
+    _join_matches_without_an_index(compiled.as_text())
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+    assert held < most_held * _COLUMN, held / _COLUMN

@@ -1,0 +1,352 @@
+"""``Frame.join``: the verb's own contracts against numpy, then every
+column against the plain reference (``heat_tpu/frame/reference.py``'s
+``join_m1``) over meshes of 1, 4 and 8 devices, both partition modes and
+the cases the merge makes delicate. The groupby, the container and the
+engine's contracts are in ``tests/test_frame.py``; the world-size sweep
+rides the same runs as that file's.
+"""
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+import heat_tpu as ht
+from heat_tpu.analysis.sanitizer import sanitizer
+from heat_tpu.frame import Frame
+from heat_tpu.parallel.flatmove import MOVE_STATS
+
+from . import _mh_helpers as mh
+from ._frame_helpers import ROWS, _release_executables, _sorted_dict, rng  # noqa: F401 - fixtures
+
+
+class TestJoin:
+    def test_inner_join_oracle(self, rng):
+        lk = rng.integers(0, 30, size=ROWS).astype(np.int32)
+        lx = rng.normal(size=ROWS).astype(np.float32)
+        rk = np.arange(0, 20, dtype=np.int32)  # unique right keys 0..19
+        ry = rng.normal(size=20).astype(np.float32)
+        out = Frame({"k": lk, "x": lx}).join(Frame({"k": rk, "y": ry}), on="k")
+        d = out.to_dict()
+        keep = lk < 20
+        assert len(d["k"]) == int(keep.sum())
+        order = np.lexsort((d["x"], d["k"]))
+        worder = np.lexsort((lx[keep], lk[keep]))
+        np.testing.assert_array_equal(d["k"][order], lk[keep][worder])
+        np.testing.assert_allclose(d["x"][order], lx[keep][worder], rtol=1e-6)
+        np.testing.assert_allclose(d["y"][order], ry[lk[keep]][worder], rtol=1e-6)
+
+    def test_left_join_nan_fills(self, rng):
+        lk = np.array([0, 1, 5, 9, 3], np.int32)
+        lx = np.arange(5, dtype=np.float32)
+        rk = np.array([0, 1, 2, 3], np.int32)
+        ry = np.array([10.0, 11.0, 12.0, 13.0], np.float32)
+        out = Frame({"k": lk, "x": lx}).join(
+            Frame({"k": rk, "y": ry}), on="k", how="left"
+        )
+        d = _sorted_dict(out, "k")
+        np.testing.assert_array_equal(d["k"], [0, 1, 3, 5, 9])
+        np.testing.assert_array_equal(d["x"], [0.0, 1.0, 4.0, 2.0, 3.0])
+        np.testing.assert_allclose(d["y"][:3], [10.0, 11.0, 13.0])
+        assert np.isnan(d["y"][3:]).all()  # unmatched left rows
+
+    def test_join_exchange_budget(self, rng):
+        lk = rng.integers(0, 16, size=100).astype(np.int32)
+        f = Frame({"k": lk, "x": np.ones(100, np.float32)})
+        small = Frame({"k": np.arange(16, dtype=np.int32), "y": np.ones(16, np.float32)})
+        f.join(small, on="k")  # cold
+        before = MOVE_STATS["bucket_moves"]
+        f.join(small, on="k")
+        # each side ships key + payload once: (1+1) + (1+1)
+        assert MOVE_STATS["bucket_moves"] - before == 4
+
+    def test_duplicate_right_keys_raise(self):
+        f = Frame({"k": np.array([0, 1], np.int32), "x": np.ones(2, np.float32)})
+        dup = Frame({"k": np.array([1, 1], np.int32), "y": np.ones(2, np.float32)})
+        with pytest.raises(ValueError, match="unique keys"):
+            f.join(dup, on="k")
+
+    def test_join_validation(self):
+        f = Frame({"k": np.array([0, 1], np.int32), "x": np.ones(2, np.float32)})
+        g = Frame({"k": np.array([0, 1], np.float32), "x": np.ones(2, np.float32)})
+        with pytest.raises(KeyError, match="join key"):
+            f.join(g, on="missing")
+        with pytest.raises(TypeError, match="dtypes differ"):
+            f.join(g, on="k")
+        h = Frame({"k": np.array([0, 1], np.int32), "x_r": np.ones(2, np.float32),
+                   "x": np.ones(2, np.float32)})
+        with pytest.raises(ValueError, match="collision"):
+            f.join(h, on="k")
+        # default rsuffix disambiguates the shared value-column name
+        out = f.join(
+            Frame({"k": np.array([0, 1], np.int32), "x": np.ones(2, np.float32)}),
+            on="k",
+        )
+        assert set(out.columns) == {"k", "x", "x_r"}
+
+
+# --- Frame.join against the plain reference (heat_tpu/frame/reference.py), at the shapes of
+# h2o.ai db-benchmark's join question 2 ("medium inner on int"), cut to 2e4 rows: x with seven
+# columns, ``medium`` (rows/1000 rows, its id2 unique) with five, three names in both; a key
+# column's values come from a shuffled pool of 1.1 n, the first 0.9 n on both sides, the next
+# 0.1 n in x only, the last 0.1 n in ``medium`` only, so about nine rows in ten of x match.
+_J_ROWS, _J_MEDIUM = 20_000, 20
+
+
+def _h2o_join_tables(rng):
+    def pool(n):
+        values = (rng.permutation(n + n // 10) + 1).astype(np.int32)
+        return values[:n], np.concatenate([values[: n - n // 10], values[n:]])
+
+    x1, m1 = pool(10)
+    x2, m2 = pool(_J_MEDIUM)
+    x = {"id1": rng.choice(x1, _J_ROWS), "id2": rng.choice(x2, _J_ROWS),
+         "id3": rng.integers(1, _J_ROWS + 1, _J_ROWS).astype(np.int32)}
+    x.update(id4=x["id1"].copy(), id5=x["id2"].copy(), id6=x["id3"].copy(),
+             v1=rng.uniform(0, 100, _J_ROWS).astype(np.float32))
+    medium = {"id1": rng.choice(m1, _J_MEDIUM), "id2": rng.permutation(m2)}
+    medium.update(id4=medium["id1"].copy(), id5=medium["id2"].copy(),
+                  v2=rng.uniform(0, 100, _J_MEDIUM).astype(np.float32))
+    return x, medium
+
+_MERGE_ROWS, _MERGE_RIGHT = 300, 48  # one size a side: one set of programs a mesh, a ``how`` and a key type
+
+
+def _mesh_of(devices: int):
+    import jax
+
+    if devices > len(jax.devices()) or (jax.process_count() > 1 and devices % jax.process_count()):
+        pytest.skip(f"no mesh of {devices} devices here")
+    return ht.MeshCommunication(devices=mh.submesh(devices))
+
+
+def _frame_on(table, comm) -> Frame:
+    return Frame({c: ht.array(a, split=0, comm=comm) for c, a in table.items()})
+
+
+def _assert_columns_equal(got, want):
+    """Every column of ``want`` in ``got`` with its dtype, bit for bit (NaN equals NaN, -0.0 equals 0.0)."""
+    for name in want:
+        assert got[name].dtype == want[name].dtype, name
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
+def _merge_tables(rng, lk, rk):
+    """(left, right) around two key columns: ``a`` on both sides, ``v`` left, ``w`` right."""
+    lk, rk = np.asarray(lk), np.asarray(rk)
+    rk = rk.astype(lk.dtype)
+    assert lk.shape == (_MERGE_ROWS,) and rk.shape == (_MERGE_RIGHT,)
+    left = {"k": lk, "a": rng.integers(-9, 9, lk.size).astype(np.int32), "v": rng.normal(size=lk.size).astype(np.float32)}
+    right = {"k": rk, "a": rng.integers(100, 999, rk.size).astype(np.int32), "w": rng.normal(size=rk.size).astype(np.float32)}
+    return left, right
+
+
+def _max_key_case(with_right_row: bool):
+    def make(rng):
+        top = np.iinfo(np.int32).max
+        lk = rng.integers(top - 40, top, _MERGE_ROWS, dtype=np.int64)
+        lk[rng.permutation(_MERGE_ROWS)[:25]] = top  # on every shard, beside its pads
+        rk = top - 1 - rng.permutation(60)[:_MERGE_RIGHT]
+        if with_right_row:
+            rk[rng.integers(_MERGE_RIGHT)] = top
+        return _merge_tables(rng, lk.astype(np.int32), rk)
+
+    return make
+
+
+def _nan_case(rng):
+    lk = rng.integers(0, 40, _MERGE_ROWS).astype(np.float32)
+    lk[rng.permutation(_MERGE_ROWS)[:30]] = np.nan
+    lk[rng.permutation(_MERGE_ROWS)[:10]] = np.inf
+    rk = rng.permutation(60)[:_MERGE_RIGHT].astype(np.float32)
+    rk[[2, 17, 30]] = np.nan  # three NaNs are no duplicate: none equals another
+    rk[5] = np.inf
+    return _merge_tables(rng, lk, rk)
+
+
+def _zeros_case(rng):
+    lk = rng.integers(-3, 4, _MERGE_ROWS).astype(np.float32)
+    lk[(lk == 0) & (rng.random(_MERGE_ROWS) < 0.5)] = -0.0
+    assert np.signbit(lk[lk == 0]).any() and not np.signbit(lk[lk == 0]).all()
+    rk = np.arange(10, 10 + _MERGE_RIGHT, dtype=np.float32)
+    rk[[40, 3, 11, 25]] = [2.0, -0.0, -1.0, 5.0]  # the right row's zero is the negative one
+    return _merge_tables(rng, lk, rk)
+
+
+def _long_run_case(rng):
+    """One key on 2**7 + 3 rows, from the middle of the first shard's block (on eight devices: 38
+    rows) over the next ones, so the run crosses shards before the exchange and a power of two
+    after it; its right row exists. A second run of 2**6 + 1 rows finds none."""
+    lk = rng.integers(0, 30, _MERGE_ROWS)
+    lk[19 : 19 + 131] = 77
+    lk[200:265] = 88
+    return _merge_tables(rng, lk.astype(np.int32), np.concatenate([rng.permutation(70)[: _MERGE_RIGHT - 1], [77]]))
+
+
+_MERGE_CASES = {
+    "max_key_with_its_right_row": _max_key_case(True),
+    "max_key_without_a_right_row": _max_key_case(False),
+    "nan_keys": _nan_case,
+    "signed_zeros": _zeros_case,
+    "none_matched": lambda rng: _merge_tables(rng, 2 * rng.integers(0, 50, _MERGE_ROWS), 2 * rng.permutation(50)[:_MERGE_RIGHT] + 1),
+    "all_matched": lambda rng: _merge_tables(rng, rng.integers(0, 37, _MERGE_ROWS), rng.permutation(_MERGE_RIGHT)),
+    "a_run_across_a_power_of_two": _long_run_case,
+}
+
+
+class TestJoinAgainstReference:
+    @pytest.mark.parametrize("mode", ["range", "hash"])
+    @pytest.mark.parametrize("devices", [1, 4, 8])
+    @pytest.mark.parametrize("how", ["inner", "left"])
+    def test_every_column_equals_the_references(self, how, devices, mode):
+        from heat_tpu.frame.reference import join_m1
+        from heat_tpu.frame._shuffle import shard_counts
+
+        comm = _mesh_of(devices)
+        x, medium = _h2o_join_tables(np.random.default_rng([27, devices]))
+        # x as a filter leaves it: ragged, and on a mesh its second shard empty (the rows
+        # it drops stay behind the kept ones as the pads' content, keys that would match)
+        keep = np.random.default_rng(devices).random(_J_ROWS) < 0.9
+        block = -(-_J_ROWS // devices)
+        if devices > 1:
+            keep[block : 2 * block] = False
+        full = Frame({c: ht.array(a, split=0, comm=comm) for c, a in x.items()})
+        left = full.filter(ht.array(keep, split=0, comm=comm))
+        assert shard_counts(left["id2"]) == tuple(int(keep[r * block : (r + 1) * block].sum()) for r in range(devices))
+        right = Frame({c: ht.array(a, split=0, comm=comm) for c, a in medium.items()})
+        xk = {c: a[keep] for c, a in x.items()}
+        matched = np.isin(xk["id2"], medium["id2"])
+        assert 0.85 < matched.mean() < 0.95 and not np.isin(medium["id2"], xk["id2"]).all()
+
+        want = join_m1(xk, medium, on="id2", how=how)
+        out = left.join(right, on="id2", how=how, mode=mode)
+        got = out.to_dict()
+        assert out.columns == tuple(want) == ("id2", "id1", "id3", "id4", "id5", "id6", "v1", "id1_r", "id4_r", "id5_r", "v2")
+        if mode == "hash":
+            # equal keys share a shard and each shard is in the promised order, the shards are
+            # not: one stable sort by key of the shards laid end to end is the promised order
+            order = np.argsort(got["id2"], kind="stable")
+            got = {c: a[order] for c, a in got.items()}
+        _assert_columns_equal(got, want)  # range: row for row as it stands, over the whole mesh
+
+    def test_a_second_call_compiles_nothing_and_leaves_both_frames_as_they_were(self):
+        from heat_tpu.frame.reference import join_m1
+
+        x, medium = _h2o_join_tables(np.random.default_rng(28))
+        left, right = Frame(x), Frame(medium)
+        first = left.join(right, on="id2").to_dict()  # cold
+        with sanitizer("warm frame join") as region:
+            again = left.join(right, on="id2")
+        assert region.compiles == 0, region.stats()
+        assert region.traces == 0, region.stats()
+        want = join_m1(x, medium, on="id2")
+        for name, col in again.to_dict().items():
+            np.testing.assert_array_equal(col, first[name], err_msg=name)
+            np.testing.assert_array_equal(col, want[name], err_msg=name)
+        # what a call may never do, with or without donated buffers: touch a user's column
+        for frame, table in ((left, x), (right, medium)):
+            for name, col in frame.to_dict().items():
+                np.testing.assert_array_equal(col, table[name], err_msg=name)
+
+    # --- what the merge makes delicate: one stable sort of the right block with the left block
+    # behind it, the pads of both inside it under the key nothing sorts after, the right row's
+    # values carried forward along each run of equal keys. Tables of ``_MERGE_ROWS`` left rows
+    # over two columns a side, one name in both; each case builds (left, right) as NumPy dicts.
+    @pytest.mark.parametrize("devices", [1, 4, 8])
+    @pytest.mark.parametrize("how", ["inner", "left"])
+    @pytest.mark.parametrize("case", sorted(_MERGE_CASES))
+    def test_the_merge_equals_the_reference(self, case, how, devices):
+        from heat_tpu.frame.reference import join_m1
+
+        comm = _mesh_of(devices)
+        x, y = _MERGE_CASES[case](np.random.default_rng([28, devices]))
+        want = join_m1(x, y, on="k", how=how)
+        out = _frame_on(x, comm).join(_frame_on(y, comm), on="k", how=how)
+        got = out.to_dict()
+        assert out.columns == tuple(want) == ("k", "a", "v", "a_r", "w")
+        _assert_columns_equal(got, want)
+        if case == "signed_zeros":  # the key a row comes out with is its own, sign and all
+            np.testing.assert_array_equal(np.signbit(got["k"]), np.signbit(want["k"]))
+        if case in ("none_matched", "all_matched"):  # the case is what its name says
+            assert np.isin(x["k"], y["k"]).all() == (case == "all_matched") == np.isin(x["k"], y["k"]).any()
+
+    @pytest.mark.parametrize("devices", [1, 4, 8])
+    @pytest.mark.parametrize("how", ["inner", "left"])
+    @pytest.mark.parametrize("side", ["left", "right"])
+    def test_an_empty_shard_on_either_side(self, side, how, devices):
+        """A side whose second shard (its only one, on one device) holds no row: a filter's result."""
+        from heat_tpu.frame.reference import join_m1
+        from heat_tpu.frame._shuffle import shard_counts
+
+        comm = _mesh_of(devices)
+        rng = np.random.default_rng([29, devices])
+        x, y = _merge_tables(rng, rng.integers(0, 60, _MERGE_ROWS), rng.permutation(80)[:_MERGE_RIGHT])
+        tables, keep = {"left": x, "right": y}, {}
+        for name, table in tables.items():
+            n = len(table["k"])
+            keep[name] = np.ones(n, bool)
+            if name == side:
+                block = -(-n // devices)
+                keep[name][block : 2 * block] = False
+                if devices == 1:
+                    keep[name][:] = False
+        frames = {name: _frame_on(table, comm).filter(ht.array(keep[name], split=0, comm=comm))
+                  for name, table in tables.items()}
+        assert 0 in shard_counts(frames[side]["k"])
+        want = join_m1(*({c: a[keep[name]] for c, a in tables[name].items()} for name in ("left", "right")), on="k", how=how)
+        _assert_columns_equal(frames["left"].join(frames["right"], on="k", how=how).to_dict(), want)
+
+    @pytest.mark.parametrize("devices", [1, 4, 8])
+    @pytest.mark.parametrize("how", ["inner", "left"])
+    @pytest.mark.parametrize("where", ["adjacent", "first_and_last", "the_maximum_twice"])
+    def test_a_key_twice_on_the_right_raises(self, where, how, devices):
+        from heat_tpu.frame.reference import join_m1
+
+        comm = _mesh_of(devices)
+        rng = np.random.default_rng([30, devices])
+        rk = rng.permutation(200)[:_MERGE_RIGHT].astype(np.int32)
+        if where == "adjacent":
+            rk[21] = rk[20]
+        elif where == "first_and_last":  # of the right table, so on a mesh in two shards before the exchange
+            rk[-1] = rk[0]
+        else:  # beside the pads, which carry that key too
+            rk[3] = rk[40] = np.iinfo(np.int32).max
+        x, y = _merge_tables(rng, rng.integers(0, 200, _MERGE_ROWS), rk)
+        with pytest.raises(ValueError, match="unique keys"):
+            join_m1(x, y, on="k", how=how)
+        with pytest.raises(ValueError, match="unique keys"):
+            _frame_on(x, comm).join(_frame_on(y, comm), on="k", how=how)
+
+    @pytest.mark.parametrize("devices", [1, 4, 8])
+    def test_a_left_join_promotes_the_right_columns_as_the_docs_say(self, devices):
+        """docs/FRAME.md: a float column of 32 bits or more keeps its type, anything else becomes
+        float32; the key and the left frame's columns keep theirs; an inner join changes none."""
+        comm = _mesh_of(devices)
+        n = 64
+        right = {"k": np.arange(n, dtype=np.int32), "i8": np.arange(n, dtype=np.int8), "i32": np.arange(n, dtype=np.int32),
+                 "b": np.arange(n) % 2 == 0, "f16": np.arange(n, dtype=np.float16), "f32": np.arange(n, dtype=np.float32)}
+        left = {"k": (np.arange(3 * n, dtype=np.int32) * 7) % (n + 9), "u8": np.arange(3 * n, dtype=np.uint8)}
+        lf, rf = _frame_on(left, comm), _frame_on(right, comm)
+        inner, outer = lf.join(rf, on="k").to_dict(), lf.join(rf, on="k", how="left").to_dict()
+        assert {c: str(a.dtype) for c, a in inner.items()} == {
+            "k": "int32", "u8": "uint8", "i8": "int8", "i32": "int32", "b": "bool", "f16": "float16", "f32": "float32"}
+        assert {c: str(a.dtype) for c, a in outer.items()} == {
+            "k": "int32", "u8": "uint8", "i8": "float32", "i32": "float32", "b": "float32", "f16": "float32", "f32": "float32"}
+        assert np.isnan(outer["i32"][outer["k"] >= n]).all() and not np.isnan(outer["i32"][outer["k"] < n]).any()
+        np.testing.assert_array_equal(outer["i32"][outer["k"] < n], outer["k"][outer["k"] < n])
+
+    @pytest.mark.parametrize("devices", [1, 4, 8])
+    @pytest.mark.parametrize("how", ["inner", "left"])
+    def test_no_buffer_of_either_frame_is_changed_or_deleted(self, how, devices):
+        """Whatever the library donates between its own programs, a caller's column is none of
+        it: after the call every buffer of both frames is alive, the same object, and holds
+        what it held."""
+        comm = _mesh_of(devices)
+        x, y = _MERGE_CASES["all_matched"](np.random.default_rng([31, devices]))
+        frames = [(_frame_on(x, comm), x), (_frame_on(y, comm), y)]
+        held = [[f[c]._raw for c in f.columns] for f, _ in frames]
+        for _ in range(2):
+            frames[0][0].join(frames[1][0], on="k", how=how).to_dict()
+        for (f, table), bufs in zip(frames, held):
+            for c, buf in zip(f.columns, bufs):
+                assert f[c]._raw is buf and not buf.is_deleted(), c
+                np.testing.assert_array_equal(np.asarray(f[c].numpy()), table[c], err_msg=c)

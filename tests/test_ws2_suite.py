@@ -89,8 +89,6 @@ def test_ws2_sampled_shard_and_budget(tmp_path):
     })
     data = mpirun.load_suite_seconds()
     assert data["ws_runs"]["ws2_shard"]["suite_seconds"] == recorded
-    # the tier-1 keys the conftest writer owns must have survived the merge
-    assert "suite_seconds" in data
 
 
 @pytest.mark.slow

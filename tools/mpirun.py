@@ -90,7 +90,7 @@ def load_suite_seconds(path=None) -> dict:
 
 def record_ws_run(key: str, summary: dict, path=None) -> None:
     """Merge this run into ``SUITE_SECONDS.json`` under ``ws_runs.KEY``,
-    preserving the tier-1 keys the conftest writer owns."""
+    leaving every other key of the file as it was."""
     path = path or os.path.join(REPO_ROOT, "SUITE_SECONDS.json")
     data = load_suite_seconds(path)
     runs = data.setdefault("ws_runs", {})
