@@ -39,8 +39,13 @@ column moves as an operand of a sort the program runs anyway
 (:func:`_fold_runs`), and a compaction is a sort on a small leading key.
 A join matches the same way: both sides sorted together, the right row
 first in each run of equal keys, its values carried along the run
-(:func:`_scan_runs`). The one indexed access left is the election's 32
-samples.
+(:func:`_scan_runs`). That sort is the only key order a join needs, so
+the co-partitioning before it makes none: a row partition is one stable
+sort by destination, and rows arrive grouped by destination, in their
+source's own order within one. On a mesh of one device there is nothing
+to bring together and the join program reads the callers' buffers as
+they stand: no election, no partition, no bucket move. The one indexed
+access left is the election's 32 samples.
 
 Partition decisions are REPLICATED at every step: splitters come out of
 an ``all_gather`` inside the program, bucket matrices are identical on
@@ -90,8 +95,10 @@ __all__ = [
 _PROGRAMS = ExecutableCache(maxsize=128)
 
 # running counters: tests and bench read these alongside MOVE_STATS to
-# assert the engine's exchange budget and cache behavior
-SHUFFLE_STATS = {"groupbys": 0, "joins": 0, "compactions": 0}
+# assert the engine's exchange budget and cache behavior; "row_shuffles"
+# counts the sides a join really partitioned and exchanged (2 a join on
+# a mesh, 0 on one device)
+SHUFFLE_STATS = {"groupbys": 0, "joins": 0, "compactions": 0, "row_shuffles": 0}
 
 # how each statistic kind folds in the merge stage (all associative)
 STAT_COMBINE = {"sum": "sum", "sumsq": "sum", "count": "sum", "min": "min", "max": "max"}
@@ -423,9 +430,13 @@ def _partition_executable(
     mode: str,
     comm: MeshCommunication,
 ):
-    """Row partition program (no pre-aggregation — the join path): sort
-    rows by key, tag destinations, destination-major sort, replicated
-    bucket matrix."""
+    """Row partition program (no pre-aggregation — the join path): each
+    row's destination from the block as it stands (both destination
+    functions are elementwise), one stable sort by destination carrying
+    the key and the payloads, replicated bucket matrix. No key order is
+    made: the join sorts by key itself, and a stable partition keeps a
+    source's rows in their own order within each destination, which is
+    all the result's row order rests on."""
     mesh = comm.mesh
     key = ("part", pshape, str(key_dtype), payload_dtypes, p, mode, mesh)
     fn = _PROGRAMS.get(key)
@@ -435,11 +446,11 @@ def _partition_executable(
 
     def frame_partition(kb, counts, splitters, *vals):
         n = counts[lax.axis_index(SPLIT_AXIS)]
-        sk, svals = _sort_by_key(kb, n, list(vals))
-        pid = _range_pid(sk, splitters) if mode == "range" else _hash_pid(sk, p)
+        pid = _range_pid(kb, splitters) if mode == "range" else _hash_pid(kb, p)
+        # the pads go behind the last destination, whatever they hold
         pid = jnp.where(lax.iota(jnp.int32, b) < n, pid, p)
         mat = _dest_matrix(pid, p)
-        return (*_partition_front(pid, [sk, *svals]), mat)
+        return (*_partition_front(pid, [kb, *vals]), mat)
 
     spec = P(SPLIT_AXIS)
     in_specs = (spec, P(), P(), *([spec] * len(payload_dtypes)))
@@ -639,8 +650,9 @@ def shuffle_rows(
 ) -> Tuple[List[jax.Array], np.ndarray, int]:
     """Full-row shuffle (no combining): co-locate equal keys. Returns
     (moved [key, *payload] buffers, per-shard out_counts, b_out). Rows
-    arrive locally sorted by destination then key; pass ``splitters`` to
-    reuse a prior election (both sides of a join must agree)."""
+    arrive grouped by source shard, in their source's own order within
+    one, in no key order; pass ``splitters`` to reuse a prior election
+    (both sides of a join must agree)."""
     comm = key_col.comm
     p = comm.size
     kb = key_col._raw
@@ -657,6 +669,7 @@ def shuffle_rows(
     out = collective_lockstep(part(kb, counts, splitters, *payload_bufs))
     mat_np = _hooks.fetch(out[-1], "shuffle.bucket_matrix")
     moved, out_counts, b_out = _exchange_operands(list(out[:-1]), mat_np, comm)
+    SHUFFLE_STATS["row_shuffles"] += 1
     return moved, out_counts, b_out
 
 
@@ -668,30 +681,39 @@ def hash_join(
     how: str = "inner",
     mode: str = "range",
 ) -> Tuple[List[jax.Array], np.ndarray, int]:
-    """Distributed join: co-partition both sides with ONE shared splitter
-    election, one exchange per operand on each side, then a device-local
-    merge join. Right keys must be unique (m:1 join — the hash-join
-    contract pandas calls ``validate="m:1"``). Returns (result buffers
-    ``[key, *left_cols, *right_cols]``, per-shard counts, dup_flag).
-    Left-join right columns are promoted to float and NaN-filled."""
+    """Distributed join: on a mesh of more than one device co-partition
+    both sides with ONE shared splitter election and one exchange per
+    operand on each side; then a device-local merge join, which sorts by
+    key itself. On a mesh of one device equal keys are together already:
+    the merge reads the callers' buffers under their own counts, nothing
+    is elected, partitioned or moved, and ``mode`` has no effect. No
+    buffer is donated. Right keys must be unique (m:1 join — the
+    hash-join contract pandas calls ``validate="m:1"``). Returns (result
+    buffers ``[key, *left_cols, *right_cols]``, per-shard counts,
+    dup_flag). Left-join right columns are promoted to float and
+    NaN-filled."""
     if how not in ("inner", "left"):
         raise ValueError(f"how must be 'inner' or 'left', got {how!r}")
     comm = l_key.comm
     p = comm.size
-    splitters = None
-    if mode == "range":
-        elect = _elect_executable(
-            (tuple(l_key._raw.shape), tuple(r_key._raw.shape)),
-            l_key._raw.dtype, p, comm,
-        )
-        splitters = collective_lockstep(
-            elect(
-                l_key._raw, r_key._raw,
-                _counts_vec(shard_counts(l_key)), _counts_vec(shard_counts(r_key)),
+    if p == 1:
+        l_moved, l_counts = [l_key._raw, *l_bufs], shard_counts(l_key)
+        r_moved, r_counts = [r_key._raw, *r_bufs], shard_counts(r_key)
+    else:
+        splitters = None
+        if mode == "range":
+            elect = _elect_executable(
+                (tuple(l_key._raw.shape), tuple(r_key._raw.shape)),
+                l_key._raw.dtype, p, comm,
             )
-        )
-    l_moved, l_counts, _ = shuffle_rows(l_key, l_bufs, mode, splitters)
-    r_moved, r_counts, _ = shuffle_rows(r_key, r_bufs, mode, splitters)
+            splitters = collective_lockstep(
+                elect(
+                    l_key._raw, r_key._raw,
+                    _counts_vec(shard_counts(l_key)), _counts_vec(shard_counts(r_key)),
+                )
+            )
+        l_moved, l_counts, _ = shuffle_rows(l_key, l_bufs, mode, splitters)
+        r_moved, r_counts, _ = shuffle_rows(r_key, r_bufs, mode, splitters)
     join = _join_executable(
         tuple(l_moved[0].shape), tuple(r_moved[0].shape), l_moved[0].dtype,
         tuple(str(b.dtype) for b in l_moved[1:]),

@@ -163,13 +163,15 @@ class Frame:
         mode: str = "range",
     ) -> "Frame":
         """Join on a shared key column; right keys must be unique (the
-        m:1 contract — duplicates raise ``ValueError``). Both sides are
-        co-partitioned by ONE shared splitter election, each side pays
-        one bounded exchange per operand, then a device-local merge join
-        matches rows: one stable sort of both sides together, each right
-        row's values carried along the left rows with its key. ``how="left"``
-        NaN-fills unmatched right values
-        (right columns promote to float: float32 unless they are wider).
+        m:1 contract — duplicates raise ``ValueError``). On a mesh of
+        more than one device both sides are co-partitioned by ONE shared
+        splitter election and each side pays one bounded exchange per
+        operand; on a mesh of one device equal keys are together already
+        and nothing is elected, partitioned or moved. Then a device-local
+        merge join matches rows: one stable sort of both sides together,
+        each right row's values carried along the left rows with its
+        key. ``how="left"`` NaN-fills unmatched right values (right
+        columns promote to float: float32 unless they are wider).
 
         Columns of the result: the key, this frame's others in its
         order, ``other``'s others in its order (``rsuffix`` appended to
@@ -179,7 +181,8 @@ class Frame:
         result is in that order, row for row what
         :func:`heat_tpu.frame.reference.join_m1` returns. ``"hash"`` only
         co-locates equal keys: each shard is ordered, the shards are
-        not. Neither input is changed or consumed."""
+        not (on one device ``mode`` has no effect). Neither input is
+        changed or consumed."""
         if on not in self._cols or on not in other._cols:
             raise KeyError(f"join key {on!r} must exist in both frames")
         lk, rk = self._cols[on], other._cols[on]

@@ -350,3 +350,90 @@ class TestJoinAgainstReference:
             for c, buf in zip(f.columns, bufs):
                 assert f[c]._raw is buf and not buf.is_deleted(), c
                 np.testing.assert_array_equal(np.asarray(f[c].numpy()), table[c], err_msg=c)
+
+
+# --- what the co-partitioning does and does not do (PR 30). The merge sorts both sides by key
+# itself, so the partition makes no key order; and on a mesh of one device there is nothing to
+# bring together: the join program reads the callers' buffers under their own counts.
+class TestCoPartitioning:
+    @pytest.mark.parametrize("mode", ["range", "hash"])
+    @pytest.mark.parametrize("devices", [1, 4])
+    def test_what_a_call_dispatches(self, devices, mode):
+        """One device: two fetches (the duplicate flag, the counts), no bucket move, no election
+        or partition program for that mesh, no row shuffle counted. A mesh: both sides shuffled,
+        one bucket move an operand a side, the partition's fetch a side on top."""
+        from heat_tpu.analysis.sanitizer import COMPILE_STATS
+        from heat_tpu.frame import SHUFFLE_STATS, _shuffle
+
+        comm = _mesh_of(devices)
+        x, y = _MERGE_CASES["all_matched"](np.random.default_rng([32, devices]))
+        left, right = _frame_on(x, comm), _frame_on(y, comm)
+        counted = lambda: (MOVE_STATS["bucket_moves"], COMPILE_STATS["host_syncs"], SHUFFLE_STATS["row_shuffles"], SHUFFLE_STATS["joins"])
+        left.join(right, on="k", mode=mode)  # cold
+        before = counted()
+        left.join(right, on="k", mode=mode)
+        moves, syncs, shuffles, joins = (a - b for a, b in zip(counted(), before))
+        # every program this file's tests have built for such a mesh, by kind
+        built = {key[0] for key in _shuffle._PROGRAMS if key[-1] == comm.mesh}
+        assert joins == 1 and "join" in built
+        if devices == 1:
+            assert (moves, syncs, shuffles) == (0, 2, 0)
+            assert not built & {"elect", "part"}
+        else:
+            assert (moves, syncs, shuffles) == (len(x) + len(y), 4, 2)
+            assert built >= ({"part", "elect"} if mode == "range" else {"part"})
+
+    @pytest.mark.parametrize("mode", ["range", "hash"])
+    def test_the_partition_program_sorts_once_and_moves_nothing_through_an_index(self, mode):
+        """Lowered for four devices, not compiled: the one sort is the stable partition by
+        destination (the parent sorted by key first), and no gather or scatter stands in it."""
+        import re
+
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from heat_tpu.core.communication import SPLIT_AXIS
+        from heat_tpu.frame import _shuffle
+
+        comm = _mesh_of(4)
+        rows, rep = NamedSharding(comm.mesh, PartitionSpec(SPLIT_AXIS)), NamedSharding(comm.mesh, PartitionSpec())
+        payloads = ("int32", "float32")
+        fn = _shuffle._partition_executable((4 * 64,), jnp.dtype("int32"), payloads, 4, mode, comm)
+        text = fn.lower(
+            jax.ShapeDtypeStruct((4 * 64,), jnp.int32, sharding=rows),
+            jax.ShapeDtypeStruct((4,), jnp.int32, sharding=rep),
+            jax.ShapeDtypeStruct((3,), jnp.int32, sharding=rep),
+            *[jax.ShapeDtypeStruct((4 * 64,), jnp.dtype(d), sharding=rows) for d in payloads],
+        ).as_text()
+        assert len(re.findall(r"stablehlo\.sort", text)) == 1, text
+        assert re.findall(r"stablehlo\.(?:dynamic_)?gather|stablehlo\.scatter", text) == []
+
+    @pytest.mark.parametrize("how", ["inner", "left"])
+    def test_both_sides_ragged_on_one_device(self, how):
+        """Two filters' results on one device: the counts go with the buffers, and the rows each
+        filter dropped stay behind the kept ones as the pads' content, keys that would match."""
+        from heat_tpu.frame._shuffle import shard_counts
+        from heat_tpu.frame.reference import join_m1
+
+        comm = _mesh_of(1)
+        rng = np.random.default_rng([33, how == "left"])
+        x, y = _merge_tables(rng, rng.integers(0, 60, _MERGE_ROWS), rng.permutation(60)[:_MERGE_RIGHT])
+        keep = {"left": rng.random(_MERGE_ROWS) < 0.7, "right": rng.random(_MERGE_RIGHT) < 0.7}
+        left = _frame_on(x, comm).filter(ht.array(keep["left"], split=0, comm=comm))
+        right = _frame_on(y, comm).filter(ht.array(keep["right"], split=0, comm=comm))
+        assert shard_counts(left["k"]) == (int(keep["left"].sum()),) and left["k"]._raw.shape == (_MERGE_ROWS,)
+        assert shard_counts(right["k"]) == (int(keep["right"].sum()),) and right["k"]._raw.shape == (_MERGE_RIGHT,)
+        xk, yk = ({c: a[keep[s]] for c, a in t.items()} for s, t in (("left", x), ("right", y)))
+        dropped = y["k"][~keep["right"]]
+        assert np.isin(xk["k"], dropped).any() and np.isin(xk["k"], yk["k"]).any()
+        buffers = lambda: [f[c]._raw for f in (left, right) for c in f.columns]
+        held = buffers()
+        want = join_m1(xk, yk, on="k", how=how)
+        for _ in range(2):
+            _assert_columns_equal(left.join(right, on="k", how=how).to_dict(), want)
+        # the join read the filters' own buffers and left them alone (reading a ragged column
+        # out rebalances it, so the buffers are looked at first)
+        assert all(b is now and not b.is_deleted() for b, now in zip(held, buffers()))
+        for f, t in ((left, xk), (right, yk)):
+            _assert_columns_equal(f.to_dict(), t)
