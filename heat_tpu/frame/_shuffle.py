@@ -97,8 +97,11 @@ _PROGRAMS = ExecutableCache(maxsize=128)
 # running counters: tests and bench read these alongside MOVE_STATS to
 # assert the engine's exchange budget and cache behavior; "row_shuffles"
 # counts the sides a join really partitioned and exchanged (2 a join on
-# a mesh, 0 on one device)
-SHUFFLE_STATS = {"groupbys": 0, "joins": 0, "compactions": 0, "row_shuffles": 0}
+# a mesh, 0 on one device); "bucket_skew" is a gauge, not a count: the
+# fullest destination's rows over the mean of the last row shuffle (1.0 =
+# even; what the election achieved: every receive block is that long,
+# rounded up by ``_receive_rows``)
+SHUFFLE_STATS = {"groupbys": 0, "joins": 0, "compactions": 0, "row_shuffles": 0, "bucket_skew": 1.0}
 
 # how each statistic kind folds in the merge stage (all associative)
 STAT_COMBINE = {"sum": "sum", "sumsq": "sum", "count": "sum", "min": "min", "max": "max"}
@@ -207,13 +210,19 @@ def _range_pid(keys, splitters):
 
 def _sample_ranks(n, b: int):
     """The ranks at which a shard's ``n`` sorted keys are sampled for the
-    election, clipped into its block of ``b`` rows."""
-    return jnp.clip((lax.iota(jnp.int32, _OVERSAMPLE) * n) // jnp.maximum(n, 1), 0, b - 1)
+    election: ``(i * n) // _OVERSAMPLE`` for each sample ``i``, evenly
+    spaced from the smallest key up, clipped into its block of ``b``
+    rows. The product is never formed: at 31 samples times 7e7 rows it
+    leaves int32. A shard of fewer keys than samples repeats some; only
+    an empty one has none to give (rank 0 is not below ``n``)."""
+    i = lax.iota(jnp.int32, _OVERSAMPLE)
+    ranks = i * (n // _OVERSAMPLE) + (i * (n % _OVERSAMPLE)) // _OVERSAMPLE
+    return jnp.clip(ranks, 0, b - 1)
 
 
 def _splitters(samples, p: int):
-    """Range splitters from every shard's key samples (a shard short of
-    keys fills up with the max key, so an empty shard does not skew the
+    """Range splitters from every shard's key samples (a shard with no
+    keys gives the max key 32 times, so an empty shard does not skew the
     splitters downward): one all_gather, sort, take the P-1 quantiles.
     Replicated by construction — every device computes the same values."""
     gs = jnp.sort(lax.all_gather(samples, SPLIT_AXIS, tiled=True))
@@ -574,16 +583,25 @@ def _counts_vec(counts: Sequence[int]) -> jnp.ndarray:
     return jnp.asarray(tuple(int(c) for c in counts), jnp.int32)
 
 
+def _receive_rows(fullest: int) -> int:
+    """Rows of a row shuffle's receive block: the fullest bucket's, rounded
+    up to the next of 64 lengths an octave (a multiple of the 128th of
+    the power of two above it: at most a 64th more rows). The merge join
+    is compiled for this length, and the fullest bucket moves with the
+    keys by a few rows in ten thousand: so a table about as long as the
+    last one finds its program compiled, whatever its keys."""
+    step = 1 << max(fullest.bit_length() - 7, 0)
+    return max(1, -(-fullest // step) * step)
+
+
 def _exchange_operands(
-    bufs: List[jax.Array], mat: np.ndarray, comm: MeshCommunication
-) -> Tuple[List[jax.Array], np.ndarray, int]:
-    """ONE bucket exchange per operand column over a shared schedule."""
-    out_counts = mat.sum(axis=0)
-    b_out = max(1, int(out_counts.max()))
-    moved = [
+    bufs: List[jax.Array], mat: np.ndarray, b_out: int, comm: MeshCommunication
+) -> List[jax.Array]:
+    """ONE bucket exchange per operand column over a shared schedule,
+    into receive blocks of ``b_out`` rows."""
+    return [
         collective_lockstep(bucket_move(b, 0, mat.tolist(), b_out, comm)) for b in bufs
     ]
-    return moved, out_counts, b_out
 
 
 def groupby_reduce(
@@ -616,7 +634,9 @@ def groupby_reduce(
     # the replicated bucket matrix comes to host to build the static
     # exchange schedule — same bounded sync as redistribute_'s target map
     mat_np = _hooks.fetch(mat, "groupby.bucket_matrix")
-    moved, out_counts, b_out = _exchange_operands([pk, *parts], mat_np, comm)
+    out_counts = mat_np.sum(axis=0)
+    b_out = max(1, int(out_counts.max()))
+    moved = _exchange_operands([pk, *parts], mat_np, b_out, comm)
     merge = _merge_executable(
         (p * b_out,),
         kb.dtype,
@@ -668,8 +688,12 @@ def shuffle_rows(
     )
     out = collective_lockstep(part(kb, counts, splitters, *payload_bufs))
     mat_np = _hooks.fetch(out[-1], "shuffle.bucket_matrix")
-    moved, out_counts, b_out = _exchange_operands(list(out[:-1]), mat_np, comm)
+    out_counts = mat_np.sum(axis=0)
+    fullest, rows = int(out_counts.max()), int(out_counts.sum())
+    b_out = _receive_rows(fullest)
+    moved = _exchange_operands(list(out[:-1]), mat_np, b_out, comm)
     SHUFFLE_STATS["row_shuffles"] += 1
+    SHUFFLE_STATS["bucket_skew"] = fullest * p / rows if rows else 1.0
     return moved, out_counts, b_out
 
 

@@ -182,7 +182,19 @@ class Frame:
         :func:`heat_tpu.frame.reference.join_m1` returns. ``"hash"`` only
         co-locates equal keys: each shard is ordered, the shards are
         not (on one device ``mode`` has no effect). Neither input is
-        changed or consumed."""
+        changed or consumed.
+
+        Memory, a device: beside the two frames' own blocks a call on a
+        mesh holds a moved copy of every column of both sides, each in
+        blocks as long as the fullest destination (the mean times
+        ``SHUFFLE_STATS["bucket_skew"]``, 1.0 on uniform keys; rounded
+        up by at most a 64th, so that the merge is not compiled anew
+        for every table's keys), and the
+        result, whose block is as long as BOTH sides' blocks together
+        whatever matches, for each of its columns; the sorts'
+        temporaries come on top (docs/FRAME.md has the sum). Reading a
+        result column through ``.larray`` rebalances it: one more copy
+        of that column."""
         if on not in self._cols or on not in other._cols:
             raise KeyError(f"join key {on!r} must exist in both frames")
         lk, rk = self._cols[on], other._cols[on]

@@ -7,7 +7,10 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
+import heat_tpu as ht
 from heat_tpu.frame import Frame
+
+from . import _mh_helpers as mh
 
 ROWS = 211
 
@@ -40,6 +43,15 @@ def _release_executables():
 @pytest.fixture(scope="module")
 def rng():
     return np.random.default_rng(7)
+
+
+def _mesh_of(devices: int):
+    """A communicator over ``devices`` devices, spanning every process; skips where there is none."""
+    import jax
+
+    if devices > len(jax.devices()) or (jax.process_count() > 1 and devices % jax.process_count()):
+        pytest.skip(f"no mesh of {devices} devices here")
+    return ht.MeshCommunication(devices=mh.submesh(devices))
 
 
 def _sorted_dict(frame: Frame, key: str):

@@ -33,29 +33,38 @@ from ._chip_helpers import _frame_mesh, _indexed_ops, _one_chip, _spec, four_chi
 #     pytest -m slow tests/test_chip_compile_join.py
 # (sandbox compiles at question 2's widths, PR 28: temporaries 3.85 columns over the four chips
 # and 3.84 over one at this size, where the compiler keeps some columns in another memory space,
-# everything held 21.86; 7.27 columns of temporaries at 1e8 rows on one chip, PERF.md §5). Over
-# four chips those widths come back with a four-chip join cell (ROADMAP.md, Queue 2).
+# everything held 21.86; 7.27 columns of temporaries at 1e8 rows on one chip, PERF.md §5).
+#
+# ``question_5`` (PR 31) is the four-chip cell's own program (PERF.md §4, `join-q5-big-inner-4chip`:
+# h2o.ai's ``big inner on int``), over four chips only, where the cell runs: the right table as
+# long as the left, five int32 and one f32 payload a side, sorts of 15 operands, the result's block
+# twice the left one's. Marked ``slow`` as question 2's is, and for the same reason, and so is
+# the partition program that feeds it (one stable sort of 9 operands by destination), a test of
+# its own so that neither compile runs into the bound conftest gives a test.
 _ROWS = 1 << 20
-_RIGHT_ROWS = _ROWS // 1024
 _COLUMN = 4 * _ROWS  # bytes: every column here is 32 bits wide
 
-# widths -> (left payloads, right payloads, most temporaries over four chips, most held on one chip),
-# the last two in columns, each pinned over the sandbox's compile with the margin question 2's bound
-# has over its own: one payload 2.75 and 8.13 (2 + 3 + 3.12 of temporaries; PR 29), question 2
-# 3.85 and 21.86 (PR 28)
+# widths -> (left payloads, right payloads, left rows to a right row, most temporaries over four
+# chips, most held on one chip), the last two in columns of the left table, each pinned over the
+# sandbox's compile with the margin question 2's bound has over its own: one payload 2.75 and 8.13
+# (2 + 3 + 3.12 of temporaries; PR 29), question 2 3.85 and 21.86 (PR 28), question 5 8.68 over
+# four chips (PR 31: 456 s alone on the sandbox; at the cell's 2.5e7 rows a chip 15.07, of blocks
+# of 0.1 GB, beside 14 of arguments and 26 of outputs; no one chip holds it)
+_Q5_PAYLOADS = ("int32",) * 5 + ("float32",)
 _WIDTHS = {
-    "one_payload": (("int32",), ("float32",), 3.2, 8.4),
-    "question_2": (("int32",) * 5 + ("float32",), ("int32",) * 3 + ("float32",), 4.5, 22.5),
+    "one_payload": (("int32",), ("float32",), 1024, 3.2, 8.4),
+    "question_2": (("int32",) * 5 + ("float32",), ("int32",) * 3 + ("float32",), 1024, 4.5, 22.5),
+    "question_5": (_Q5_PAYLOADS, _Q5_PAYLOADS, 1, 10.0, None),
 }
 
 
-def _compiled_join(mesh, p: int, left, right):
+def _compiled_join(mesh, p: int, left, right, ratio: int):
     import jax.numpy as jnp
 
     from heat_tpu.frame import _shuffle
 
     comm, rows, rep = _frame_mesh(mesh)
-    lshape, rshape = (p * _ROWS,), (p * _RIGHT_ROWS,)
+    lshape, rshape = (p * _ROWS,), (p * _ROWS // ratio,)
     fn = _shuffle._join_executable(lshape, rshape, jnp.dtype("int32"), left, right, "inner", p, comm)
     return fn.lower(
         _spec(lshape, jnp.int32, rows), _spec((p,), jnp.int32, rep), *[_spec(lshape, jnp.dtype(d), rows) for d in left],
@@ -63,25 +72,52 @@ def _compiled_join(mesh, p: int, left, right):
     ).compile()
 
 
-def _join_matches_without_an_index(text: str):
-    for block in (_ROWS + _RIGHT_ROWS, _ROWS, _RIGHT_ROWS):
+def _join_matches_without_an_index(text: str, ratio: int):
+    for block in (_ROWS + _ROWS // ratio, _ROWS, _ROWS // ratio):
         assert _indexed_ops(text, block) == [], block
     assert text.count(" sort(") == 2  # both sides together by key; the compaction
 
 
-@pytest.mark.parametrize("widths", ["one_payload"])
-def test_join_program_compiles_over_four_chips(four_chips, widths):
-    left, right, most_temp, _ = _WIDTHS[widths]
-    compiled = _compiled_join(four_chips, 4, left, right)
+@pytest.mark.parametrize("widths", [pytest.param("question_5", marks=pytest.mark.slow)])
+def test_partition_program_sorts_once_over_four_chips(four_chips, widths):
+    """``frame_partition`` compiled over the four chips, range mode: every row's destination from
+    the block as it stands, ONE stable sort by it carrying the key and the payloads, the bucket
+    matrix gathered; nothing block-long goes through an index, and the program holds its
+    arguments, its outputs and the sort's two columns of temporaries."""
+    import jax.numpy as jnp
+
+    from heat_tpu.frame import _shuffle
+
+    payloads = _WIDTHS[widths][0]
+    comm, rows, rep = _frame_mesh(four_chips)
+    shape = (4 * _ROWS,)
+    fn = _shuffle._partition_executable(shape, jnp.dtype("int32"), payloads, 4, "range", comm)
+    compiled = fn.lower(
+        _spec(shape, jnp.int32, rows), _spec((4,), jnp.int32, rep), _spec((3,), jnp.int32, rep),
+        *[_spec(shape, jnp.dtype(d), rows) for d in payloads],
+    ).compile()
     text = compiled.as_text()
-    _join_matches_without_an_index(text)
+    assert text.count(" sort(") == 1
+    assert _indexed_ops(text, _ROWS) == []
+    assert "all-gather" in text or "all-reduce" in text  # the bucket matrix, a few words
+    mem = compiled.memory_analysis()
+    # 2.22 here (PR 31: 177-186 s alone on the sandbox), 2.00 at the cell's 2.5e7 rows a chip
+    assert mem.temp_size_in_bytes < 2.6 * _COLUMN, mem.temp_size_in_bytes / _COLUMN
+
+
+@pytest.mark.parametrize("widths", ["one_payload", pytest.param("question_5", marks=pytest.mark.slow)])
+def test_join_program_compiles_over_four_chips(four_chips, widths):
+    left, right, ratio, most_temp, _ = _WIDTHS[widths]
+    compiled = _compiled_join(four_chips, 4, left, right, ratio)
+    text = compiled.as_text()
+    _join_matches_without_an_index(text, ratio)
     assert "all-gather" in text or "all-reduce" in text  # the row counts and the duplicate flag, a few words
     mem = compiled.memory_analysis()
-    # a chip's arguments are its quarter: the left table's columns, the right one's a thousandth as long
-    arguments = (1 + len(left)) + (1 + len(right)) / 1024
+    # a chip's arguments are its quarter: the left table's columns, the right one's as much shorter as the table
+    arguments = (1 + len(left)) + (1 + len(right)) / ratio
     assert mem.argument_size_in_bytes < arguments * _COLUMN + (1 << 20), mem.argument_size_in_bytes / _COLUMN
     # the key and every payload, of both blocks' rows
-    outputs = (1 + len(left) + len(right)) * (1 + 1 / 1024)
+    outputs = (1 + len(left) + len(right)) * (1 + 1 / ratio)
     assert mem.output_size_in_bytes < outputs * _COLUMN + (1 << 20), mem.output_size_in_bytes / _COLUMN
     assert mem.temp_size_in_bytes < most_temp * _COLUMN, mem.temp_size_in_bytes / _COLUMN
 
@@ -91,9 +127,9 @@ def test_join_program_fits_one_chip(topo, widths):
     """The cell's layout. Everything the program holds at once, in columns of the left table: its
     arguments, its outputs and its temporaries (7 + 11 + 3.84 at question 2's widths; at 1e8 rows a
     column is 0.4 GB and the two tables stand beside the program)."""
-    left, right, _, most_held = _WIDTHS[widths]
-    compiled = _compiled_join(_one_chip(topo), 1, left, right)
-    _join_matches_without_an_index(compiled.as_text())
+    left, right, ratio, _, most_held = _WIDTHS[widths]
+    compiled = _compiled_join(_one_chip(topo), 1, left, right, ratio)
+    _join_matches_without_an_index(compiled.as_text(), ratio)
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes - mem.alias_size_in_bytes
     assert held < most_held * _COLUMN, held / _COLUMN

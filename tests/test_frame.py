@@ -24,7 +24,7 @@ from heat_tpu.parallel.flatmove import MOVE_STATS
 from heat_tpu.stream import StreamingGroupBy
 
 from . import _mh_helpers as mh
-from ._frame_helpers import ROWS, _release_executables, _sorted_dict, rng  # noqa: F401 - fixtures
+from ._frame_helpers import ROWS, _mesh_of, _release_executables, _sorted_dict, rng  # noqa: F401 - fixtures
 
 
 def _oracle(keys: np.ndarray, vals: np.ndarray, agg: str, ddof: int = 1):
@@ -494,37 +494,53 @@ class TestGroupbyReduceLayouts:
 
 
 def _numpy_election(blocks, mk, p):
-    """The election as the parent commit ran it, in NumPy: each shard's sorted
-    keys sampled at ``_sample_ranks``, short shards filled with the max key,
-    the P-1 quantiles of all samples."""
+    """The election in NumPy: each shard's sorted keys sampled at the ranks
+    ``(i * n) // 32`` (int64 here, the product formed outright: what the
+    program's ``_sample_ranks`` must equal without forming it), an empty
+    shard's samples the max key, the P-1 quantiles of all samples. Up to
+    PR 30 the ranks were ``(i * n) // n``, each shard's 32 smallest keys."""
     from heat_tpu.frame._shuffle import _OVERSAMPLE
 
     samples = []
     for blk, size in blocks:
         n = blk.size
-        idx = np.clip((np.arange(_OVERSAMPLE) * n) // max(n, 1), 0, size - 1)
+        idx = np.clip((np.arange(_OVERSAMPLE, dtype=np.int64) * n) // _OVERSAMPLE, 0, size - 1)
         samples.append(np.where(idx < n, blk[np.minimum(idx, max(n - 1, 0))] if n else mk, mk))
     gs = np.sort(np.concatenate(samples))
     return gs[(np.arange(1, p) * gs.size) // p]
 
 
+def _even_quantiles(shards, p):
+    """What the election is for, with none of its arithmetic: every shard's sorted keys at
+    32 evenly spaced ranks (``np.linspace`` over the rank range, floored), all of them
+    sorted together, the P-1 values that cut that list into P equal parts."""
+    picks = [np.sort(s)[np.floor(np.linspace(0, s.size, 32, endpoint=False)).astype(np.int64)] for s in shards]
+    return np.array_split(np.sort(np.concatenate(picks)), p)
+
+
 class TestRangeElection:
-    """The election must not move with the rewrite: the plan samples among a
-    shard's distinct keys, ``shuffle_rows``' election among its rows."""
+    """What the election elects: the plan samples among a shard's distinct
+    keys, ``shuffle_rows``' election among its rows, both at 32 evenly
+    spaced ranks (PR 31; each shard's 32 smallest keys before, which sent
+    192 of a shard's 223 partials to the last of eight destinations)."""
 
     ROWS = 2000
 
-    def _data(self):
+    @staticmethod
+    def _one_process():
         import jax
 
         if jax.process_count() > 1:
             pytest.skip("reads the programs' raw outputs, which one process does not hold whole")
+
+    def _data(self):
+        self._one_process()
         rng = np.random.default_rng(25)
         keys = rng.integers(-500, 500, size=self.ROWS).astype(np.int32)
         vals = rng.integers(0, 9, size=self.ROWS).astype(np.int32)
         return keys, vals
 
-    def test_plan_buckets_match_the_parents_election(self):
+    def test_plan_buckets_match_the_numpy_election(self):
         from heat_tpu.frame import _shuffle
 
         keys, vals = self._data()
@@ -542,10 +558,15 @@ class TestRangeElection:
         np.testing.assert_array_equal(uvec, [u.size for u in uniq])
         want = np.stack([np.bincount(np.searchsorted(splitters, u, side="right"), minlength=p) for u in uniq])
         np.testing.assert_array_equal(mat, want)
-        if p == 8:  # what the parent commit (b98c5b1) returned for these rows
+        if p == 8:  # these rows' distinct keys a shard (no election in that), then the buckets
             np.testing.assert_array_equal(uvec, [223, 224, 217, 215, 219, 230, 224, 218])
-            np.testing.assert_array_equal(mat[0], [6, 9, 1, 4, 5, 3, 3, 192])
-            np.testing.assert_array_equal(mat[:, -1], [192, 193, 186, 185, 193, 199, 202, 197])
+            # derived here and not read off a run: the first key of each of the P equal parts
+            # of all the samples is a splitter, and a shard's keys fall between them
+            cuts = [part[0] for part in _even_quantiles(uniq, p)[1:]]
+            np.testing.assert_array_equal(splitters, cuts)
+            np.testing.assert_array_equal(mat[0], np.histogram(uniq[0], [-np.inf, *cuts, np.inf])[0])
+            np.testing.assert_array_equal(mat[:, -1], [(u >= cuts[-1]).sum() for u in uniq])
+            assert mat.sum(axis=0).max() <= 1.25 * mat.sum() / p, mat.sum(axis=0)  # 1 547 of 1 770 went last before
         # the partials leave destination-major, in key order, each key's total exact
         pk, ps = np.asarray(out[0]).reshape(p, b), np.asarray(out[1]).reshape(p, b)
         for r in range(p):
@@ -554,7 +575,7 @@ class TestRangeElection:
             shard_k, shard_v = keys[offs[r] : offs[r + 1]], vals[offs[r] : offs[r + 1]]
             np.testing.assert_array_equal(got_s, [shard_v[shard_k == u].sum() for u in uniq[r]])
 
-    def test_row_election_matches_the_parents(self):
+    def test_row_election_matches_the_numpy_election(self):
         from heat_tpu.frame import _shuffle
 
         keys, _ = self._data()
@@ -566,5 +587,61 @@ class TestRangeElection:
         b, offs = k._raw.shape[0] // p, np.cumsum((0, *counts))
         rows = [(np.sort(keys[offs[r] : offs[r + 1]]), b) for r in range(p)]
         np.testing.assert_array_equal(got, _numpy_election(rows, np.iinfo(np.int32).max, p))
-        if p == 8:  # what the parent commit (b98c5b1) returned for these rows
-            np.testing.assert_array_equal(got, [-485, -471, -455, -437, -420, -402, -385])
+        if p == 8:  # derived here: the seven values that cut all the samples into eight equal parts
+            np.testing.assert_array_equal(got, [part[0] for part in _even_quantiles([r for r, _ in rows], p)[1:]])
+            assert np.all(np.abs(got - np.quantile(keys, np.arange(1, p) / p)) < 40), got  # -485..-385 before
+
+    # --- what the election achieves (PR 31): uniform keys spread evenly over the destinations,
+    # and the rank arithmetic holds at a shard longer than int32 lets 31 * n be.
+    @pytest.mark.parametrize("devices", [4, 8])
+    def test_the_row_election_spreads_uniform_keys_evenly(self, devices):
+        """``shuffle_rows`` on 2**16 uniform keys: no destination gets more than 1.25 times the
+        mean, and ``SHUFFLE_STATS["bucket_skew"]`` says what the fullest one got."""
+        from heat_tpu.frame import _shuffle
+
+        self._one_process()
+        comm = _mesh_of(devices)
+        n = 1 << 16
+        keys = np.random.default_rng([31, devices]).integers(1, n + n // 10, size=n).astype(np.int32)
+        k = ht.array(keys, split=0, comm=comm)
+        moved, out_counts, b_out = _shuffle.shuffle_rows(k, [], "range")
+        assert out_counts.sum() == n and b_out == _shuffle._receive_rows(int(out_counts.max()))
+        assert out_counts.max() <= 1.25 * n / devices, out_counts
+        assert SHUFFLE_STATS["bucket_skew"] == out_counts.max() * devices / n
+        # destinations hold ascending key ranges, in rank order
+        got = np.asarray(moved[0]).reshape(devices, b_out)
+        tops = [got[d, : out_counts[d]].max() for d in range(devices)]
+        assert all(got[d + 1, : out_counts[d + 1]].min() > tops[d] for d in range(devices - 1))
+
+    @pytest.mark.parametrize("devices", [4, 8])
+    def test_the_plans_election_spreads_uniform_distinct_keys_evenly(self, devices):
+        """The groupby's plan elects among each shard's distinct keys: 2**16 rows over 2**14 keys."""
+        from heat_tpu.frame import _shuffle
+
+        self._one_process()
+        comm = _mesh_of(devices)
+        n = 1 << 16
+        rng = np.random.default_rng([32, devices])
+        k = ht.array(rng.integers(0, 1 << 14, size=n).astype(np.int32), split=0, comm=comm)
+        v = ht.array(rng.integers(0, 9, size=n).astype(np.int32), split=0, comm=comm)
+        plan = _shuffle._plan_executable(
+            tuple(k._raw.shape), k._raw.dtype, ("int32",), (("sum", 0, "int32"),), devices, "range", comm
+        )
+        out = plan(k._raw, _shuffle._counts_vec(_shuffle.shard_counts(k)), v._raw)
+        mat = np.asarray(out[-2])
+        assert mat.sum() == np.asarray(out[-1]).sum() > n // 2  # every shard's distinct keys, most of its rows
+        assert mat.sum(axis=0).max() <= 1.25 * mat.sum() / devices, mat.sum(axis=0)
+
+    @pytest.mark.parametrize("n", [0, 1, 31, 33, 2_000, 69_273_667, 100_000_000, 2**31 - 1])
+    def test_the_sample_ranks_are_exact_at_any_declared_length(self, n):
+        """``(i * n) // 32`` without the product: 31 * n leaves int32 past 6.9e7 rows a shard, and a
+        cell holds 1e8 on one chip. Only the scalar is made, never such an array."""
+        import jax.numpy as jnp
+
+        from heat_tpu.frame._shuffle import _OVERSAMPLE, _sample_ranks
+
+        got = np.asarray(_sample_ranks(jnp.int32(n), max(n, 1)))
+        want = (np.arange(_OVERSAMPLE, dtype=np.int64) * n) // _OVERSAMPLE
+        assert got.dtype == np.int32
+        np.testing.assert_array_equal(got, np.clip(want, 0, max(n, 1) - 1))
+        assert got[-1] < max(n, 1) and np.all(np.diff(got.astype(np.int64)) >= 0)  # no wraparound

@@ -15,8 +15,7 @@ from heat_tpu.analysis.sanitizer import sanitizer
 from heat_tpu.frame import Frame
 from heat_tpu.parallel.flatmove import MOVE_STATS
 
-from . import _mh_helpers as mh
-from ._frame_helpers import ROWS, _release_executables, _sorted_dict, rng  # noqa: F401 - fixtures
+from ._frame_helpers import ROWS, _mesh_of, _release_executables, _sorted_dict, rng  # noqa: F401 - fixtures
 
 
 class TestJoin:
@@ -92,13 +91,15 @@ class TestJoin:
 _J_ROWS, _J_MEDIUM = 20_000, 20
 
 
-def _h2o_join_tables(rng):
-    def pool(n):
-        values = (rng.permutation(n + n // 10) + 1).astype(np.int32)
-        return values[:n], np.concatenate([values[: n - n // 10], values[n:]])
+def _h2o_pool(rng, n):
+    """The source's ``split_xlr(n)``: 1..1.1n shuffled, as (values of x, values of the right table)."""
+    values = (rng.permutation(n + n // 10) + 1).astype(np.int32)
+    return values[:n], np.concatenate([values[: n - n // 10], values[n:]])
 
-    x1, m1 = pool(10)
-    x2, m2 = pool(_J_MEDIUM)
+
+def _h2o_join_tables(rng):
+    x1, m1 = _h2o_pool(rng, 10)
+    x2, m2 = _h2o_pool(rng, _J_MEDIUM)
     x = {"id1": rng.choice(x1, _J_ROWS), "id2": rng.choice(x2, _J_ROWS),
          "id3": rng.integers(1, _J_ROWS + 1, _J_ROWS).astype(np.int32)}
     x.update(id4=x["id1"].copy(), id5=x["id2"].copy(), id6=x["id3"].copy(),
@@ -109,14 +110,6 @@ def _h2o_join_tables(rng):
     return x, medium
 
 _MERGE_ROWS, _MERGE_RIGHT = 300, 48  # one size a side: one set of programs a mesh, a ``how`` and a key type
-
-
-def _mesh_of(devices: int):
-    import jax
-
-    if devices > len(jax.devices()) or (jax.process_count() > 1 and devices % jax.process_count()):
-        pytest.skip(f"no mesh of {devices} devices here")
-    return ht.MeshCommunication(devices=mh.submesh(devices))
 
 
 def _frame_on(table, comm) -> Frame:
@@ -437,3 +430,96 @@ class TestCoPartitioning:
         assert all(b is now and not b.is_deleted() for b, now in zip(held, buffers()))
         for f, t in ((left, xk), (right, yk)):
             _assert_columns_equal(f.to_dict(), t)
+
+
+# --- both sides long (PR 31): h2o.ai db-benchmark's join question 5, ``big inner on int``
+# (x join big on id3), cut to 6 000 rows a side: seven columns each, six names in both, ``big``'s
+# id3 unique. id3's values come from a shuffled pool of 1.1 n as the source draws them: the first
+# 0.9 n on both sides, the next 0.1 n in x only, the last 0.1 n in ``big`` only; x draws its id3
+# with replacement from its n values, ``big`` holds each of its own once. On a mesh the whole-row
+# shuffle moves both tables and a run of equal keys is a right row and a left row or two.
+_BIG_ROWS = 6_000
+_BIG_OUT = ("id3", "id1", "id2", "id4", "id5", "id6", "v1", "id1_r", "id2_r", "id4_r", "id5_r", "id6_r", "v2")
+
+
+def _h2o_big_tables(rng, n: int = _BIG_ROWS):
+    (x1, b1), (x2, b2), (x3, b3) = (_h2o_pool(rng, m) for m in (10, n // 1000, n))
+    x = {"id1": rng.choice(x1, n), "id2": rng.choice(x2, n), "id3": rng.choice(x3, n)}
+    x.update(id4=x["id1"].copy(), id5=x["id2"].copy(), id6=x["id3"].copy(), v1=rng.uniform(0, 100, n).astype(np.float32))
+    big = {"id1": rng.choice(b1, n), "id2": rng.choice(b2, n), "id3": rng.permutation(b3)}
+    big.update(id4=big["id1"].copy(), id5=big["id2"].copy(), id6=big["id3"].copy(), v2=rng.uniform(0, 100, n).astype(np.float32))
+    return x, big
+
+
+class TestBothSidesLong:
+    @pytest.mark.parametrize("mode", ["range", "hash"])
+    @pytest.mark.parametrize("devices", [4, 8])
+    @pytest.mark.parametrize("how", ["inner", "left"])
+    def test_every_column_equals_the_references(self, how, devices, mode):
+        from heat_tpu.frame import SHUFFLE_STATS
+        from heat_tpu.frame.reference import join_m1
+
+        comm = _mesh_of(devices)
+        x, big = _h2o_big_tables(np.random.default_rng([31, devices]))
+        matched = np.isin(x["id3"], big["id3"]).mean()
+        assert 0.88 < matched < 0.92 and np.unique(big["id3"]).size == _BIG_ROWS
+        want = join_m1(x, big, on="id3", how=how)
+        out = _frame_on(x, comm).join(_frame_on(big, comm), on="id3", how=how, mode=mode)
+        got = out.to_dict()
+        assert out.columns == tuple(want) == _BIG_OUT
+        if mode == "hash":
+            # each shard in the promised order, the shards not: their rows as multisets are the
+            # reference's, and one stable sort by key of the shards end to end is its order
+            order = np.argsort(got["id3"], kind="stable")
+            got = {c: a[order] for c, a in got.items()}
+        else:  # the range election left no destination more than a quarter over the mean
+            assert SHUFFLE_STATS["bucket_skew"] <= 1.25
+        _assert_columns_equal(got, want)  # range: row for row in the reference's order over the whole mesh
+
+    @pytest.mark.parametrize("devices", [4, 8])
+    @pytest.mark.parametrize("side", ["left", "right"])
+    def test_an_empty_shard_on_either_long_side(self, side, devices):
+        """The second shard of one side holds no row, as a filter leaves it; what it dropped stays
+        behind as the pads' content, keys that would match."""
+        from heat_tpu.frame._shuffle import shard_counts
+        from heat_tpu.frame.reference import join_m1
+
+        comm = _mesh_of(devices)
+        tables = dict(zip(("left", "right"), _h2o_big_tables(np.random.default_rng([32, devices]))))
+        block = -(-_BIG_ROWS // devices)
+        keep = {name: np.ones(_BIG_ROWS, bool) for name in tables}
+        keep[side][block : 2 * block] = False
+        frames = {name: _frame_on(t, comm).filter(ht.array(keep[name], split=0, comm=comm)) for name, t in tables.items()}
+        assert shard_counts(frames[side]["id3"])[1] == 0
+        want = join_m1(*({c: a[keep[name]] for c, a in tables[name].items()} for name in ("left", "right")), on="id3")
+        assert 0 < want["id3"].size < _BIG_ROWS
+        _assert_columns_equal(frames["left"].join(frames["right"], on="id3").to_dict(), want)
+
+    # --- a receive block's length does not follow the keys (PR 31): every run of the question
+    # at 1e8 rows compiled ``frame_join`` anew, because the fullest bucket sized the moved blocks.
+    @pytest.mark.parametrize("fullest, rows", [
+        (0, 1), (1, 1), (127, 127), (128, 128), (129, 130), (1_001, 1_008), (2**20, 2**20), (2**20 + 1, 2**20 + 2**14),
+        (25_005_753, 25_165_824), (25_017_842, 25_165_824),  # both ends of what six seeds of the question elect
+    ])
+    def test_a_receive_block_is_one_of_64_lengths_an_octave(self, fullest, rows):
+        from heat_tpu.frame._shuffle import _receive_rows
+
+        assert _receive_rows(fullest) == rows
+        assert max(fullest, 1) <= rows <= max(fullest + fullest // 64, 1)
+        top = 1 << max(fullest, 1).bit_length()  # the octave above: 64 lengths in it, and its end
+        assert len({_receive_rows(n) for n in range(top, 2 * top, max(top // 512, 1))}) <= 65
+
+    def test_another_table_as_long_finds_the_join_compiled(self):
+        """Two pairs of tables drawn apart: other keys, another fullest bucket, one ``frame_join``."""
+        from heat_tpu.frame import SHUFFLE_STATS, _shuffle
+        from heat_tpu.frame.reference import join_m1
+
+        comm = _mesh_of(4)
+        joins = lambda: [key[1:3] for key in _shuffle._PROGRAMS if key[0] == "join"]
+        fullest = []
+        for draw in (1, 5):
+            x, big = _h2o_big_tables(np.random.default_rng([33, draw]))
+            before = joins()
+            _assert_columns_equal(_frame_on(x, comm).join(_frame_on(big, comm), on="id3").to_dict(), join_m1(x, big, on="id3"))
+            fullest.append(round(SHUFFLE_STATS["bucket_skew"] * _BIG_ROWS / 4))  # of ``big``, the side moved last
+        assert fullest[0] != fullest[1] and joins() == before, (fullest, before, joins())
