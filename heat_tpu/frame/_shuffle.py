@@ -14,10 +14,10 @@ cached jitted programs plus ONE bounded bucketed exchange per operand:
    makes low cardinality cheap — elect range splitters from per-shard
    key samples via one ``all_gather`` (replicated by construction —
    every device computes identical splitters, the sample-sort election),
-   tag each run's last row with its destination partition and every
-   other row with the one past the last, sort once more by (destination,
-   key) carrying the totals, and ``all_gather`` the per-destination
-   counts into the replicated P×P bucket matrix.
+   give each run's last row its destination partition, bring those rows
+   to the front with their totals, each destination's contiguous and in
+   key order, and ``all_gather`` the per-destination counts into the
+   replicated P×P bucket matrix.
 2. **exchange**: the host materializes the (tiny) bucket matrix — the
    same bounded host sync ``redistribute_`` performs for its target
    map — and dispatches :func:`heat_tpu.parallel.flatmove.bucket_move`
@@ -35,8 +35,15 @@ gather or scatter of a 1e8-row column ran at 0.21 GB/s, bound by how the
 chip executes indexed access and not by its memory, and the twelve of
 them in the plan were 87 % of a 17.9 s groupby (PERF.md §6, PR 25). A
 column moves as an operand of a sort the program runs anyway
-(:func:`_carry_sort`), equal keys fold by comparing neighbours
-(:func:`_fold_runs`), and a compaction is a sort on a small leading key.
+(:func:`_carry_sort`) and equal keys fold by comparing neighbours
+(:func:`_fold_runs`). Nor does a program sort where the order is there
+already: rows that carry a one-bit "keep" and stand in the order the
+result wants (a filter's, a join's matched left rows, a sorted block's
+run ends) are shifted to the front in at most ⌈log2 block⌉ elementwise
+passes (:func:`_compact_front`; a sort took 0.14 s an operand at 1e8
+rows, 140 passes' worth, PERF.md §6 PR 32), and only a partition into
+several destinations that interleave is a sort on a small leading key
+(:func:`_partition_front`, :func:`_ends_first`).
 A join matches the same way: both sides sorted together, the right row
 first in each run of equal keys, its values carried along the run
 (:func:`_scan_runs`). That sort is the only key order a join needs, so
@@ -100,8 +107,13 @@ _PROGRAMS = ExecutableCache(maxsize=128)
 # a mesh, 0 on one device); "bucket_skew" is a gauge, not a count: the
 # fullest destination's rows over the mean of the last row shuffle (1.0 =
 # even; what the election achieved: every receive block is that long,
-# rounded up by ``_receive_rows``)
-SHUFFLE_STATS = {"groupbys": 0, "joins": 0, "compactions": 0, "row_shuffles": 0, "bucket_skew": 1.0}
+# rounded up by ``_receive_rows``); "compact_steps" is a gauge too: the
+# passes ``_compact_front`` ran in the last join, filter or groupby on the
+# shard that ran most (the bit length of the most rows dropped ahead of a
+# kept one; 0 where nothing was), read from the counts fetched anyway
+SHUFFLE_STATS = {
+    "groupbys": 0, "joins": 0, "compactions": 0, "row_shuffles": 0, "bucket_skew": 1.0, "compact_steps": 0,
+}
 
 # how each statistic kind folds in the merge stage (all associative)
 STAT_COMBINE = {"sum": "sum", "sumsq": "sum", "count": "sum", "min": "min", "max": "max"}
@@ -171,11 +183,15 @@ def _partition_front(dest, cols):
 
 
 def _ends_first(pid, p: int, sk, totals):
-    """The compaction: each destination's group totals contiguous and in
-    key order, ahead of every row that is no group's (``pid == p``).
-    Among the run ends of one destination the keys differ, so (pid, key)
-    orders them fully and the sort needs no stability, which spares the
-    index operand a stable one carries. Returns (keys, *totals)."""
+    """Each destination's group totals contiguous and in key order, ahead
+    of every row that is no group's (``pid == p``), where the run ends'
+    key order is NOT their (destination, key) order: hash mode on a mesh,
+    whose destinations interleave along the keys. (Everywhere else the run
+    ends stand in that order already and :func:`_compact_front` moves
+    them.) Among the run ends of one destination the keys differ, so
+    (pid, key) orders them fully and the sort needs no stability, which
+    spares the index operand a stable one carries. Returns (keys,
+    *totals)."""
     pid = pid.astype(jnp.int8 if p <= jnp.iinfo(jnp.int8).max else jnp.int32)
     return _carry_sort([pid, sk], totals, stable=False)[1:]
 
@@ -282,6 +298,93 @@ def _scan_runs(sk, n, cols, combiners):
     return lax.optimization_barrier((sk, totals))
 
 
+# columns :func:`_compact_front` moves in one loop: a loop holds its columns twice beside the word
+# that steers it, whatever the table's width. At question 2's shapes on a v5e the compaction took
+# 648 / 622 / 609 / 596 ms in loops of 2 / 3 / 4 / 6 columns (each loop reads the word again), and a
+# join of three columns held 3.77 / 5.02 columns of temporaries at 2 / 3 (2.51 with the sort this
+# replaced; PERF.md §6, PR 32)
+_COMPACT_GROUP = 2
+
+
+def _compact_front(keep, cols):
+    """The rows that ``keep`` marks, moved to the front of every column in
+    the order they have (a stable compaction), without a sort and without
+    an index: returns (columns, steps). What stands behind the kept rows
+    afterwards is unspecified (some of the rows that were there before);
+    every caller hands on the number of kept rows and nothing reads past
+    it.
+    A kept row has to move ``d`` rows forward, ``d`` the rows dropped
+    before it (one cumulative sum). In step s = 0, 1, 2, ... every row
+    whose ``d`` has bit s set moves 2^s rows: row i takes row i + 2^s,
+    ``d`` with it, if that row moves, and becomes a hole (``d`` = 0: it
+    moves no more) if only its own does. Lowest bit first no two kept
+    rows ever meet (Hacker's Delight 7-4, "compress"), every kept row
+    ends at i - d, and the steps needed are the bit length of the largest
+    ``d``, read on the device: at most that of the block's length, 0
+    where nothing is dropped ahead of a kept row. Each step is one
+    elementwise pass whose shift is static (a pad, fused into the pass),
+    as in :func:`_scan_runs`.
+    Which rows take in which step does not depend on what they hold: one
+    loop over ``d`` alone writes it down, bit s of ``takes`` for step s,
+    and the columns follow it in loops of their own, ``_COMPACT_GROUP`` at
+    a time, so that no loop holds more than that many columns twice."""
+    b = keep.shape[0]
+    cols = tuple(cols)
+    widths = [1 << s for s in range((b - 1).bit_length())]
+    if not widths or not cols:
+        return list(cols), jnp.int32(0)
+    d = jnp.where(keep, lax.iota(jnp.int32, b) + 1 - jnp.cumsum(keep.astype(jnp.int32)), 0)
+    steps = 32 - lax.clz(jnp.max(d))
+
+    def ahead(x, w: int):  # row i reads row i + w
+        return lax.pad(x, jnp.zeros((), x.dtype), [(-w, w, 0)])
+
+    def halves(step):
+        """``step(w, x)`` as a loop round's two switches, asked by the round ``r``: its first step
+        (2r: the even widths) and its second (2r + 1: the odd ones and, where the count of steps
+        is odd and ends before it, an entry that moves nothing). A round's halves are two
+        instructions whatever is done, so each holds only the widths it can be asked for."""
+        even = [partial(step, w) for w in widths[0::2]]
+        odd = [*(partial(step, w) for w in widths[1::2]), lambda x: x]
+        return (
+            lambda r, x: lax.switch(r, even, x),
+            lambda r, x: lax.switch(jnp.where(2 * r + 1 < steps, r, len(odd) - 1), odd, x),
+        )
+
+    def rounds(first, second, x):
+        """Steps 0 .. ``steps`` - 1, two a loop round: the first writes beside the loop's carry and
+        the second back into it, where one a round would copy every column back into the carry
+        each time."""
+        def body(carry):
+            r, x = carry
+            return r + 1, second(r, first(r, x))
+
+        return lax.while_loop(lambda c: 2 * c[0] < steps, body, (jnp.int32(0), x))[1]
+
+    def shifted(w, d):
+        arriving = ahead(d, w)
+        return jnp.where((arriving & w) != 0, arriving, jnp.where((d & w) != 0, 0, d))
+
+    def noting(half, second: int):
+        # after step s bit s of a row's d is set exactly where the row arrived in it: one that
+        # stayed had it clear (as all have after the entry that moves nothing: no d is that
+        # large). Outside the switch, so that ``takes`` is updated where it stands
+        def noted(r, x):
+            d, takes = x
+            d = half(r, d)
+            return d, takes | (d & (1 << (2 * r + second)))
+
+        return noted
+
+    first, second = halves(shifted)
+    _, takes = rounds(noting(first, 0), noting(second, 1), (d, jnp.zeros_like(d)))
+    move = halves(lambda w, cs: tuple(jnp.where((takes & w) != 0, ahead(c, w), c) for c in cs))
+    out = []
+    for j in range(0, len(cols), _COMPACT_GROUP):
+        out += rounds(*move, cols[j : j + _COMPACT_GROUP])
+    return out, steps
+
+
 def _fold_runs(sk, n, cols, kinds):
     """Each run of equal keys among a sorted block's first ``n`` rows folded
     into its last row, ``kinds[j]``'s combiner on ``cols[j]``
@@ -312,6 +415,13 @@ def _dest_matrix(pid, p: int):
     return lax.all_gather(row, SPLIT_AXIS)
 
 
+def _counts_and_steps(count, steps):
+    """The replicated vector a caller fetches anyway, a word longer: every
+    shard's row count and, behind them, the steps :func:`_compact_front`
+    ran on the shard that ran most (:func:`_read_counts` splits it)."""
+    return jnp.concatenate([lax.all_gather(count, SPLIT_AXIS), lax.pmax(steps, SPLIT_AXIS)[None]])
+
+
 # ------------------------------------------------------------------- programs
 def _plan_executable(
     pshape: Tuple[int, ...],
@@ -324,8 +434,13 @@ def _plan_executable(
 ):
     """The groupby plan program: local sort carrying the values →
     segmented scan into partials → splitter election → destination
-    tagging → compaction by (destination, key) → replicated bucket
-    matrix. One dispatch, data-independent cache key."""
+    tagging → the run ends to the front, each destination's contiguous
+    and in key order → replicated bucket matrix. The run ends stand in
+    key order; where that is their (destination, key) order too, on one
+    device and under range splitters (a destination that rises with the
+    key), :func:`_compact_front` moves them, and only hash mode on a
+    mesh sorts (:func:`_ends_first`). One dispatch, data-independent
+    cache key."""
     mesh = comm.mesh
     key = ("plan", pshape, str(key_dtype), val_dtypes, stats, p, mode, mesh)
     fn = _PROGRAMS.get(key)
@@ -356,11 +471,15 @@ def _plan_executable(
         pid = jnp.where(is_end, pid, p)
         mat = _dest_matrix(pid, p)
         uvec = lax.all_gather(u, SPLIT_AXIS)
-        return (*_ends_first(pid, p, sk, totals), mat, uvec)
+        if p == 1 or mode == "range":
+            outs, steps = _compact_front(is_end, [sk, *totals])
+        else:
+            outs, steps = _ends_first(pid, p, sk, totals), jnp.int32(0)
+        return (*outs, lax.pmax(steps, SPLIT_AXIS), mat, uvec)
 
     spec = P(SPLIT_AXIS)
     in_specs = (spec, P(), *([spec] * len(val_dtypes)))
-    out_specs = (spec, *([spec] * len(stats)), P(), P())
+    out_specs = (spec, *([spec] * len(stats)), P(), P(), P())
     prog = shard_map(frame_plan, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     fn = _PROGRAMS[key] = jax.jit(prog)
     return fn
@@ -375,22 +494,25 @@ def _merge_executable(
 ):
     """The post-exchange merge program: sort received partials by key,
     scan with each statistic's associative combiner, compact the group
-    totals to the front, report per-shard group counts (replicated)."""
+    totals to the front, report per-shard group counts (replicated) and,
+    behind them, the most steps a compaction of this groupby ran: the
+    plan's (``planned``, handed over on the device) or this one's."""
     mesh = comm.mesh
     key = ("gmerge", pshape, str(key_dtype), stats, p, mesh)
     fn = _PROGRAMS.get(key)
     if fn is not None:
         return fn
 
-    def frame_merge(kb, counts, *parts):
+    def frame_merge(kb, counts, planned, *parts):
         n = counts[lax.axis_index(SPLIT_AXIS)]
         sk, sparts = _sort_by_key(kb, n, list(parts))
         sk, totals, is_end = _fold_runs(sk, n, sparts, [kind for kind, _ in stats])
-        gvec = lax.all_gather(jnp.sum(is_end.astype(jnp.int32)), SPLIT_AXIS)
-        return (*_ends_first(~is_end, 1, sk, totals), gvec)
+        outs, steps = _compact_front(is_end, [sk, *totals])
+        gvec = _counts_and_steps(jnp.sum(is_end.astype(jnp.int32)), jnp.maximum(steps, planned))
+        return (*outs, gvec)
 
     spec = P(SPLIT_AXIS)
-    in_specs = (spec, P(), *([spec] * len(stats)))
+    in_specs = (spec, P(), P(), *([spec] * len(stats)))
     out_specs = (spec, *([spec] * len(stats)), P())
     prog = shard_map(frame_merge, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     fn = _PROGRAMS[key] = jax.jit(prog)
@@ -485,12 +607,13 @@ def _join_executable(
     (zeros in the other side's rows): within a run of equal keys the
     right row, which stood earlier, comes first, then the left rows in
     their own order. :func:`_scan_runs` carries that first row's values,
-    and whether it was a right row at all (``hit``), along the run; a
-    compaction brings the left rows to keep to the front: the matched
-    (inner), or all of them, what found no match NaN-filled (left). No
-    search, no index, no lookup. The result's block is as long as both
-    blocks together: cut back to the left block's length, every column
-    of it would be one more copy."""
+    and whether it was a right row at all (``hit``), along the run;
+    :func:`_compact_front` shifts the left rows to keep to the front: the
+    matched (inner), or all of them, what found no match NaN (left: a
+    left row starts out with it). One sort; no search, no index, no
+    lookup. The result's block is as long as both blocks together: cut
+    back to the left block's length, every column of it would be one
+    more copy."""
     mesh = comm.mesh
     key = ("join", l_pshape, r_pshape, str(key_dtype), l_dtypes, r_dtypes, how, p, mesh)
     fn = _PROGRAMS.get(key)
@@ -512,7 +635,19 @@ def _join_executable(
         # the run of a valid row with that very key, where its side tells it apart
         k = jnp.where(side == pad, jnp.asarray(_last_key(lk.dtype)), jnp.concatenate([rk, lk]))
         lcols = [jnp.concatenate([jnp.zeros((br,), v.dtype), v]) for v in lvals]
-        rcols = [jnp.concatenate([v, jnp.zeros((bl,), v.dtype)]) for v in rvals]
+        # What a left row without a match comes out with, it starts out with, and the scan hands it
+        # on: NaN (left; the right columns become floats for it), nothing anyone reads (inner).
+        # The right block's pads hold it too: they stand first in the run of a left row whose key
+        # nothing sorts after. No pass fills anything afterwards: its results were new buffers that
+        # stood beside the compaction's, 4.8 columns at question 2's widths (sandbox compile, PR 32)
+        null = jnp.nan if how == "left" else 0
+        if how == "left":
+            rvals = [v.astype(jnp.promote_types(v.dtype, jnp.float32)) for v in rvals]
+        in_right = lax.iota(jnp.int32, br) < nr
+        rcols = [
+            jnp.concatenate([jnp.where(in_right, v, jnp.asarray(null, v.dtype)), jnp.full((bl,), null, v.dtype)])
+            for v in rvals
+        ]
         # the side is a payload, not a second key: one comparison a row
         sk, side, *cols = _carry_sort([k], [side, *lcols, *rcols], stable=True)
         slv, srv = cols[: len(lvals)], cols[len(lvals) :]
@@ -523,19 +658,12 @@ def _join_executable(
         dup = lax.pmax(dup_local.astype(jnp.int32), SPLIT_AXIS)
         sk, (hit, *srv) = _scan_runs(sk, br + bl, [is_right, *srv], ["first"] * (1 + len(srv)))
         if how == "inner":
-            keep, null = (side == left) & hit, 0
+            keep = (side == left) & hit
             g = jnp.sum(keep.astype(jnp.int32))
-        else:  # left: all valid left rows, unmatched right values -> NaN
-            keep, null = side == left, jnp.nan
-            g = nl
-            srv = [v.astype(jnp.promote_types(v.dtype, jnp.float32)) for v in srv]
-        # An inner join drops the rows this fills, but the pass pays for itself in memory: its
-        # results are new buffers, so the scan's columns need not outlive the scan, and at 1e8
-        # rows the program holds 4 columns (1.6 GB) less (sandbox compile, PR 28; PERF.md §5)
-        srv = [jnp.where(hit, v, jnp.asarray(null, v.dtype)) for v in srv]
-        outs = _partition_front((~keep).astype(jnp.int8), [sk, *slv, *srv])
-        gvec = lax.all_gather(g, SPLIT_AXIS)
-        return (*outs, gvec, dup)
+        else:  # left: all valid left rows
+            keep, g = side == left, nl
+        outs, steps = _compact_front(keep, [sk, *slv, *srv])
+        return (*outs, _counts_and_steps(g, steps), dup)
 
     spec = P(SPLIT_AXIS)
     in_specs = (
@@ -555,8 +683,9 @@ def _compact_executable(
     p: int,
     comm: MeshCommunication,
 ):
-    """Local filter compaction: stable-partition kept rows to each
-    shard's prefix (ragged result, ZERO exchanges), report kept counts."""
+    """Local filter compaction: the kept rows shifted to each shard's
+    prefix in their order (:func:`_compact_front`; ragged result, ZERO
+    exchanges), report kept counts."""
     mesh = comm.mesh
     key = ("compact", pshape, dtypes, p, mesh)
     fn = _PROGRAMS.get(key)
@@ -567,8 +696,8 @@ def _compact_executable(
     def frame_compact(mask, counts, *cols):
         n = counts[lax.axis_index(SPLIT_AXIS)]
         keep = mask & (lax.iota(jnp.int32, b) < n)
-        gvec = lax.all_gather(jnp.sum(keep.astype(jnp.int32)), SPLIT_AXIS)
-        return (*_partition_front((~keep).astype(jnp.int8), list(cols)), gvec)
+        outs, steps = _compact_front(keep, cols)
+        return (*outs, _counts_and_steps(jnp.sum(keep.astype(jnp.int32)), steps))
 
     spec = P(SPLIT_AXIS)
     in_specs = (spec, P(), *([spec] * len(dtypes)))
@@ -581,6 +710,14 @@ def _compact_executable(
 # ------------------------------------------------------------- orchestration
 def _counts_vec(counts: Sequence[int]) -> jnp.ndarray:
     return jnp.asarray(tuple(int(c) for c in counts), jnp.int32)
+
+
+def _read_counts(vec, site: str) -> np.ndarray:
+    """Fetch what :func:`_counts_and_steps` made: the per-shard counts come
+    back, the steps go into the gauge ``SHUFFLE_STATS["compact_steps"]``."""
+    vec = _hooks.fetch(vec, site)
+    SHUFFLE_STATS["compact_steps"] = int(vec[-1])
+    return vec[:-1]
 
 
 def _receive_rows(fullest: int) -> int:
@@ -630,7 +767,7 @@ def groupby_reduce(
         tuple(kb.shape), kb.dtype, val_dtypes, stats, p, mode, comm
     )
     out = collective_lockstep(plan(kb, counts, *value_bufs))
-    pk, parts, mat = out[0], list(out[1 : 1 + len(stats)]), out[-2]
+    pk, parts, planned, mat = out[0], list(out[1 : 1 + len(stats)]), out[-3], out[-2]
     # the replicated bucket matrix comes to host to build the static
     # exchange schedule — same bounded sync as redistribute_'s target map
     mat_np = _hooks.fetch(mat, "groupby.bucket_matrix")
@@ -644,8 +781,8 @@ def groupby_reduce(
         p,
         comm,
     )
-    mout = collective_lockstep(merge(moved[0], _counts_vec(out_counts), *moved[1:]))
-    gvec = _hooks.fetch(mout[-1], "groupby.group_counts")
+    mout = collective_lockstep(merge(moved[0], _counts_vec(out_counts), planned, *moved[1:]))
+    gvec = _read_counts(mout[-1], "groupby.group_counts")
     n_groups = int(gvec.sum())
     mkeys = DNDarray._from_ragged(
         mout[0], (n_groups,), mout[0].dtype, 0, tuple(int(c) for c in gvec),
@@ -751,7 +888,7 @@ def hash_join(
         )
     )
     dup = int(_hooks.fetch(out[-1], "shuffle.join_dup"))
-    gvec = _hooks.fetch(out[-2], "shuffle.join_counts")
+    gvec = _read_counts(out[-2], "shuffle.join_counts")
     SHUFFLE_STATS["joins"] += 1
     return list(out[:-2]), gvec, dup
 
@@ -768,6 +905,6 @@ def compact_rows(
         tuple(mask_buf.shape), tuple(str(b.dtype) for b in col_bufs), comm.size, comm
     )
     out = collective_lockstep(fn(mask_buf, _counts_vec(counts), *col_bufs))
-    gvec = _hooks.fetch(out[-1], "shuffle.compact_counts")
+    gvec = _read_counts(out[-1], "shuffle.compact_counts")
     SHUFFLE_STATS["compactions"] += 1
     return list(out[:-1]), gvec

@@ -14,47 +14,55 @@ from ._chip_helpers import _frame_mesh, _indexed_ops, _one_chip, _spec, four_chi
 # thousandth of the left one's rows as in h2o.ai db-benchmark's join question 2 (PERF.md §4,
 # `join-q2-medium-inner`). It sorts the right block with the left block behind it (key, side, every
 # payload of both sides, the index stability costs), carries each run's first row forward and
-# compacts with a second sort of as many operands: no search, no lookup, so no gather and no scatter
-# over any of the three block lengths involved. The result's block is as long as both sides' blocks
-# together: the concatenation is written into the result's buffers, and half of the first sort's
-# columns leave them for temporaries until the second sort brings them back.
+# shifts the left rows to keep to the front in elementwise passes (``_compact_front``, PR 32; a
+# second sort of as many operands before): ONE sort, no search, no lookup, so no gather and no
+# scatter over any of the three block lengths involved. The result's block is as long as both
+# sides' blocks together. The compaction holds, beside the columns it moves, the word that steers
+# it and two columns more at a time (a loop's columns stand twice), whatever the table's width.
 #
 # A sort's compile time follows its operand count, not its rows, and what these tests ask does not
-# follow it: the structure (two sorts, nothing indexed, the collectives, a chip's arguments being
+# follow it: the structure (one sort, nothing indexed, the collectives, a chip's arguments being
 # its share) is the same with one payload a side as with six and four, and the memory bounds are
-# linear in the payloads and stated in columns of the left table. So tier-1 compiles
-# ``one_payload`` (one int32 payload left, one f32 right: sorts of 5 operands, the groupby's price)
+# stated in columns of the left table. So tier-1 compiles
+# ``one_payload`` (one int32 payload left, one f32 right: a sort of 5 operands, the groupby's price)
 # for both layouts, and a program that began to hold a second copy of its columns would show there
 # as it would at question 2's widths. ``question_2`` (five int32 and one f32 payload left, three
-# int32 and one f32 right: sorts of 13 operands) is the cell's own program a hundredth as long, on
-# one chip only and marked ``slow``: 352-373 s alone on the sandbox and 463 s beside a full run
-# (PR 29), 53-70 % of the bound conftest gives a test. Whoever changes ``_join_executable``,
-# ``_carry_sort``, ``_scan_runs`` or ``_partition_front`` runs it:
+# int32 and one f32 right: a sort of 13 operands) is the cell's own program, on one chip only and
+# marked ``slow``: 352-373 s alone on the sandbox and 463 s beside a full run with the two sorts it
+# had (PR 29), 53-70 % of the bound conftest gives a test; about 500-830 s beside five other
+# compiles now (PR 32). Whoever changes ``_join_executable``, ``_carry_sort``, ``_scan_runs`` or
+# ``_compact_front`` runs it:
 #     pytest -m slow tests/test_chip_compile_join.py
-# (sandbox compiles at question 2's widths, PR 28: temporaries 3.85 columns over the four chips
-# and 3.84 over one at this size, where the compiler keeps some columns in another memory space,
-# everything held 21.86; 7.27 columns of temporaries at 1e8 rows on one chip, PERF.md §5).
 #
 # ``question_5`` (PR 31) is the four-chip cell's own program (PERF.md §4, `join-q5-big-inner-4chip`:
 # h2o.ai's ``big inner on int``), over four chips only, where the cell runs: the right table as
-# long as the left, five int32 and one f32 payload a side, sorts of 15 operands, the result's block
+# long as the left, five int32 and one f32 payload a side, a sort of 15 operands, the result's block
 # twice the left one's. Marked ``slow`` as question 2's is, and for the same reason, and so is
 # the partition program that feeds it (one stable sort of 9 operands by destination), a test of
 # its own so that neither compile runs into the bound conftest gives a test.
-_ROWS = 1 << 20
-_COLUMN = 4 * _ROWS  # bytes: every column here is 32 bits wide
+#
+# The rows are the cells' own, nearly (PR 32; 2^20 a chip before): 2^26 on one chip, the power of
+# two under question 2's 1e8 (the plan of the groupby compiles in 110 s at 2^26 and 147 s at 1e8,
+# alone on the sandbox, and reads the same 3.52 columns), and the 25 165 824 of question 5's
+# receive block on each of four. At 2^20 rows a column is 4 MB, short enough for the compiler to
+# stage whole columns in its fast memory: ``temp_size_in_bytes`` then follows that staging and not
+# what the program holds in HBM (question 2's program read 12.04 columns of temporaries at 2^20
+# rows and 5.54 at 1e8; with the two sorts it had before, 3.84 and 7.27).
+_ROWS = {1: 1 << 26, 4: 25_165_824}  # chips -> rows a chip; every column here is 32 bits wide
 
 # widths -> (left payloads, right payloads, left rows to a right row, most temporaries over four
 # chips, most held on one chip), the last two in columns of the left table, each pinned over the
-# sandbox's compile with the margin question 2's bound has over its own: one payload 2.75 and 8.13
-# (2 + 3 + 3.12 of temporaries; PR 29), question 2 3.85 and 21.86 (PR 28), question 5 8.68 over
-# four chips (PR 31: 456 s alone on the sandbox; at the cell's 2.5e7 rows a chip 15.07, of blocks
-# of 0.1 GB, beside 14 of arguments and 26 of outputs; no one chip holds it)
+# sandbox's compile (PR 32, one chip's at 1e8 rows; the same programs with the compaction's sort,
+# PR 31's tree at the same rows, in brackets): one payload 4.06 [2.77] over four chips and 8.77 [7.51] held on one (2 + 3 +
+# 3.77 [2.51] of temporaries: the compaction's word, its two columns twice); question 2 23.56
+# [25.28] held (7 + 11.01 + 5.54 [7.27]); question 5 14.67 [15.07] over four chips, of blocks of 0.1 GB,
+# beside 14 of arguments and 26 of outputs (no one chip holds it). A narrow table pays for the
+# compaction's own columns, 1.3 more; at the cells' widths they stand where the second sort's stood
 _Q5_PAYLOADS = ("int32",) * 5 + ("float32",)
 _WIDTHS = {
-    "one_payload": (("int32",), ("float32",), 1024, 3.2, 8.4),
-    "question_2": (("int32",) * 5 + ("float32",), ("int32",) * 3 + ("float32",), 1024, 4.5, 22.5),
-    "question_5": (_Q5_PAYLOADS, _Q5_PAYLOADS, 1, 10.0, None),
+    "one_payload": (("int32",), ("float32",), 1024, 4.5, 9.1),
+    "question_2": (("int32",) * 5 + ("float32",), ("int32",) * 3 + ("float32",), 1024, None, 24.2),
+    "question_5": (_Q5_PAYLOADS, _Q5_PAYLOADS, 1, 15.5, None),
 }
 
 
@@ -64,7 +72,7 @@ def _compiled_join(mesh, p: int, left, right, ratio: int):
     from heat_tpu.frame import _shuffle
 
     comm, rows, rep = _frame_mesh(mesh)
-    lshape, rshape = (p * _ROWS,), (p * _ROWS // ratio,)
+    lshape, rshape = (p * _ROWS[p],), (p * (_ROWS[p] // ratio),)
     fn = _shuffle._join_executable(lshape, rshape, jnp.dtype("int32"), left, right, "inner", p, comm)
     return fn.lower(
         _spec(lshape, jnp.int32, rows), _spec((p,), jnp.int32, rep), *[_spec(lshape, jnp.dtype(d), rows) for d in left],
@@ -72,10 +80,10 @@ def _compiled_join(mesh, p: int, left, right, ratio: int):
     ).compile()
 
 
-def _join_matches_without_an_index(text: str, ratio: int):
-    for block in (_ROWS + _ROWS // ratio, _ROWS, _ROWS // ratio):
+def _join_matches_without_an_index(text: str, rows: int, ratio: int):
+    for block in (rows + rows // ratio, rows, rows // ratio):
         assert _indexed_ops(text, block) == [], block
-    assert text.count(" sort(") == 2  # both sides together by key; the compaction
+    assert text.count(" sort(") == 1  # both sides together by key; the compaction is no sort
 
 
 @pytest.mark.parametrize("widths", [pytest.param("question_5", marks=pytest.mark.slow)])
@@ -90,7 +98,7 @@ def test_partition_program_sorts_once_over_four_chips(four_chips, widths):
 
     payloads = _WIDTHS[widths][0]
     comm, rows, rep = _frame_mesh(four_chips)
-    shape = (4 * _ROWS,)
+    shape, column = (4 * _ROWS[4],), 4 * _ROWS[4]
     fn = _shuffle._partition_executable(shape, jnp.dtype("int32"), payloads, 4, "range", comm)
     compiled = fn.lower(
         _spec(shape, jnp.int32, rows), _spec((4,), jnp.int32, rep), _spec((3,), jnp.int32, rep),
@@ -98,38 +106,38 @@ def test_partition_program_sorts_once_over_four_chips(four_chips, widths):
     ).compile()
     text = compiled.as_text()
     assert text.count(" sort(") == 1
-    assert _indexed_ops(text, _ROWS) == []
+    assert _indexed_ops(text, _ROWS[4]) == []
     assert "all-gather" in text or "all-reduce" in text  # the bucket matrix, a few words
     mem = compiled.memory_analysis()
-    # 2.22 here (PR 31: 177-186 s alone on the sandbox), 2.00 at the cell's 2.5e7 rows a chip
-    assert mem.temp_size_in_bytes < 2.6 * _COLUMN, mem.temp_size_in_bytes / _COLUMN
+    # 2.00 at the cell's rows (PR 32; 2.22 at 2^20 rows a chip, PR 31: 177-186 s alone on the sandbox)
+    assert mem.temp_size_in_bytes < 2.6 * column, mem.temp_size_in_bytes / column
 
 
 @pytest.mark.parametrize("widths", ["one_payload", pytest.param("question_5", marks=pytest.mark.slow)])
 def test_join_program_compiles_over_four_chips(four_chips, widths):
     left, right, ratio, most_temp, _ = _WIDTHS[widths]
     compiled = _compiled_join(four_chips, 4, left, right, ratio)
-    text = compiled.as_text()
-    _join_matches_without_an_index(text, ratio)
+    text, column = compiled.as_text(), 4 * _ROWS[4]
+    _join_matches_without_an_index(text, _ROWS[4], ratio)
     assert "all-gather" in text or "all-reduce" in text  # the row counts and the duplicate flag, a few words
     mem = compiled.memory_analysis()
     # a chip's arguments are its quarter: the left table's columns, the right one's as much shorter as the table
     arguments = (1 + len(left)) + (1 + len(right)) / ratio
-    assert mem.argument_size_in_bytes < arguments * _COLUMN + (1 << 20), mem.argument_size_in_bytes / _COLUMN
+    assert mem.argument_size_in_bytes < arguments * column + (1 << 20), mem.argument_size_in_bytes / column
     # the key and every payload, of both blocks' rows
     outputs = (1 + len(left) + len(right)) * (1 + 1 / ratio)
-    assert mem.output_size_in_bytes < outputs * _COLUMN + (1 << 20), mem.output_size_in_bytes / _COLUMN
-    assert mem.temp_size_in_bytes < most_temp * _COLUMN, mem.temp_size_in_bytes / _COLUMN
+    assert mem.output_size_in_bytes < outputs * column + (1 << 20), mem.output_size_in_bytes / column
+    assert mem.temp_size_in_bytes < most_temp * column, mem.temp_size_in_bytes / column
 
 
 @pytest.mark.parametrize("widths", ["one_payload", pytest.param("question_2", marks=pytest.mark.slow)])
 def test_join_program_fits_one_chip(topo, widths):
     """The cell's layout. Everything the program holds at once, in columns of the left table: its
-    arguments, its outputs and its temporaries (7 + 11 + 3.84 at question 2's widths; at 1e8 rows a
-    column is 0.4 GB and the two tables stand beside the program)."""
+    arguments, its outputs and its temporaries (7 + 11.01 + 5.54 at question 2's widths: a column is
+    0.4 GB and the two tables stand beside the program)."""
     left, right, ratio, _, most_held = _WIDTHS[widths]
     compiled = _compiled_join(_one_chip(topo), 1, left, right, ratio)
-    _join_matches_without_an_index(compiled.as_text(), ratio)
-    mem = compiled.memory_analysis()
+    _join_matches_without_an_index(compiled.as_text(), _ROWS[1], ratio)
+    mem, column = compiled.memory_analysis(), 4 * _ROWS[1]
     held = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes - mem.alias_size_in_bytes
-    assert held < most_held * _COLUMN, held / _COLUMN
+    assert held < most_held * column, held / column
