@@ -364,7 +364,9 @@ def phase_linalg(key):
 
 def _lasso_reference(X, y, lam, sweeps: int):
     """Plain coordinate descent, the same sweeps in the same column order
-    (column 0 is the unregularized intercept), one residual kept up to date."""
+    (column 0 is the unregularized intercept), one residual kept up to date.
+    The smoke's on-device comparison; the reference of record is
+    ``heat_tpu/regression/reference.py`` (NumPy float64, on the host)."""
     import jax
     import jax.numpy as jnp
 

@@ -13,6 +13,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core import _hooks, types
 from ..core._cache import ExecutableCache
@@ -241,6 +242,7 @@ class Lasso(BaseEstimator, RegressionMixin):
         self.__theta = final["theta"]
         return self
 
+    @_hooks.public_call("Lasso.fit")
     def fit(self, x: DNDarray, y: DNDarray, supervisor=None,
             block_iters: int = 16) -> "Lasso":
         """reference ``lasso.py:fit``; with ``supervisor`` the fit runs as
@@ -252,18 +254,20 @@ class Lasso(BaseEstimator, RegressionMixin):
         if supervisor is not None:
             return self._fit_supervised(x, y, supervisor, block_iters)
         X = x._logical().astype(jnp.promote_types(x.larray.dtype, jnp.float32))
-        Y = y._logical().astype(X.dtype).ravel()
-        m = X.shape[1]
-        theta = jnp.zeros(m, dtype=X.dtype)
-        lam = jnp.asarray(self.lam, dtype=X.dtype)
-
+        # reshape, not ravel: a 1-D y is then handed on as it is, where ravel's
+        # jitted program copied it (40 MB a call at 1e7 rows)
+        Y = y._logical().astype(X.dtype).reshape(-1)
+        # the start and the three scalars go in as host values of the program's
+        # dtypes: the jitted call places them itself, where four eager ops
+        # before it cost 1.9 ms a fit with the device idle (PERF.md, PR 33)
+        scalar = X.dtype.type
         theta, n_iter = _cd_fit(
             X,
             Y,
-            theta,
-            lam,
-            jnp.asarray(self.tol, X.dtype),
-            jnp.int32(self.max_iter),
+            np.zeros(X.shape[1], dtype=X.dtype),
+            scalar(self.lam),
+            scalar(self.tol),
+            np.int32(self.max_iter),
         )
         self.n_iter = int(_hooks.fetch(n_iter, "lasso.n_iter"))
         self.__theta = DNDarray(theta.reshape(-1, 1), split=None, device=x.device, comm=x.comm)

@@ -117,7 +117,7 @@ def _outermost_calls(traced):
 def test_one_outermost_call_span_a_public_call_with_increasing_call(traced):
     outer = _outermost_calls(traced)
     names = [c["name"] for c in outer]
-    assert names == ["ht.call:Frame.join", "ht.call:KMeans.fit", "ht.call:test.outer"] + ["ht.call:KMeans.fit", "ht.call:cdist", "ht.call:groupby.agg"] * ROUNDS
+    assert names == ["ht.call:Frame.join", "ht.call:Lasso.fit", "ht.call:Lasso.fit", "ht.call:KMeans.fit", "ht.call:test.outer"] + ["ht.call:KMeans.fit", "ht.call:cdist", "ht.call:groupby.agg"] * ROUNDS
     numbers = [c["call"] for c in outer]
     assert numbers == list(range(numbers[0], numbers[0] + len(outer)))  # test.inner, nested, drew none
 
@@ -148,8 +148,8 @@ SITES = [
     ("kmeans.inertia", "ht.call:KMeans.fit", ROUNDS + 1),
     ("kcluster.shift", "ht.call:KMeans.fit", 2),
     ("kcluster.iters", "ht.call:KMeans.fit", 2),
-    ("lasso.diff", None, 2),
-    ("lasso.sweeps", None, 2),
+    ("lasso.diff", "ht.call:Lasso.fit", 2),
+    ("lasso.sweeps", "ht.call:Lasso.fit", 2),
     ("kmeans.n_iter", "ht.call:KMeans.fit", ROUNDS),
     ("groupby.bucket_matrix", "ht.call:groupby.agg", ROUNDS),
     ("groupby.group_counts", "ht.call:groupby.agg", ROUNDS),
@@ -161,7 +161,7 @@ SITES = [
     ("frame.to_dict", None, 1),
     ("kmedians.n_iter", None, 1),
     ("kmedoids.n_iter", None, 1),
-    ("lasso.n_iter", None, 1),
+    ("lasso.n_iter", "ht.call:Lasso.fit", 1),
     ("dndarray.gather", None, None),
     ("dndarray.item", None, None),
     ("dndarray.scalar", None, None),
@@ -177,6 +177,14 @@ def test_fetch_site(traced, site, parent, count):
         for f in found:
             (call,) = [c for c in _named(traced, parent) if f in _inside(traced, c)]
             assert f["call"] == call["call"]
+
+
+def test_lasso_fit_opens_its_span_once_a_fit(traced):
+    plain, supervised = _named(traced, "ht.call:Lasso.fit")  # one a fit, in the order _the_other_reads makes them
+    assert plain in _outermost_calls(traced) and supervised in _outermost_calls(traced)
+    assert [s["name"] for s in _inside(traced, plain)] == ["ht.fetch:lasso.n_iter"]  # the fit's one fetch
+    assert {s["name"] for s in _inside(traced, supervised)} == {"ht.fetch:lasso.diff", "ht.fetch:lasso.sweeps"}
+    assert supervised["call"] == plain["call"] + 1
 
 
 def test_no_fetch_inside_cdist(traced):
