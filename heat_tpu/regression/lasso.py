@@ -1,14 +1,21 @@
 """Lasso regression (reference ``heat/regression/lasso.py``).
 
-Coordinate descent with soft thresholding. The reference's per-feature loop
-issues a distributed matvec per coordinate (``lasso.py:10-186``); here one
-full sweep over features is a single jitted ``lax.fori_loop`` whose matvecs
-are sharded over the data axis (psum on ICI), so a sweep is one XLA program
-regardless of feature count.
+Cyclic coordinate descent with soft thresholding. The reference's
+per-feature loop issues a distributed matvec per coordinate
+(``lasso.py:10-186``); here a whole fit is one jitted program, sweeps in a
+``lax.while_loop`` with the convergence test on the device, a sweep a
+``lax.fori_loop`` over the features. Which sweep runs is decided by the
+table's shape alone (:func:`_cd_sweep` states the rule):
+
+* a tall table (``m <= n``, ``m <= 2048``) is swept in the Gram matrix's
+  space: x is read twice a program, for ``X^T y``, the column norms and
+  ``X^T X`` (one all-reduce of each over the data axis), and a sweep
+  touches theta and an (m, m) matrix only;
+* any other table is swept over x itself with the running residual
+  carried, a column read and a scalar psum (on ICI) a coordinate.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Optional
 
 import jax
@@ -66,30 +73,116 @@ def _sgd_program():
     return prog
 
 
-@partial(jax.jit, static_argnames=())
-def _cd_sweep(X: jnp.ndarray, y: jnp.ndarray, theta: jnp.ndarray, lam: jnp.ndarray):
-    """One full coordinate-descent sweep (all features), jitted.
+# The widest table whose sweeps run in the Gram matrix's space (``_cd_path``).
+_GRAM_MAX_COLUMNS = 2048
 
-    Maintains the running residual so a sweep costs one matvec total
-    instead of one per coordinate. Coordinate 0 (the intercept column) is
-    not regularized, matching the reference (``lasso.py:160-164``).
+
+def _cd_path(n: int, m: int) -> str:
+    """Which sweep a table of ``n`` rows and ``m`` columns takes: ``"gram"``
+    or ``"residual"``; see :func:`_cd_sweep` for the rule and its reasons."""
+    return "gram" if m <= n and m <= _GRAM_MAX_COLUMNS else "residual"
+
+
+def _cd_step(rho, lam_n, col_sq_j, j):
+    """The coordinate's new value from ``rho = x_j . (r + x_j theta_j)``:
+    the soft threshold, coordinate 0 (the intercept column) not
+    regularized, matching the reference (``lasso.py:160-164``)."""
+    soft = jnp.sign(rho) * jnp.maximum(jnp.abs(rho) - lam_n, 0.0)
+    numer = jnp.where(j == 0, rho, soft)
+    return jnp.where(col_sq_j > 0, numer / jnp.maximum(col_sq_j, 1e-30), 0.0)
+
+
+def _gram_sweep(X: jnp.ndarray, y: jnp.ndarray, lam):
+    """The sweep in the Gram matrix's space: x is read twice here, for all
+    the sweeps of a program, and a sweep carries theta alone. With
+    ``r = y - X theta``, ``x_j . (r + x_j theta_j) = q_j - sum_{i != j}
+    G_ji theta_i`` where ``q = X^T y`` and ``G = X^T X``.
+
+    What decides the result's accuracy is how sums of size n are added up.
+    ``q``, the column norms and the column sums ``s`` are taken by a
+    multiply-reduce (written side by side: one fused pass over x), whose
+    float32 partial sums stay short. The one matmul adds each output into
+    a single accumulator over all rows, so it is given only sums that stay
+    near sqrt(n): the off-diagonals of the *centred* columns' Gram matrix,
+    ``G_ij = sum_k (x_ki - s_i/n)(x_kj - s_j/n) + s_i s_j / n``, the
+    subtraction riding the matmul's own read of x. The diagonal is set to
+    zero and never meets the norms. The matmul contracts the rows of x as
+    x stands (a reshape, a transpose or a cast would copy the table), at
+    ``HIGHEST`` precision: a float32 product.
     """
     n, m = X.shape
-    col_sq = jnp.sum(X * X, axis=0)  # (m,)
+    lam_n = lam * n
+    col_sq = jnp.sum(X * X, axis=0)
+    q = jnp.sum(X * y[:, None], axis=0)
+    s = jnp.sum(X, axis=0)
+    mean = s / n
+    Z = X - mean[None, :]
+    C = jax.lax.dot_general(
+        Z, Z, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=X.dtype,
+    )
+    off = jnp.where(jnp.eye(m, dtype=bool), jnp.zeros((), X.dtype), C + s[:, None] * mean[None, :])
+
+    def body(j, th):
+        # an elementwise product and a sum: a vector dot at default
+        # precision may be given one bf16 pass
+        rho = q[j] - jnp.sum(off[j] * th)
+        return th.at[j].set(_cd_step(rho, lam_n, col_sq[j], j))
+
+    return lambda theta: jax.lax.fori_loop(0, m, body, theta)
+
+
+def _residual_sweep(X: jnp.ndarray, y: jnp.ndarray, lam):
+    """The sweep over x itself: the running residual is carried beside
+    theta, so a sweep costs one matvec and one column read a coordinate
+    instead of a matvec a coordinate."""
+    n, m = X.shape
+    lam_n = lam * n
+    col_sq = jnp.sum(X * X, axis=0)
 
     def body(j, carry):
         th, r = carry
         # rho_j over the residual with feature j added back
         rho = X[:, j] @ (r + X[:, j] * th[j])
-        soft = jnp.sign(rho) * jnp.maximum(jnp.abs(rho) - lam * n, 0.0)
-        numer = jnp.where(j == 0, rho, soft)  # intercept unregularized
-        new_tj = jnp.where(col_sq[j] > 0, numer / jnp.maximum(col_sq[j], 1e-30), 0.0)
+        new_tj = _cd_step(rho, lam_n, col_sq[j], j)
         r = r - X[:, j] * (new_tj - th[j])
         return (th.at[j].set(new_tj), r)
 
-    r0 = y - X @ theta
-    th, _ = jax.lax.fori_loop(0, m, body, (theta, r0))
-    return th
+    return lambda theta: jax.lax.fori_loop(0, m, body, (theta, y - X @ theta))[0]
+
+
+def _cd_sweep(X: jnp.ndarray, y: jnp.ndarray, lam):
+    """The sweep of a program over ``(X, y)``: a function ``theta -> theta``
+    that runs one full cyclic coordinate-descent sweep (all features).
+    Ask for it once a program, outside the sweeps' loop: what no sweep
+    changes is computed here.
+
+    The two sweeps give the same iterates, coordinate by coordinate, and
+    share :func:`_cd_step` and nothing else (they want different state:
+    theta alone, theta and a residual). Which one runs is decided by the
+    table's shape, static under ``jit``, and by nothing else
+    (:func:`_cd_path`):
+
+    * ``m <= n`` and ``m <= 2048`` (:data:`_GRAM_MAX_COLUMNS`):
+      :func:`_gram_sweep` (glmnet's "covariance updates", scikit-learn's
+      ``precompute``). A program reads x twice and a sweep costs m^2.
+      The bound on ``m`` is what the build may cost: on a v5e, for an x
+      of 4 GB, a one-sweep fit takes 43 / 76 / 146 / 283 ms at m = 512 /
+      1024 / 2048 / 4096 (it grows with m, the MXU's work) against 86 to
+      108 ms over x itself (chip run, PR 34), so up to 2048 columns the
+      second sweep has paid the build back at the latest (up to 1024, the
+      first), and from 4096 it costs three sweeps and more. At 2048 the
+      matrix is 16 MiB and the compiler keeps it in the chip's fast
+      memory beside the loop (it does so up to 64 MiB, m = 4096, and not
+      at 8192).
+    * otherwise :func:`_residual_sweep`: every sweep reads x. The only
+      path for a wide table (``m > n``), where the Gram matrix is larger
+      than x and costs more than the sweeps it saves.
+    """
+    return _SWEEPS[_cd_path(*X.shape)](X, y, lam)
+
+
+_SWEEPS = {"gram": _gram_sweep, "residual": _residual_sweep}
 
 
 @jax.jit
@@ -99,6 +192,7 @@ def _cd_fit(X: jnp.ndarray, y: jnp.ndarray, theta: jnp.ndarray, lam, tol, max_it
     host fetch, like the device-resident cg/lanczos solvers (the eager
     loop fetched ``diff`` to host every sweep: a device→host sync per
     step). Returns (theta, n_iter)."""
+    sweep = _cd_sweep(X, y, lam)
 
     def cond(carry):
         i, _, diff = carry
@@ -106,7 +200,7 @@ def _cd_fit(X: jnp.ndarray, y: jnp.ndarray, theta: jnp.ndarray, lam, tol, max_it
 
     def body(carry):
         i, th, _ = carry
-        nt = _cd_sweep(X, y, th, lam)
+        nt = sweep(th)
         return (i + 1, nt, jnp.max(jnp.abs(nt - th)))
 
     i, th, _ = jax.lax.while_loop(
@@ -122,6 +216,7 @@ def _cd_block(X, y, theta, lam, tol, budget, diff0):
     exactly the whole-fit sweep sequence. This is the supervised-fit unit
     — the chunk boundary is where a supervisor checkpoints ``theta`` and
     recovers from faults. Returns (theta, sweeps_done, diff)."""
+    sweep = _cd_sweep(X, y, lam)
 
     def cond(carry):
         i, _, diff = carry
@@ -129,7 +224,7 @@ def _cd_block(X, y, theta, lam, tol, budget, diff0):
 
     def body(carry):
         i, th, _ = carry
-        nt = _cd_sweep(X, y, th, lam)
+        nt = sweep(th)
         return (i + 1, nt, jnp.max(jnp.abs(nt - th)))
 
     i, th, diff = jax.lax.while_loop(cond, body, (jnp.int32(0), theta, diff0))
@@ -251,6 +346,8 @@ class Lasso(BaseEstimator, RegressionMixin):
             raise TypeError(f"input needs to be DNDarrays, but were {type(x)}, {type(y)}")
         if x.ndim != 2:
             raise ValueError(f"x needs to be 2D, but was {x.ndim}D")
+        n, m = x.shape
+        _hooks.observe("lasso.path", path=_cd_path(n, m), rows=n, columns=m)
         if supervisor is not None:
             return self._fit_supervised(x, y, supervisor, block_iters)
         X = x._logical().astype(jnp.promote_types(x.larray.dtype, jnp.float32))

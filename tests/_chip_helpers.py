@@ -1,4 +1,7 @@
-"""What the three ``test_chip_compile*.py`` files share: the described chip and how to read a program.
+"""What the ``test_chip_compile*.py`` files share: the described chip and how to read a program.
+
+Four files since PR 34 (the Lasso fit's); ``test_lasso_reference.py`` reads
+a CPU program's loops with the same helpers.
 
 Interpret mode discharges a pallas kernel to plain jax on the CPU, so it
 passes what Mosaic refuses: a scalar store to VMEM, a 64-bit index-map
@@ -127,3 +130,44 @@ def _indexed_ops(text: str, b: int):
         if m and re.search(rf"\[(\d+,)*{b}(,\d+)*\]", m.group(1) + m.group(3)):
             found.append(line.strip()[:160])
     return found
+
+
+def _hlo_computations(text: str):
+    """name -> lines of each computation of a compiled module's text."""
+    computations, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$", line)
+        if m and not line.startswith(" "):
+            name = m.group(1)
+            computations[name] = []
+        elif name is not None:
+            computations[name].append(line)
+    return computations
+
+
+def _reached_from_loops(text: str):
+    """The lines of every computation a ``while`` of the module runs: body, condition, what they call."""
+    computations = _hlo_computations(text)
+    joined = {n: "\n".join(lines) for n, lines in computations.items()}
+    calls = {n: set(re.findall(r"(?:body|condition|to_apply|calls)=%?([\w.\-]+)", body))
+             | {c for group in re.findall(r"branch_computations=\{([^}]*)\}", body) for c in re.findall(r"[\w.\-]+", group)}
+             for n, body in joined.items()}
+    todo = [c for body in joined.values() for line in body.splitlines() if " while(" in line
+            for c in re.findall(r"(?:body|condition)=%?([\w.\-]+)", line)]
+    assert todo, "no while in the module"
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in computations:
+            continue
+        seen.add(c)
+        todo += calls[c]
+    return [line for c in seen for line in computations[c]]
+
+
+_COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "collective-permute", "reduce-scatter", "collective-broadcast")
+
+
+def _collectives(lines):
+    """Those of ``lines`` that are a collective instruction, plain or ``-start``."""
+    return [line.strip()[:120] for line in lines if any(f" {c}(" in line or f" {c}-start(" in line for c in _COLLECTIVES)]
