@@ -18,7 +18,9 @@ The same hook carries the program's spans (:func:`span`,
 ``jax.profiler.TraceAnnotation``, which the profiler writes on the host
 plane on the clock of the device's events while a trace is being taken
 (``ht.utils.profiling.trace``) and which is one object and one flag test
-otherwise. Nothing else stores, exports or switches them.
+otherwise. Inside a compiled program a span is a :func:`phase`: a name
+scope, entered while the program is traced and carried by every operation
+of it from then on. Nothing else stores, exports or switches them.
 """
 from __future__ import annotations
 
@@ -202,11 +204,13 @@ def observe(event: str, **ctx) -> None:
             fn(event, ctx)
 
 
-# spans: the three families a trace of the program shows, named at the
+# spans: the four families a trace of the program shows, named at the
 # layer boundaries (docs/PERFORMANCE.md, "Reading a trace of your own
 # program"): ``ht.call:<public call>`` around an entry point,
 # ``ht.fetch:<site>`` around a device -> host read, ``ht.exchange:<kind>``
-# around the host's part of a data movement. ``_CALL`` is the open public
+# around the host's part of a data movement, on the host plane, and
+# ``ht.phase:<name>`` inside a compiled program, on the device's
+# (:func:`phase`). ``_CALL`` is the open public
 # call of this thread (depth, and the number every span of one request
 # carries); like ``_TRACE_SAFE`` it is per thread, so a serving thread's
 # calls do not renumber a client's.
@@ -257,3 +261,17 @@ def fetch(x, site: str):
     observe("host.fetch", site=site)
     with span("ht.fetch:" + site):
         return _jax.device_get(x)
+
+
+def phase(name: str):
+    """A named part of a compiled program: a context manager for code that
+    runs under ``jit``. It is ``jax.named_scope("ht.phase:<name>")``,
+    entered when the program is traced and never again, so a cached
+    program pays nothing for it; every operation traced inside carries
+    the name in its metadata, and a profiler trace shows it on the
+    device's plane as the operation's ``tf_op``. Nested phases: the
+    innermost names the operation. The scope is no part of the compile
+    cache's key: an executable served from a cache filled before a scope
+    was written or moved still carries the old names. Public as
+    ``ht.utils.profiling.phase``."""
+    return _jax.named_scope("ht.phase:" + name)

@@ -156,11 +156,12 @@ def _carry_sort(leads, cols, stable: bool):
     a v5e, PERF.md §6 PR 25). Returns the sorted leads and cols, in that
     order."""
     ops = [*leads, *cols]
-    out = lax.sort(
-        [o.astype(jnp.int8) if o.dtype == jnp.bool_ else o for o in ops],
-        num_keys=len(leads), is_stable=stable,
-    )
-    return [r.astype(o.dtype) for r, o in zip(out, ops)]
+    with _hooks.phase("sort"):
+        out = lax.sort(
+            [o.astype(jnp.int8) if o.dtype == jnp.bool_ else o for o in ops],
+            num_keys=len(leads), is_stable=stable,
+        )
+        return [r.astype(o.dtype) for r, o in zip(out, ops)]
 
 
 def _sort_by_key(keys, n, payloads):
@@ -231,9 +232,10 @@ def _sample_ranks(n, b: int):
     rows. The product is never formed: at 31 samples times 7e7 rows it
     leaves int32. A shard of fewer keys than samples repeats some; only
     an empty one has none to give (rank 0 is not below ``n``)."""
-    i = lax.iota(jnp.int32, _OVERSAMPLE)
-    ranks = i * (n // _OVERSAMPLE) + (i * (n % _OVERSAMPLE)) // _OVERSAMPLE
-    return jnp.clip(ranks, 0, b - 1)
+    with _hooks.phase("elect"):
+        i = lax.iota(jnp.int32, _OVERSAMPLE)
+        ranks = i * (n // _OVERSAMPLE) + (i * (n % _OVERSAMPLE)) // _OVERSAMPLE
+        return jnp.clip(ranks, 0, b - 1)
 
 
 def _splitters(samples, p: int):
@@ -241,8 +243,9 @@ def _splitters(samples, p: int):
     keys gives the max key 32 times, so an empty shard does not skew the
     splitters downward): one all_gather, sort, take the P-1 quantiles.
     Replicated by construction — every device computes the same values."""
-    gs = jnp.sort(lax.all_gather(samples, SPLIT_AXIS, tiled=True))
-    return gs[(jnp.arange(1, p) * gs.shape[0]) // p]
+    with _hooks.phase("elect"):
+        gs = jnp.sort(lax.all_gather(samples, SPLIT_AXIS, tiled=True))
+        return gs[(jnp.arange(1, p) * gs.shape[0]) // p]
 
 
 # how a row takes in the row ``d`` before it in its run: ``op(own, before)``
@@ -289,13 +292,14 @@ def _scan_runs(sk, n, cols, combiners):
         return s + 1, go, cs
 
     totals = tuple(cols)
-    if steps and totals:
-        totals = lax.while_loop(
-            lambda c: c[1] & (c[0] < len(steps)), body, (jnp.int32(0), jnp.bool_(True), totals)
-        )[2]
-    # what the caller derives from the sorted keys next (run ends, destinations)
-    # waits behind this barrier for the scan, and so is not held alive across it
-    return lax.optimization_barrier((sk, totals))
+    with _hooks.phase("scan"):  # the steps are traced where the loop is
+        if steps and totals:
+            totals = lax.while_loop(
+                lambda c: c[1] & (c[0] < len(steps)), body, (jnp.int32(0), jnp.bool_(True), totals)
+            )[2]
+        # what the caller derives from the sorted keys next (run ends, destinations)
+        # waits behind this barrier for the scan, and so is not held alive across it
+        return lax.optimization_barrier((sk, totals))
 
 
 # columns :func:`_compact_front` moves in one loop: a loop holds its columns twice beside the word
@@ -333,8 +337,9 @@ def _compact_front(keep, cols):
     widths = [1 << s for s in range((b - 1).bit_length())]
     if not widths or not cols:
         return list(cols), jnp.int32(0)
-    d = jnp.where(keep, lax.iota(jnp.int32, b) + 1 - jnp.cumsum(keep.astype(jnp.int32)), 0)
-    steps = 32 - lax.clz(jnp.max(d))
+    with _hooks.phase("compact"):
+        d = jnp.where(keep, lax.iota(jnp.int32, b) + 1 - jnp.cumsum(keep.astype(jnp.int32)), 0)
+        steps = 32 - lax.clz(jnp.max(d))
 
     def ahead(x, w: int):  # row i reads row i + w
         return lax.pad(x, jnp.zeros((), x.dtype), [(-w, w, 0)])
@@ -377,12 +382,13 @@ def _compact_front(keep, cols):
         return noted
 
     first, second = halves(shifted)
-    _, takes = rounds(noting(first, 0), noting(second, 1), (d, jnp.zeros_like(d)))
-    move = halves(lambda w, cs: tuple(jnp.where((takes & w) != 0, ahead(c, w), c) for c in cs))
-    out = []
-    for j in range(0, len(cols), _COMPACT_GROUP):
-        out += rounds(*move, cols[j : j + _COMPACT_GROUP])
-    return out, steps
+    with _hooks.phase("compact"):  # both loops, traced here
+        _, takes = rounds(noting(first, 0), noting(second, 1), (d, jnp.zeros_like(d)))
+        move = halves(lambda w, cs: tuple(jnp.where((takes & w) != 0, ahead(c, w), c) for c in cs))
+        out = []
+        for j in range(0, len(cols), _COMPACT_GROUP):
+            out += rounds(*move, cols[j : j + _COMPACT_GROUP])
+        return out, steps
 
 
 def _fold_runs(sk, n, cols, kinds):
@@ -401,9 +407,10 @@ def _unique_samples(sk, is_end, u):
     the key at each sampled rank is the one at the run end whose running
     count of ends first reaches rank + 1."""
     b = sk.shape[0]
-    idx = _sample_ranks(u, b)
-    pos = jnp.searchsorted(jnp.cumsum(is_end.astype(jnp.int32)), idx + 1, side="left")
-    return jnp.where(idx < u, sk[jnp.clip(pos, 0, b - 1)], jnp.asarray(_max_key(sk.dtype)))
+    with _hooks.phase("elect"):
+        idx = _sample_ranks(u, b)
+        pos = jnp.searchsorted(jnp.cumsum(is_end.astype(jnp.int32)), idx + 1, side="left")
+        return jnp.where(idx < u, sk[jnp.clip(pos, 0, b - 1)], jnp.asarray(_max_key(sk.dtype)))
 
 
 def _dest_matrix(pid, p: int):

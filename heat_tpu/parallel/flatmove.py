@@ -270,17 +270,18 @@ def _ragged_kernel(
         else 1
     )
     unit = outer * inner
-    flat = jnp.moveaxis(x.reshape((outer, b_in, inner)), 1, 0).reshape((b_in * unit,))
-    out_flat = _exchange(
-        flat,
-        axis_name=axis_name,
-        p=p,
-        c_out=b_out * unit,
-        self_edges=self_edges,
-        rounds=rounds,
-    )
-    out = jnp.moveaxis(out_flat.reshape((b_out, outer, inner)), 0, 1)
-    return out.reshape(shape[:split] + (b_out,) + shape[split + 1 :])
+    with _hooks.phase("move"):
+        flat = jnp.moveaxis(x.reshape((outer, b_in, inner)), 1, 0).reshape((b_in * unit,))
+        out_flat = _exchange(
+            flat,
+            axis_name=axis_name,
+            p=p,
+            c_out=b_out * unit,
+            self_edges=self_edges,
+            rounds=rounds,
+        )
+        out = jnp.moveaxis(out_flat.reshape((b_out, outer, inner)), 0, 1)
+        return out.reshape(shape[:split] + (b_out,) + shape[split + 1 :])
 
 
 def reshape_flatmove_executable(

@@ -112,16 +112,18 @@ def _gram_sweep(X: jnp.ndarray, y: jnp.ndarray, lam):
     """
     n, m = X.shape
     lam_n = lam * n
-    col_sq = jnp.sum(X * X, axis=0)
-    q = jnp.sum(X * y[:, None], axis=0)
-    s = jnp.sum(X, axis=0)
-    mean = s / n
-    Z = X - mean[None, :]
-    C = jax.lax.dot_general(
-        Z, Z, (((0,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST, preferred_element_type=X.dtype,
-    )
-    off = jnp.where(jnp.eye(m, dtype=bool), jnp.zeros((), X.dtype), C + s[:, None] * mean[None, :])
+    with _hooks.phase("moments"):
+        col_sq = jnp.sum(X * X, axis=0)
+        q = jnp.sum(X * y[:, None], axis=0)
+        s = jnp.sum(X, axis=0)
+    with _hooks.phase("gram"):
+        mean = s / n
+        Z = X - mean[None, :]
+        C = jax.lax.dot_general(
+            Z, Z, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST, preferred_element_type=X.dtype,
+        )
+        off = jnp.where(jnp.eye(m, dtype=bool), jnp.zeros((), X.dtype), C + s[:, None] * mean[None, :])
 
     def body(j, th):
         # an elementwise product and a sum: a vector dot at default
@@ -138,7 +140,8 @@ def _residual_sweep(X: jnp.ndarray, y: jnp.ndarray, lam):
     instead of a matvec a coordinate."""
     n, m = X.shape
     lam_n = lam * n
-    col_sq = jnp.sum(X * X, axis=0)
+    with _hooks.phase("moments"):
+        col_sq = jnp.sum(X * X, axis=0)
 
     def body(j, carry):
         th, r = carry
@@ -203,9 +206,10 @@ def _cd_fit(X: jnp.ndarray, y: jnp.ndarray, theta: jnp.ndarray, lam, tol, max_it
         nt = sweep(th)
         return (i + 1, nt, jnp.max(jnp.abs(nt - th)))
 
-    i, th, _ = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), theta, jnp.asarray(jnp.inf, theta.dtype))
-    )
+    with _hooks.phase("sweep"):  # the sweeps' loop, and in it each sweep's over the columns
+        i, th, _ = jax.lax.while_loop(
+            cond, body, (jnp.int32(0), theta, jnp.asarray(jnp.inf, theta.dtype))
+        )
     return th, i
 
 
@@ -227,7 +231,8 @@ def _cd_block(X, y, theta, lam, tol, budget, diff0):
         nt = sweep(th)
         return (i + 1, nt, jnp.max(jnp.abs(nt - th)))
 
-    i, th, diff = jax.lax.while_loop(cond, body, (jnp.int32(0), theta, diff0))
+    with _hooks.phase("sweep"):
+        i, th, diff = jax.lax.while_loop(cond, body, (jnp.int32(0), theta, diff0))
     return th, i, diff
 
 

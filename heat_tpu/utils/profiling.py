@@ -17,7 +17,7 @@ import jax
 
 from ..core import _hooks
 
-__all__ = ["trace", "annotate", "force_sync", "Timer", "configure_compile_cache"]
+__all__ = ["trace", "annotate", "phase", "force_sync", "Timer", "configure_compile_cache"]
 
 
 def configure_compile_cache() -> str:
@@ -56,9 +56,9 @@ def force_sync(*arrays) -> None:
 
 
 @contextlib.contextmanager
-def trace(log_dir: str, create_perfetto_link: bool = False):
+def trace(log_dir: str):
     """Capture an XLA device trace viewable in TensorBoard/Perfetto."""
-    jax.profiler.start_trace(log_dir, create_perfetto_link=create_perfetto_link)
+    jax.profiler.start_trace(log_dir)
     try:
         yield
     finally:
@@ -69,6 +69,11 @@ def trace(log_dir: str, create_perfetto_link: bool = False):
 # The library's own spans (``ht.call:*``, ``ht.fetch:*``, ``ht.exchange:*``)
 # are made by the same function.
 annotate = _hooks.span
+
+# a named part of a jitted function, on the device's plane of the capture:
+# ``with phase("solve"):`` around the code as it is traced. The library's
+# own (``ht.phase:sort``, ``ht.phase:scan``, ...) are made by the same function.
+phase = _hooks.phase
 
 
 class Timer:
