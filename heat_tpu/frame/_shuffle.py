@@ -46,7 +46,12 @@ several destinations that interleave is a sort on a small leading key
 (:func:`_partition_front`, :func:`_ends_first`).
 A join matches the same way: both sides sorted together, the right row
 first in each run of equal keys, its values carried along the run
-(:func:`_scan_runs`). That sort is the only key order a join needs, so
+(:func:`_scan_runs`). A sort costs by the operand, so that one carries
+no more than it must: a row is one side's, and both sides' payloads
+share operands width by width (:func:`_join_operands`); the row's place
+is the second key, which makes the order a stable sort's without the
+index operand one would add, and says which side the row is of. That
+sort is the only key order a join needs, so
 the co-partitioning before it makes none: a row partition is one stable
 sort by destination, and rows arrive grouped by destination, in their
 source's own order within one. On a mesh of one device there is nothing
@@ -110,9 +115,15 @@ _PROGRAMS = ExecutableCache(maxsize=128)
 # rounded up by ``_receive_rows``); "compact_steps" is a gauge too: the
 # passes ``_compact_front`` ran in the last join, filter or groupby on the
 # shard that ran most (the bit length of the most rows dropped ahead of a
-# kept one; 0 where nothing was), read from the counts fetched anyway
+# kept one; 0 where nothing was), read from the counts fetched anyway;
+# "join_sort_operands" is a gauge as well: the operands the last join's one
+# sort carried (the key, the row's place, and of each byte width as many
+# payload operands as the side with more columns of it has), known when
+# its program is built; a stable sort's would count one more, the index
+# the compiler adds (PERF.md counts a sort's cost by them)
 SHUFFLE_STATS = {
     "groupbys": 0, "joins": 0, "compactions": 0, "row_shuffles": 0, "bucket_skew": 1.0, "compact_steps": 0,
+    "join_sort_operands": 0,
 }
 
 # how each statistic kind folds in the merge stage (all associative)
@@ -598,6 +609,43 @@ def _partition_executable(
     return fn
 
 
+def _recast(v, dtype):
+    """``v``'s bits under ``dtype``, a type as wide as its own: no value is converted, so a float's
+    NaN keeps its payload and ``-0.0`` its sign. A bool stands as an int8, as in :func:`_carry_sort`."""
+    dtype = jnp.dtype(dtype)
+    if v.dtype == dtype:
+        return v
+    if v.dtype == jnp.bool_:
+        v = v.astype(jnp.int8)
+    if dtype == jnp.bool_:
+        return lax.bitcast_convert_type(v, jnp.int8).astype(jnp.bool_)
+    return lax.bitcast_convert_type(v, dtype)
+
+
+def _join_operands(l_dtypes: Sequence[str], r_dtypes: Sequence[str]):
+    """The payload operands of the join's sort, each ``(left payload, right payload, dtype)`` by
+    index, ``None`` where a side has none in it. A row is one side's: where it holds the other
+    side's column nothing is read. So a left payload and a right payload of one byte width stand
+    in ONE operand, the right block's rows first: under their own dtype where a partner of it is
+    left (first come, first paired), else under the unsigned integer of their width (a bitcast
+    each way: 1.2 ms a column of 1e8 rows on a v5e). A width one side has more columns of keeps
+    the surplus in operands of their own: ``max(left, right)`` operands of each width in all,
+    where each side's columns had operands of their own (a sort costs by the operand: 0.14 s each
+    at 1e8 rows, PERF.md §6 PR 36)."""
+    left, right = [jnp.dtype(d) for d in l_dtypes], [jnp.dtype(d) for d in r_dtypes]
+    partner, free = {}, list(range(len(right)))
+    for fits in (lambda a, b: a == b, lambda a, b: a.itemsize == b.itemsize):  # one dtype first, then one width
+        for i in (i for i in range(len(left)) if i not in partner):
+            j = next((j for j in free if fits(left[i], right[j])), None)
+            if j is not None:
+                partner[i] = j
+                free.remove(j)
+    shared = [
+        (i, j, left[i] if left[i] == right[j] else jnp.dtype(f"uint{8 * left[i].itemsize}")) for i, j in partner.items()
+    ]
+    return [*shared, *((i, None, left[i]) for i in range(len(left)) if i not in partner), *((None, j, right[j]) for j in free)]
+
+
 def _join_executable(
     l_pshape: Tuple[int, ...],
     r_pshape: Tuple[int, ...],
@@ -609,18 +657,20 @@ def _join_executable(
     comm: MeshCommunication,
 ):
     """Device-local merge join of two co-partitioned, exchanged sides,
-    neither of them in any order. One stable sort by key of the right
-    block with the left block behind it, each side's columns carried
-    (zeros in the other side's rows): within a run of equal keys the
-    right row, which stood earlier, comes first, then the left rows in
-    their own order. :func:`_scan_runs` carries that first row's values,
-    and whether it was a right row at all (``hit``), along the run;
+    neither of them in any order. One sort of the right block with the
+    left block behind it, by the key and then by where a row stood (the
+    order a stable sort by the key gives, its index ours and not one more
+    operand the compiler adds), both sides' payloads sharing operands
+    width by width (:func:`_join_operands`): within a run of equal keys
+    the right row, which stood earlier, comes first, then the left rows
+    in their own order, and a row's side is read off where it stood.
+    :func:`_scan_runs` carries that first row's values, and whether it
+    was a right row at all (``hit``), along the run;
     :func:`_compact_front` shifts the left rows to keep to the front: the
-    matched (inner), or all of them, what found no match NaN (left: a
-    left row starts out with it). One sort; no search, no index, no
-    lookup. The result's block is as long as both blocks together: cut
-    back to the left block's length, every column of it would be one
-    more copy."""
+    matched (inner), or all of them, what found no match NaN (left). One
+    sort; no search, no index, no lookup. The result's block is as long
+    as both blocks together: cut back to the left block's length, every
+    column of it would be one more copy."""
     mesh = comm.mesh
     key = ("join", l_pshape, r_pshape, str(key_dtype), l_dtypes, r_dtypes, how, p, mesh)
     fn = _PROGRAMS.get(key)
@@ -628,47 +678,58 @@ def _join_executable(
         return fn
     bl = l_pshape[0] // p
     br = r_pshape[0] // p
-    pad, right, left = (jnp.int8(t) for t in range(3))
+    if how == "left":  # the right columns become floats for the NaN of a row without a match
+        r_dtypes = tuple(str(jnp.promote_types(d, jnp.float32)) for d in r_dtypes)
+    operands = _join_operands(l_dtypes, r_dtypes)
 
     def frame_join(lk, lcnt, *rest):
         rk, rcnt = rest[len(l_dtypes)], rest[len(l_dtypes) + 1]
         lvals = list(rest[: len(l_dtypes)])
-        rvals = list(rest[len(l_dtypes) + 2 :])
+        rvals = [v.astype(d) for v, d in zip(rest[len(l_dtypes) + 2 :], r_dtypes)]
         r = lax.axis_index(SPLIT_AXIS)
         nl, nr = lcnt[r], rcnt[r]
         i = lax.iota(jnp.int32, br + bl)
-        side = jnp.where(i < nr, right, jnp.where((i >= br) & (i < br + nl), left, pad))
         # a pad gets the key nothing sorts after, as in _sort_by_key; it may end up inside
-        # the run of a valid row with that very key, where its side tells it apart
-        k = jnp.where(side == pad, jnp.asarray(_last_key(lk.dtype)), jnp.concatenate([rk, lk]))
-        lcols = [jnp.concatenate([jnp.zeros((br,), v.dtype), v]) for v in lvals]
-        # What a left row without a match comes out with, it starts out with, and the scan hands it
-        # on: NaN (left; the right columns become floats for it), nothing anyone reads (inner).
-        # The right block's pads hold it too: they stand first in the run of a left row whose key
-        # nothing sorts after. No pass fills anything afterwards: its results were new buffers that
-        # stood beside the compaction's, 4.8 columns at question 2's widths (sandbox compile, PR 32)
-        null = jnp.nan if how == "left" else 0
-        if how == "left":
-            rvals = [v.astype(jnp.promote_types(v.dtype, jnp.float32)) for v in rvals]
-        in_right = lax.iota(jnp.int32, br) < nr
-        rcols = [
-            jnp.concatenate([jnp.where(in_right, v, jnp.asarray(null, v.dtype)), jnp.full((bl,), null, v.dtype)])
-            for v in rvals
+        # the run of a valid row with that very key, where its place tells it apart
+        valid = (i < nr) | ((i >= br) & (i < br + nl))
+        k = jnp.where(valid, jnp.concatenate([rk, lk]), jnp.asarray(_last_key(lk.dtype)))
+        cols = [
+            jnp.concatenate([
+                jnp.zeros((br,), d) if jr is None else _recast(rvals[jr], d),
+                jnp.zeros((bl,), d) if jl is None else _recast(lvals[jl], d),
+            ])
+            for jl, jr, d in operands
         ]
-        # the side is a payload, not a second key: one comparison a row
-        sk, side, *cols = _carry_sort([k], [side, *lcols, *rcols], stable=True)
-        slv, srv = cols[: len(lvals)], cols[len(lvals) :]
-        is_right = side == right
+        # (key, place) orders the rows fully: no stability is asked for, which spares the index
+        # operand a stable sort carries beside ours, and no side tag rides along
+        sk, si, *cols = _carry_sort([k, i], cols, stable=False)
+        # both sides' flags are made here, behind a barrier, and the place dies with it: left to
+        # the compiler, ``keep`` reads the place after the scan and a whole column stays alive
+        # across it where two bits a row do (0.75 of a column at question 2's widths, 1.44 over
+        # four chips at question 5's: sandbox compile, PR 36)
+        is_right, is_left = lax.optimization_barrier((si < nr, (si >= br) & (si < br + nl)))
+        slv, srv = [None] * len(l_dtypes), [None] * len(r_dtypes)
+        for (jl, jr, _), c in zip(operands, cols):
+            if jl is not None:  # what stands in the right rows of it is dropped, unread
+                slv[jl] = _recast(c, l_dtypes[jl])
+            if jr is not None:
+                srv[jr] = _recast(c, r_dtypes[jr])
+        if how == "left":
+            # What a left row without a match comes out with, the scan hands it from the first row
+            # of its run: itself or another left row or, under the key nothing sorts after, a pad of
+            # the right block. So all but the right rows hold NaN before the scan; an inner join
+            # keeps no such row and reads none of it
+            srv = [jnp.where(is_right, v, jnp.asarray(jnp.nan, v.dtype)) for v in srv]
         # duplicate right keys would silently multiply rows in a merge
         # join — detect and report (replicated via max over shards)
         dup_local = jnp.any(is_right[1:] & is_right[:-1] & (sk[1:] == sk[:-1]))
         dup = lax.pmax(dup_local.astype(jnp.int32), SPLIT_AXIS)
         sk, (hit, *srv) = _scan_runs(sk, br + bl, [is_right, *srv], ["first"] * (1 + len(srv)))
         if how == "inner":
-            keep = (side == left) & hit
+            keep = is_left & hit
             g = jnp.sum(keep.astype(jnp.int32))
         else:  # left: all valid left rows
-            keep, g = side == left, nl
+            keep, g = is_left, nl
         outs, steps = _compact_front(keep, [sk, *slv, *srv])
         return (*outs, _counts_and_steps(g, steps), dup)
 
@@ -681,6 +742,7 @@ def _join_executable(
     )
     prog = shard_map(frame_join, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     fn = _PROGRAMS[key] = jax.jit(prog)
+    fn.sort_operands = 2 + len(operands)  # the key, the place, the payloads' (``hash_join``'s gauge)
     return fn
 
 
@@ -897,6 +959,7 @@ def hash_join(
     dup = int(_hooks.fetch(out[-1], "shuffle.join_dup"))
     gvec = _read_counts(out[-2], "shuffle.join_counts")
     SHUFFLE_STATS["joins"] += 1
+    SHUFFLE_STATS["join_sort_operands"] = join.sort_operands
     return list(out[:-2]), gvec, dup
 
 

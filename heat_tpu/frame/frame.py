@@ -168,10 +168,11 @@ class Frame:
         splitter election and each side pays one bounded exchange per
         operand; on a mesh of one device equal keys are together already
         and nothing is elected, partitioned or moved. Then a device-local
-        merge join matches rows: one stable sort of both sides together,
-        each right row's values carried along the left rows with its
-        key. ``how="left"`` NaN-fills unmatched right values (right
-        columns promote to float: float32 unless they are wider).
+        merge join matches rows: one sort of both sides together (by the
+        key, then by where a row stood: what a stable sort gives), each
+        right row's values carried along the left rows with its key.
+        ``how="left"`` NaN-fills unmatched right values (right columns
+        promote to float: float32 unless they are wider).
 
         Columns of the result: the key, this frame's others in its
         order, ``other``'s others in its order (``rsuffix`` appended to

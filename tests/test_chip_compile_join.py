@@ -6,40 +6,46 @@ compile here shows and what it does not.
 """
 from __future__ import annotations
 
+import re
+
 import pytest
 
 from ._chip_helpers import _frame_mesh, _indexed_ops, _one_chip, _spec, four_chips, topo  # noqa: F401 - fixtures
 
 # --- the join's match program (``_shuffle._join_executable``) on an int32 key, the right table a
 # thousandth of the left one's rows as in h2o.ai db-benchmark's join question 2 (PERF.md §4,
-# `join-q2-medium-inner`). It sorts the right block with the left block behind it (key, side, every
-# payload of both sides, the index stability costs), carries each run's first row forward and
-# shifts the left rows to keep to the front in elementwise passes (``_compact_front``, PR 32; a
-# second sort of as many operands before): ONE sort, no search, no lookup, so no gather and no
-# scatter over any of the three block lengths involved. The result's block is as long as both
-# sides' blocks together. The compaction holds, beside the columns it moves, the word that steers
-# it and two columns more at a time (a loop's columns stand twice), whatever the table's width.
+# `join-q2-medium-inner`). It sorts the right block with the left block behind it by (key, place):
+# the order a stable sort by the key gives, with no operand added for it and no side tag; both sides'
+# payloads share operands width by width (PR 36: the key, the place and ``max(left, right)`` payload
+# operands of each width; before, the key, a side tag, every payload of both sides and the index
+# stability costs). It carries each run's first row forward and shifts the left rows to keep to the
+# front in elementwise passes (``_compact_front``, PR 32; a second sort of as many operands before):
+# ONE sort, no search, no lookup, so no gather and no scatter over any of the three block lengths
+# involved. The result's block is as long as both sides' blocks together. The compaction holds,
+# beside the columns it moves, the word that steers it and two columns more at a time (a loop's
+# columns stand twice), whatever the table's width.
 #
 # A sort's compile time follows its operand count, not its rows, and what these tests ask does not
 # follow it: the structure (one sort, nothing indexed, the collectives, a chip's arguments being
 # its share) is the same with one payload a side as with six and four, and the memory bounds are
 # stated in columns of the left table. So tier-1 compiles
-# ``one_payload`` (one int32 payload left, one f32 right: a sort of 5 operands, the groupby's price)
-# for both layouts, and a program that began to hold a second copy of its columns would show there
-# as it would at question 2's widths. ``question_2`` (five int32 and one f32 payload left, three
-# int32 and one f32 right: a sort of 13 operands) is the cell's own program, on one chip only and
-# marked ``slow``: 352-373 s alone on the sandbox and 463 s beside a full run with the two sorts it
-# had (PR 29), 53-70 % of the bound conftest gives a test; about 500-830 s beside five other
-# compiles now (PR 32). Whoever changes ``_join_executable``, ``_carry_sort``, ``_scan_runs`` or
-# ``_compact_front`` runs it:
+# ``one_payload`` (one int32 payload left, one f32 right, in one operand as uint32: a sort of 3
+# operands, 5 before PR 36) for both layouts, and a program that began to hold a second copy of its
+# columns would show there as it would at question 2's widths. ``question_2`` (five int32 and one f32
+# payload left, three int32 and one f32 right: a sort of 8 operands, 13 before PR 36) is the cell's own
+# program, on one chip only and marked ``slow``: 229-230 s on the sandbox, 23.56 columns held
+# (PR 36; 352-373 s alone with 13 operands, 463 s beside a full run with the two sorts it had, PR 29).
+# Whoever changes ``_join_executable``, ``_carry_sort``, ``_scan_runs`` or ``_compact_front`` runs it:
 #     pytest -m slow tests/test_chip_compile_join.py
 #
 # ``question_5`` (PR 31) is the four-chip cell's own program (PERF.md §4, `join-q5-big-inner-4chip`:
 # h2o.ai's ``big inner on int``), over four chips only, where the cell runs: the right table as
-# long as the left, five int32 and one f32 payload a side, a sort of 15 operands, the result's block
-# twice the left one's. Marked ``slow`` as question 2's is, and for the same reason, and so is
-# the partition program that feeds it (one stable sort of 9 operands by destination), a test of
-# its own so that neither compile runs into the bound conftest gives a test.
+# long as the left, five int32 and one f32 payload a side, a sort of 8 operands (15 before PR 36:
+# every payload operand was half empty), the result's block twice the left one's: 195-204 s, 14.61
+# columns of temporaries (PR 36; 602 s with 15 operands). Marked ``slow`` as question 2's is, and so is
+# the partition program that feeds it (one stable sort of 9 operands by destination: 240-249 s, 2.00
+# columns), a test of its own so that neither compile runs into the bound conftest gives a test. The
+# three ``slow`` cases ran in 670 and 691 s together (PR 36; 1 393 s before).
 #
 # The rows are the cells' own, nearly (PR 32; 2^20 a chip before): 2^26 on one chip, the power of
 # two under question 2's 1e8 (the plan of the groupby compiles in 110 s at 2^26 and 147 s at 1e8,
@@ -50,23 +56,26 @@ from ._chip_helpers import _frame_mesh, _indexed_ops, _one_chip, _spec, four_chi
 # rows and 5.54 at 1e8; with the two sorts it had before, 3.84 and 7.27).
 _ROWS = {1: 1 << 26, 4: 25_165_824}  # chips -> rows a chip; every column here is 32 bits wide
 
-# widths -> (left payloads, right payloads, left rows to a right row, most temporaries over four
-# chips, most held on one chip), the last two in columns of the left table, each pinned over the
-# sandbox's compile (PR 32, one chip's at 1e8 rows; the same programs with the compaction's sort,
-# PR 31's tree at the same rows, in brackets): one payload 4.06 [2.77] over four chips and 8.77 [7.51] held on one (2 + 3 +
-# 3.77 [2.51] of temporaries: the compaction's word, its two columns twice); question 2 23.56
-# [25.28] held (7 + 11.01 + 5.54 [7.27]); question 5 14.67 [15.07] over four chips, of blocks of 0.1 GB,
-# beside 14 of arguments and 26 of outputs (no one chip holds it). A narrow table pays for the
-# compaction's own columns, 1.3 more; at the cells' widths they stand where the second sort's stood
+# widths -> (left payloads, right payloads, left rows to a right row, the sort's operands, most
+# temporaries over four chips, most held on one chip), the last two in columns of the left table, each
+# pinned over the sandbox's compile (PR 36; the same shapes with every payload in an operand of its
+# own, PR 32's tree, in brackets): one payload 4.04 [4.05] over four chips and 8.77 [8.52] held on one
+# (2 + 3 + 3.77 of temporaries: the compaction's word, its two columns twice); question 2 23.56 [23.56]
+# held (7 + 11.01 + 5.54: the compaction sets the high-water mark, not the sort); question 5 14.61
+# [14.67] over four chips, of blocks of 0.1 GB, beside 14 of arguments and 26 of outputs (no one chip
+# holds it). No bound moved with PR 36. The flags that say which side a sorted row is of stand behind
+# a barrier in ``frame_join``: without it the sorted place, a whole column, stayed alive across the scan
+# for the compaction's ``keep`` and question 2 held 24.31, question 5 16.11 of temporaries (PR 36)
 _Q5_PAYLOADS = ("int32",) * 5 + ("float32",)
 _WIDTHS = {
-    "one_payload": (("int32",), ("float32",), 1024, 4.5, 9.1),
-    "question_2": (("int32",) * 5 + ("float32",), ("int32",) * 3 + ("float32",), 1024, None, 24.2),
-    "question_5": (_Q5_PAYLOADS, _Q5_PAYLOADS, 1, 15.5, None),
+    "one_payload": (("int32",), ("float32",), 1024, 3, 4.5, 9.1),
+    "question_2": (("int32",) * 5 + ("float32",), ("int32",) * 3 + ("float32",), 1024, 8, None, 24.2),
+    "question_5": (_Q5_PAYLOADS, _Q5_PAYLOADS, 1, 8, 15.5, None),
 }
 
 
 def _compiled_join(mesh, p: int, left, right, ratio: int):
+    """(compiled program, the operands ``hash_join`` would count for it)."""
     import jax.numpy as jnp
 
     from heat_tpu.frame import _shuffle
@@ -77,13 +86,17 @@ def _compiled_join(mesh, p: int, left, right, ratio: int):
     return fn.lower(
         _spec(lshape, jnp.int32, rows), _spec((p,), jnp.int32, rep), *[_spec(lshape, jnp.dtype(d), rows) for d in left],
         _spec(rshape, jnp.int32, rows), _spec((p,), jnp.int32, rep), *[_spec(rshape, jnp.dtype(d), rows) for d in right],
-    ).compile()
+    ).compile(), fn.sort_operands
 
 
-def _join_matches_without_an_index(text: str, rows: int, ratio: int):
+def _join_matches_without_an_index(text: str, rows: int, ratio: int, operands: int, counted: int):
     for block in (rows + rows // ratio, rows, rows // ratio):
         assert _indexed_ops(text, block) == [], block
-    assert text.count(" sort(") == 1  # both sides together by key; the compaction is no sort
+    (sort,) = [line for line in text.splitlines() if " sort(" in line]  # both sides together; the compaction is no sort
+    # what the sort carries is what it was handed: the compiler added no index (the sort is not
+    # stable, (key, place) orders the rows fully), and the gauge counts the same
+    assert "is_stable=true" not in sort
+    assert len(re.findall(r"%[\w.\-]+", sort.split(" sort(")[1].split("), dimensions=")[0])) == operands == counted, sort[:400]
 
 
 @pytest.mark.parametrize("widths", [pytest.param("question_5", marks=pytest.mark.slow)])
@@ -115,10 +128,10 @@ def test_partition_program_sorts_once_over_four_chips(four_chips, widths):
 
 @pytest.mark.parametrize("widths", ["one_payload", pytest.param("question_5", marks=pytest.mark.slow)])
 def test_join_program_compiles_over_four_chips(four_chips, widths):
-    left, right, ratio, most_temp, _ = _WIDTHS[widths]
-    compiled = _compiled_join(four_chips, 4, left, right, ratio)
+    left, right, ratio, operands, most_temp, _ = _WIDTHS[widths]
+    compiled, counted = _compiled_join(four_chips, 4, left, right, ratio)
     text, column = compiled.as_text(), 4 * _ROWS[4]
-    _join_matches_without_an_index(text, _ROWS[4], ratio)
+    _join_matches_without_an_index(text, _ROWS[4], ratio, operands, counted)
     assert "all-gather" in text or "all-reduce" in text  # the row counts and the duplicate flag, a few words
     mem = compiled.memory_analysis()
     # a chip's arguments are its quarter: the left table's columns, the right one's as much shorter as the table
@@ -135,9 +148,9 @@ def test_join_program_fits_one_chip(topo, widths):
     """The cell's layout. Everything the program holds at once, in columns of the left table: its
     arguments, its outputs and its temporaries (7 + 11.01 + 5.54 at question 2's widths: a column is
     0.4 GB and the two tables stand beside the program)."""
-    left, right, ratio, _, most_held = _WIDTHS[widths]
-    compiled = _compiled_join(_one_chip(topo), 1, left, right, ratio)
-    _join_matches_without_an_index(compiled.as_text(), _ROWS[1], ratio)
+    left, right, ratio, operands, _, most_held = _WIDTHS[widths]
+    compiled, counted = _compiled_join(_one_chip(topo), 1, left, right, ratio)
+    _join_matches_without_an_index(compiled.as_text(), _ROWS[1], ratio, operands, counted)
     mem, column = compiled.memory_analysis(), 4 * _ROWS[1]
     held = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes - mem.alias_size_in_bytes
     assert held < most_held * column, held / column

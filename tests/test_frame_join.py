@@ -345,6 +345,73 @@ class TestJoinAgainstReference:
                 np.testing.assert_array_equal(np.asarray(f[c].numpy()), table[c], err_msg=c)
 
 
+# --- what the merged sort is handed (PR 36): a row is one side's, so a left payload and a right payload
+# of one byte width ride in one operand (columns of one type pair first; the rest under the unsigned
+# integer of their width), and a row's side is read off where it stood (the sort's second key, no tag).
+# Each case is (left payload dtypes, right payload dtypes); the key is an int32. Floats carry NaNs of two
+# bit patterns, ``-0.0``, an infinity and a denormal: nothing converts a value, so every column comes
+# out bit for bit (a left join's right columns as floats, as the docs say).
+_PAYLOAD_CASES = {
+    "every_width_pairs_across_types": (("int32", "int32", "bool", "bfloat16"), ("float32", "float32", "int8", "int16")),
+    "and_the_other_way_round": (("float32", "int8", "int16", "float32"), ("int32", "bool", "bfloat16", "float32")),
+    "more_left_than_right": (("float32", "int32", "float32", "int16"), ("float32",)),
+    "more_right_than_left": (("int8",), ("bool", "int8", "float32", "float32")),
+    "no_left_payload": ((), ("float32", "int16")),
+    "no_right_payload": (("float32", "bool"), ()),
+    "widths_that_do_not_pair": (("int8",), ("float32", "float32")),
+}
+_FLOAT_BITS = np.array([0x7FC00001, 0xFFC12345, 0x80000000, 0x00000001, 0xFF800000], np.uint32)
+
+
+def _payload_column(rng, dtype: str, n: int) -> np.ndarray:
+    import ml_dtypes
+
+    if dtype == "bool":
+        return rng.random(n) < 0.5
+    if dtype == "float32":
+        col = rng.normal(size=n).astype(np.float32)
+        col[rng.permutation(n)[: n // 3]] = rng.choice(_FLOAT_BITS, n // 3).view(np.float32)
+        return col
+    if dtype == "bfloat16":
+        col = rng.normal(size=n).astype(ml_dtypes.bfloat16)
+        col[rng.permutation(n)[: n // 4]] = -0.0
+        return col
+    info = np.iinfo(dtype)
+    return rng.integers(info.min, info.max, n, dtype=np.int64, endpoint=True).astype(dtype)
+
+
+def _bits(col: np.ndarray) -> np.ndarray:
+    return np.ascontiguousarray(col).view(f"uint{8 * col.dtype.itemsize}")
+
+
+class TestTheSortsOperands:
+    @pytest.mark.parametrize("devices", [1, 4, 8])
+    @pytest.mark.parametrize("how", ["inner", "left"])
+    @pytest.mark.parametrize("case", sorted(_PAYLOAD_CASES))
+    def test_both_sides_share_operands_and_no_bit_changes(self, case, how, devices):
+        from heat_tpu.frame import SHUFFLE_STATS
+        from heat_tpu.frame.reference import join_m1
+
+        comm = _mesh_of(devices)
+        rng = np.random.default_rng([36, devices])
+        ldt, rdt = _PAYLOAD_CASES[case]
+        x = {"k": rng.integers(0, 60, _MERGE_ROWS).astype(np.int32)}
+        y = {"k": rng.permutation(80)[:_MERGE_RIGHT].astype(np.int32)}
+        x.update({f"l{j}": _payload_column(rng, d, _MERGE_ROWS) for j, d in enumerate(ldt)})
+        y.update({f"r{j}": _payload_column(rng, d, _MERGE_RIGHT) for j, d in enumerate(rdt)})
+        want = join_m1(x, y, on="k", how=how)
+        assert 0 < len(want["k"]) and (how == "inner") == (len(want["k"]) < _MERGE_ROWS)  # some rows match, some do not
+        out = _frame_on(x, comm).join(_frame_on(y, comm), on="k", how=how)
+        got = out.to_dict()
+        assert out.columns == tuple(want)
+        for name, col in want.items():
+            assert got[name].dtype == col.dtype, name
+            np.testing.assert_array_equal(_bits(got[name]), _bits(col), err_msg=name)
+        # the key, the row's place, and of each width as many operands as the side with more columns of it
+        widths = [[col.dtype.itemsize for name, col in want.items() if name.startswith(side)] for side in "lr"]
+        assert SHUFFLE_STATS["join_sort_operands"] == 2 + sum(max(w.count(b) for w in widths) for b in {*widths[0], *widths[1]})
+
+
 # --- what the co-partitioning does and does not do (PR 30). The merge sorts both sides by key
 # itself, so the partition makes no key order; and on a mesh of one device there is nothing to
 # bring together: the join program reads the callers' buffers under their own counts.
