@@ -3,20 +3,25 @@
 Pipeline identical to the reference (``spectral.py:103``): similarity
 Laplacian -> Lanczos tridiagonalization (distributed matvecs) -> local
 eigendecomposition of the small T -> back-projected eigenvectors ->
-KMeans on the spectral embedding.
+KMeans on the spectral embedding. Three compiled programs, one a phase:
+``ht.phase:laplacian`` (the similarity and L, written once),
+``ht.phase:lanczos`` (every read of L) and ``ht.phase:ritz`` (``eigh`` of
+T and the back-projection), all at float32 precision.
 """
 from __future__ import annotations
 
-from typing import Optional, Union
+from functools import partial
+from typing import Optional
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
-from ..core import types
+from ..core import _hooks
 from ..core.base import BaseEstimator, ClusteringMixin
 from ..core.dndarray import DNDarray
-from ..core.linalg import lanczos, matmul
-from ..graph.laplacian import Laplacian
-from ..spatial import distance as ht_distance
+from ..core.linalg.solver import _lanczos_arrays
+from ..graph.laplacian import Laplacian, PairwiseSimilarity
 from .kmeans import KMeans
 
 __all__ = ["Spectral"]
@@ -28,6 +33,14 @@ class Spectral(BaseEstimator, ClusteringMixin):
     Parameters follow the reference: gamma (rbf width), metric, laplacian
     mode, threshold/boundary for eNeighbour graphs, n_lanczos iterations,
     assign_labels (only 'kmeans').
+
+    The embedding is clustered by KMeans (``init="probability_based"``, 30
+    iterations and no tolerance test unless ``params`` say otherwise).
+
+    Fitted attributes: ``labels_``; ``ritz_values_``, the (m,) Ritz values
+    of the last fit's Laplacian in ascending order (m = min(n_lanczos,
+    n)), and ``embedding_``, its (n, k) spectral embedding: the Ritz
+    vectors of the k smallest, k = max(n_clusters, 2), each up to sign.
     """
 
     def __init__(
@@ -51,15 +64,8 @@ class Spectral(BaseEstimator, ClusteringMixin):
         self.n_lanczos = n_lanczos
         self.assign_labels = assign_labels
 
-        if metric == "rbf":
-            sigma = (1.0 / (2.0 * gamma)) ** 0.5
-            sim = lambda x: ht_distance.rbf(x, sigma=sigma)
-        elif metric == "euclidean":
-            sim = lambda x: ht_distance.cdist(x)
-        else:
-            raise NotImplementedError(f"Metric {metric} not supported")
         self._laplacian = Laplacian(
-            similarity=sim,
+            similarity=PairwiseSimilarity(metric, gamma),
             definition="norm_sym",
             mode=laplacian,
             threshold_key=boundary,
@@ -67,53 +73,67 @@ class Spectral(BaseEstimator, ClusteringMixin):
         )
         if assign_labels != "kmeans":
             raise NotImplementedError(f"assign_labels {assign_labels} not supported")
-        self._cluster = KMeans(n_clusters=n_clusters or 8, init="probability_based", **params)
+        # as upstream builds it: a fixed 30 Lloyd iterations, no tolerance test. The embedding's
+        # entries are about 1/sqrt(n), so KMeans' absolute tol (1e-4 on the centres' shift) would
+        # stop at the first assignment at n = 40 000, a wrong one where both starts share a class
+        kmeans = {"n_clusters": n_clusters or 8, "init": "probability_based", "max_iter": 30, "tol": None}
+        self._cluster = KMeans(**{**kmeans, **params})
         self._labels = None
+        self.ritz_values_ = None
+        self.embedding_ = None
 
     @property
     def labels_(self) -> DNDarray:
         return self._labels
 
-    def _spectral_embedding(self, x: DNDarray):
-        """Laplacian eigenvectors via Lanczos (reference ``spectral.py:103``)."""
+    def _spectral_embedding(self, x: DNDarray, k: Optional[int]):
+        """Ritz values and the Ritz vectors of the k smallest (all m where k
+        is None) of the Laplacian of ``x`` (reference ``spectral.py:103``)."""
         L = self._laplacian.construct(x)
         m = min(self.n_lanczos, L.shape[0])
-        V, T = lanczos(L, m)
-        # local eigendecomposition of the tridiagonal T
-        evals, evecs = jnp.linalg.eigh(T._logical())
-        # back-project onto the Lanczos basis
-        full = V._logical() @ evecs
-        return (
-            DNDarray(evals, split=None, device=x.device, comm=x.comm),
-            DNDarray(full, split=None, device=x.device, comm=x.comm),
-        )
+        with jax.default_matmul_precision("highest"):
+            V, T, n = _lanczos_arrays(L, m)
+            evals, vectors = _ritz(V, T, m if k is None else k, n)
+        return evals, vectors
 
+    def _embedding(self, vectors: jnp.ndarray, x: DNDarray) -> DNDarray:
+        return DNDarray(vectors, split=x.split, device=x.device, comm=x.comm)
+
+    @_hooks.public_call("Spectral.fit")
     def fit(self, x: DNDarray) -> "Spectral":
         """reference ``spectral.py``"""
         if not isinstance(x, DNDarray):
             raise TypeError(f"input needs to be a DNDarray, but was {type(x)}")
-        eigenvalues, eigenvectors = self._spectral_embedding(x)
-        if self.n_clusters is None:
+        k = None if self.n_clusters is None else max(self.n_clusters, 2)
+        evals, vectors = self._spectral_embedding(x, k)
+        if k is None:
             # eigengap heuristic on sorted eigenvalues
-            ev = eigenvalues._logical()
-            diffs = jnp.diff(ev[: min(len(ev), 20)])
-            self.n_clusters = int(jnp.argmax(diffs)) + 1
+            ev = _hooks.fetch(evals, "spectral.eigengap")
+            self.n_clusters = int(np.argmax(np.diff(ev[: min(len(ev), 20)]))) + 1
             self._cluster.n_clusters = max(self.n_clusters, 2)
-        k = max(self.n_clusters, 2)
-        components = eigenvectors._logical()[:, :k]
-        embedding = DNDarray(components, split=x.split, device=x.device, comm=x.comm)
-        self._cluster.fit(embedding)
+            k = max(self.n_clusters, 2)
+            vectors = vectors[:, :k]
+        self.ritz_values_ = DNDarray(evals, split=None, device=x.device, comm=x.comm)
+        self.embedding_ = self._embedding(vectors, x)
+        self._cluster.fit(self.embedding_)
         self._labels = self._cluster.labels_
         return self
 
+    @_hooks.public_call("Spectral.predict")
     def predict(self, x: DNDarray) -> DNDarray:
         """Recompute the spectral embedding of ``x`` and predict with the
         fitted KMeans (reference ``spectral.py:190-215``)."""
         if self._labels is None:
             raise RuntimeError("fit needs to be called before predict")
-        _, eigenvectors = self._spectral_embedding(x)
-        k = max(self.n_clusters, 2)
-        embedding = DNDarray(
-            eigenvectors._logical()[:, :k], split=x.split, device=x.device, comm=x.comm
-        )
-        return self._cluster.predict(embedding)
+        _, vectors = self._spectral_embedding(x, max(self.n_clusters, 2))
+        return self._cluster.predict(self._embedding(vectors, x))
+
+
+@partial(jax.jit, static_argnames=("k", "n"))
+def _ritz(V, T, k: int, n: int):
+    """Ritz values of T in ascending order, and the Ritz vectors of the k
+    smallest back-projected onto the Lanczos basis: ``V^T evecs[:, :k]``
+    over the n logical columns of V."""
+    with _hooks.phase("ritz"):
+        evals, evecs = jnp.linalg.eigh(T)
+        return evals, V[:, :n].T @ evecs[:, :k]

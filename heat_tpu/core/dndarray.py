@@ -56,8 +56,10 @@ __all__ = ["DNDarray", "LAYOUT_STATS"]
 
 # Running count of ragged→canonical rebalances actually performed by
 # ``balance_`` (no-op calls are not counted). Tests hook this to assert
-# that hot compute paths never force the rebalance round-trip.
-LAYOUT_STATS = {"rebalances": 0}
+# that hot compute paths never force the rebalance round-trip. Beside it
+# the gauge "lanczos_steps": the matvecs of its operand the last
+# ``linalg.lanczos`` ran (``linalg/solver.py``).
+LAYOUT_STATS = {"rebalances": 0, "lanczos_steps": 0}
 
 
 class LocalIndex:

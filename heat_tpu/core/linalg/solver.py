@@ -15,14 +15,15 @@ is needed.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from .. import factories
+from .. import _hooks
 from .._operations import _mask_padding
-from ..dndarray import DNDarray
+from ..dndarray import LAYOUT_STATS, DNDarray
 
 __all__ = ["cg", "lanczos"]
 
@@ -111,12 +112,28 @@ def lanczos(
     """Lanczos tridiagonalization of a symmetric matrix (reference
     ``solver.py:68``). Returns (V, T) with A ~= V T V^T.
 
-    One compiled ``lax.fori_loop`` over the m steps — O(1) dispatches where
-    the reference paid a collective round-trip per step. Full
-    re-orthogonalization is applied every step (the reference
-    re-orthogonalizes conditionally); the extra matvec is cheap on the MXU
-    and buys numerical stability.
+    One compiled program (:func:`_lanczos_program`) for all m steps — O(1)
+    dispatches where the reference paid a collective round-trip per step.
+    Full re-orthogonalization is applied every step (the reference
+    re-orthogonalizes conditionally); it reads the m Lanczos vectors, not
+    A, and buys numerical stability. The m matvecs of A the call ran are
+    the gauge ``LAYOUT_STATS["lanczos_steps"]``.
     """
+    V, T, n = _lanczos_arrays(A, m, v0)
+    V_dnd = DNDarray(V[:, :n].T, split=None, device=A.device, comm=A.comm)
+    T_dnd = DNDarray(T, split=None, device=A.device, comm=A.comm)
+    if V_out is not None:
+        V_out.larray = V_dnd._logical()
+        V_dnd = V_out
+    if T_out is not None:
+        T_out.larray = T_dnd._logical()
+        T_dnd = T_out
+    return V_dnd, T_dnd
+
+
+def _lanczos_arrays(A: DNDarray, m: int, v0: Optional[DNDarray] = None):
+    """``(V, T, n)``: the (m, n_pad) Lanczos vectors as rows, zero past
+    column n, and the (m, m) tridiagonal T, as device arrays."""
     if not isinstance(A, DNDarray):
         raise TypeError(f"A needs to be a DNDarray, got {type(A)}")
     if A.ndim != 2 or A.shape[0] != A.shape[1]:
@@ -132,57 +149,46 @@ def lanczos(
         v = v / jnp.linalg.norm(v)
 
     with jax.default_matmul_precision("highest"):
-        V, T = _lanczos_device(arr, v, m)
-
-    V_dnd = DNDarray(V[:, :n].T, split=None, device=A.device, comm=A.comm)
-    T_dnd = DNDarray(T, split=None, device=A.device, comm=A.comm)
-    if V_out is not None:
-        V_out.larray = V_dnd._logical()
-        V_dnd = V_out
-    if T_out is not None:
-        T_out.larray = T_dnd._logical()
-        T_dnd = T_out
-    return V_dnd, T_dnd
+        V, T = _lanczos_program(arr, v, m=m)
+    LAYOUT_STATS["lanczos_steps"] = m
+    return V, T, n
 
 
-def _lanczos_loop(arr, V, alphas, betas, w, m):
-    def body(i, state):
-        V, alphas, betas, w = state
-        beta = jnp.linalg.norm(w)
-        v_next = jnp.where(beta > 1e-12, w / jnp.where(beta == 0, 1.0, beta), jnp.zeros_like(w))
-        # full re-orthogonalization against previous Lanczos vectors
-        v_next = v_next - V.T @ (V @ v_next)
-        nrm = jnp.linalg.norm(v_next)
-        v_next = jnp.where(nrm > 1e-12, v_next / jnp.where(nrm == 0, 1.0, nrm), v_next)
-        V = V.at[i].set(v_next)
-        w2 = arr @ v_next
-        alpha = jnp.dot(w2, v_next)
-        w2 = w2 - alpha * v_next - beta * V[i - 1]
-        return (V, alphas.at[i].set(alpha), betas.at[i].set(beta), w2)
-
-    return jax.lax.fori_loop(1, m, body, (V, alphas, betas, w))
+def _matvec(arr, v):
+    """``arr @ v`` as a float32 multiply and row sum: one read of ``arr`` a
+    step in its own precision, and no matmul whose operand the compiler
+    could split into bf16 parts and hoist out of the loop beside it."""
+    return jnp.sum(arr * v[None, :], axis=1)
 
 
-# module-level jit: arr enters as a traced operand and the iteration count is
-# a static argument, so repeated same-shape solves reuse one executable (a
-# per-call jitted lambda here retraced the whole fori_loop on every solve)
-_lanczos_jit = jax.jit(_lanczos_loop, static_argnames="m")
+@partial(jax.jit, static_argnames="m")
+def _lanczos_program(arr, v, m: int):
+    """The m steps from the unit vector ``v``: the first matvec, then a
+    ``fori_loop`` over the other m - 1, all of them inside
+    ``ht.phase:lanczos``. Module-level jit: ``arr`` is a traced operand and
+    m static, so same-shape solves share one executable."""
+    with _hooks.phase("lanczos"):
+        V = jnp.zeros((m, arr.shape[0]), dtype=arr.dtype).at[0].set(v)
+        w = _matvec(arr, v)
+        alpha = jnp.dot(w, v)
+        alphas = jnp.zeros(m, dtype=arr.dtype).at[0].set(alpha)
+        betas = jnp.zeros(m, dtype=arr.dtype)
+        w = w - alpha * v
 
+        def body(i, state):
+            V, alphas, betas, w = state
+            beta = jnp.linalg.norm(w)
+            v_next = jnp.where(beta > 1e-12, w / jnp.where(beta == 0, 1.0, beta), jnp.zeros_like(w))
+            # full re-orthogonalization against previous Lanczos vectors
+            v_next = v_next - V.T @ (V @ v_next)
+            nrm = jnp.linalg.norm(v_next)
+            v_next = jnp.where(nrm > 1e-12, v_next / jnp.where(nrm == 0, 1.0, nrm), v_next)
+            V = V.at[i].set(v_next)
+            w2 = _matvec(arr, v_next)
+            alpha = jnp.dot(w2, v_next)
+            w2 = w2 - alpha * v_next - beta * V[i - 1]
+            return (V, alphas.at[i].set(alpha), betas.at[i].set(beta), w2)
 
-def _lanczos_device(arr, v, m):
-    n_pad = arr.shape[0]
-
-    V = jnp.zeros((m, n_pad), dtype=arr.dtype)
-    alphas = jnp.zeros(m, dtype=arr.dtype)
-    betas = jnp.zeros(m, dtype=arr.dtype)
-
-    V = V.at[0].set(v)
-    w = arr @ v
-    alpha = jnp.dot(w, v)
-    w = w - alpha * v
-    alphas = alphas.at[0].set(alpha)
-
-    V, alphas, betas, _ = _lanczos_jit(arr, V, alphas, betas, w, m=m)
-
-    T = jnp.diag(alphas) + jnp.diag(betas[1:], 1) + jnp.diag(betas[1:], -1)
+        V, alphas, betas, _ = jax.lax.fori_loop(1, m, body, (V, alphas, betas, w))
+        T = jnp.diag(alphas) + jnp.diag(betas[1:], 1) + jnp.diag(betas[1:], -1)
     return V, T

@@ -1,2 +1,2 @@
 """Graph algorithms (reference ``heat/graph/``)."""
-from .laplacian import Laplacian
+from .laplacian import Laplacian, PairwiseSimilarity
